@@ -3,15 +3,15 @@ from Avro training data (the reference's FeatureIndexingJob), then train
 the GLM driver against it via --offheap-indexmap-dir.
 
 Run:  python examples/feature_indexing.py  [--output-dir OUT]
+
+Runs on jax's default device (the driver logs which); set JAX_PLATFORMS=cpu
+for a CPU run.
 """
 import argparse
 import os
 import sys
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -5,18 +5,15 @@ and scored by the scoring driver with evaluators.
 
 Run:  python examples/game_yahoo_music.py  [--output-dir OUT] [--distributed]
 
-Works on an 8-virtual-device CPU mesh (forced below); pass --distributed to
-entity-shard the random effects over that mesh — on real hardware the same
-flag shards over the TPU chips instead.
+Runs on jax's default device (the drivers log which). With JAX_PLATFORMS=cpu
+it gets an 8-virtual-device CPU mesh (the XLA_FLAGS default below); pass
+--distributed to entity-shard the random effects over the visible devices.
 """
 import argparse
 import os
 import sys
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)

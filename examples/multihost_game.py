@@ -35,18 +35,17 @@ def free_port():
 
 
 def launch(module, args):
+    # a CPU harness: a chip belongs to one process at a time, so the two
+    # worker processes are pinned to 4 virtual CPU devices each (each
+    # driver logs its own ``platform: cpu`` line at start)
     port = free_port()
-    launcher = (
-        "import jax; jax.config.update('jax_platforms','cpu'); "
-        f"from photon_ml_tpu.cli.{module} import main; "
-        "import sys; main(sys.argv[1:])"
-    )
     procs = []
     for pid in range(2):
         env = dict(os.environ)
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        env["JAX_PLATFORMS"] = "cpu"
         procs.append(subprocess.Popen(
-            [sys.executable, "-c", launcher,
+            [sys.executable, "-m", f"photon_ml_tpu.cli.{module}",
              "--multihost-coordinator", f"127.0.0.1:{port}",
              "--multihost-num-processes", "2",
              "--multihost-process-id", str(pid)] + args,
@@ -58,9 +57,6 @@ def launch(module, args):
 
 
 def main():
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from game_test_utils import make_glmix_data
@@ -125,7 +121,7 @@ def main():
         "global:fixedFeatures|per_user:userFeatures",
     ])
 
-    print("== multihost training (2 SPMD processes) ==")
+    print("== multihost training (2 SPMD processes, platform: cpu) ==")
     launch("game_multihost_driver", [
         "--output-dir", os.path.join(work, "model"),
         "--train-input-dirs", train,
@@ -151,7 +147,7 @@ def main():
     ))
     print(f"model saved; random-effect parts (one per host): {sorted(re_parts)}")
 
-    print("== multihost scoring (model stays sharded) ==")
+    print("== multihost scoring (model stays sharded, platform: cpu) ==")
     launch("game_multihost_scoring_driver", [
         "--input-dirs", score_in,
         "--game-model-input-dir", os.path.join(work, "model", "best"),

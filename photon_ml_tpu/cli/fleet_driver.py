@@ -98,11 +98,7 @@ class GameFleetDriver:
 
         p = self.params
         out = out_stream if out_stream is not None else sys.stdout
-        if p.persistent_cache_dir:
-            if compat.enable_persistent_cache(p.persistent_cache_dir):
-                self.logger.info(
-                    f"persistent XLA cache: {p.persistent_cache_dir}"
-                )
+        compat.start_up(self.logger.info, p.persistent_cache_dir)
         compile_stats.install_xla_listeners()
         from photon_ml_tpu.serve.fleet import load_fleet_meta
 

@@ -42,7 +42,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from photon_ml_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.cli.game_params import (
@@ -738,21 +738,9 @@ def _main_once(mh_args: dict, p, restart: bool = False) -> dict:
     from photon_ml_tpu.compile import compile_stats
 
     compile_stats.install_xla_listeners()
-    if p.persistent_cache_dir:
-        # per-process subdir: hosts compile the same programs but must not
-        # race each other's cache files on a shared filesystem
-        from photon_ml_tpu import compat
+    from photon_ml_tpu import compat
 
-        cache_dir = os.path.join(
-            p.persistent_cache_dir, f"process-{mh.process_id}"
-        )
-        if compat.enable_persistent_cache(cache_dir):
-            logger.info(f"persistent XLA compilation cache: {cache_dir}")
-        else:
-            logger.warn(
-                "--persistent-cache requested but this jax has no "
-                "compilation-cache API; compiling uncached"
-            )
+    compat.start_up(logger.info, p.persistent_cache_dir)
 
     _check_multihost_support(p)
     # the execution plan (photon_ml_tpu.compile.plan) threads the shape

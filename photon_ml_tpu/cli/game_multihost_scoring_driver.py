@@ -109,6 +109,9 @@ def main(argv: Optional[List[str]] = None) -> dict:
     logger = PhotonLogger(
         os.path.join(p.output_dir, f"photon-ml-tpu-mh-scoring-{mh.process_id}.log")
     )
+    from photon_ml_tpu import compat
+
+    compat.start_up(logger.info)
     if not p.offheap_indexmap_dir:
         raise ValueError(
             "multihost scoring needs prebuilt feature maps: pass "

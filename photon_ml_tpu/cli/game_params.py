@@ -324,10 +324,11 @@ class GameTrainingParams:
     # config, so a re-run / warm-started grid over unchanged inputs skips
     # Avro decode + grouping + padding entirely
     tensor_cache_dir: Optional[str] = None
-    # persistent XLA compilation cache (photon_ml_tpu.compat shims): warm
-    # driver runs skip XLA compilation entirely — composes with
-    # --tensor-cache for a fully warm restart (cached tensors + cached
-    # executables)
+    # persistent XLA compilation cache directory, used when
+    # JAX_COMPILATION_CACHE_DIR is unset (compat.enable_persistent_cache;
+    # None = the fixed in-checkout default): warm driver runs skip XLA
+    # compilation entirely — composes with --tensor-cache for a fully
+    # warm restart (cached tensors + cached executables)
     persistent_cache_dir: Optional[str] = None
     # incremental delta retraining (photon_ml_tpu.retrain): the prior run's
     # OUTPUT dir (it holds retrain.json + the saved model). The delta
@@ -628,9 +629,10 @@ def build_training_parser() -> argparse.ArgumentParser:
            "Avro decode + grouping + padding; any input/config change is "
            "a miss")
     a("--persistent-cache", dest="persistent_cache_dir", default=None,
-      help="persistent XLA compilation cache dir: warm driver runs skip "
-           "compilation entirely (composes with --tensor-cache for a "
-           "fully warm restart)")
+      help="persistent XLA compilation cache dir (JAX_COMPILATION_CACHE_DIR "
+           "wins when set; default: .jax_compilation_cache in the "
+           "checkout): warm driver runs skip compilation entirely "
+           "(composes with --tensor-cache for a fully warm restart)")
     a("--warm-start-from", dest="warm_start_from", default=None,
       help="prior run's output dir (holds retrain.json + the saved "
            "model): delta retraining — unchanged coordinates/entity "
@@ -892,7 +894,7 @@ class GameServeParams:
     warmup: bool = True
     warm_nnz: Optional[int] = None
     # fail startup unless the warm start compiled nothing new in XLA
-    # (requires --persistent-cache and a prior run to have filled it)
+    # (requires a prior run to have filled the compile cache)
     assert_warm: bool = False
     # export the model store from --game-model-input-dir then exit
     build_store_only: bool = False
@@ -923,11 +925,6 @@ class GameServeParams:
             errors.append("--num-store-partitions must be >= 1")
         if self.warm_nnz is not None and self.warm_nnz < 1:
             errors.append("--warm-nnz must be >= 1")
-        if self.assert_warm and not self.persistent_cache_dir:
-            errors.append(
-                "--assert-warm needs --persistent-cache (zero new compiles "
-                "is only achievable from a filled persistent cache)"
-            )
         if self.assert_warm and not self.warmup:
             errors.append(
                 "--assert-warm needs warmup: with --no-warmup nothing "
@@ -969,8 +966,10 @@ def build_serve_parser() -> argparse.ArgumentParser:
       help="batch-shape ladder: off | on | BASE:GROWTH (default ON — every "
            "request shape rounds up to a warmed canonical executable)")
     a("--persistent-cache", dest="persistent_cache_dir", default=None,
-      help="persistent XLA compilation cache dir: a warm server start "
-           "compiles nothing (asserted when --assert-warm)")
+      help="persistent XLA compilation cache dir (JAX_COMPILATION_CACHE_DIR "
+           "wins when set; default: .jax_compilation_cache in the "
+           "checkout): a warm server start compiles nothing (asserted "
+           "when --assert-warm)")
     a("--no-warmup", action="store_true",
       help="skip the startup ladder warmup (first requests then compile)")
     a("--warm-nnz", type=int, default=None,

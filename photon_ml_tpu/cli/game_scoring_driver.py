@@ -194,6 +194,9 @@ class GameScoringDriver:
     def _run_guarded(self) -> None:
         p = self.params
         prepare_output_dir(p.output_dir, p.delete_output_dir_if_exists)
+        from photon_ml_tpu import compat
+
+        compat.start_up(self.logger.info)
         try:
             fixed, random = self._load_model_layout()
             shards = sorted(

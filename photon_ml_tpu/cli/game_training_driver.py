@@ -1672,18 +1672,9 @@ class GameTrainingDriver:
             os.makedirs(p.output_dir, exist_ok=True)
         else:
             prepare_output_dir(p.output_dir, p.delete_output_dir_if_exists)
-        if p.persistent_cache_dir:
-            from photon_ml_tpu import compat
+        from photon_ml_tpu import compat
 
-            if compat.enable_persistent_cache(p.persistent_cache_dir):
-                self.logger.info(
-                    f"persistent XLA compilation cache: {p.persistent_cache_dir}"
-                )
-            else:
-                self.logger.warn(
-                    "--persistent-cache requested but this jax has no "
-                    "compilation-cache API; compiling uncached"
-                )
+        compat.start_up(self.logger.info, p.persistent_cache_dir)
         self.logger.info(self.plan.describe())
         for line in self.plan.describe_decisions():
             self.logger.info(f"execution plan: {line}")
@@ -1762,7 +1753,7 @@ class GameTrainingDriver:
             from photon_ml_tpu.io.tensor_cache import cache_stats
 
             self.logger.info(cache_stats.summary())
-        if p.persistent_cache_dir and compile_stats.xla_cache_misses == 0:
+        if compile_stats.xla_cache_misses == 0:
             self.logger.info(
                 "persistent cache fully warm: zero new XLA compiles"
             )

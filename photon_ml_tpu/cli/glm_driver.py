@@ -142,18 +142,9 @@ class Driver:
         from photon_ml_tpu.compile import compile_stats
 
         compile_stats.install_xla_listeners()
-        if p.persistent_cache_dir:
-            from photon_ml_tpu import compat
+        from photon_ml_tpu import compat
 
-            if compat.enable_persistent_cache(p.persistent_cache_dir):
-                self.logger.info(
-                    f"persistent XLA compilation cache: {p.persistent_cache_dir}"
-                )
-            else:
-                self.logger.warn(
-                    "--persistent-cache requested but this jax has no "
-                    "compilation-cache API; compiling uncached"
-                )
+        compat.start_up(self.logger.info, p.persistent_cache_dir)
         try:
             with self.timer.measure("preprocess"):
                 self.preprocess()
@@ -171,7 +162,7 @@ class Driver:
                 from photon_ml_tpu.io.tensor_cache import cache_stats
 
                 self.logger.info(cache_stats.summary())
-            if p.persistent_cache_dir and compile_stats.xla_cache_misses == 0:
+            if compile_stats.xla_cache_misses == 0:
                 self.logger.info(
                     "persistent cache fully warm: zero new XLA compiles"
                 )

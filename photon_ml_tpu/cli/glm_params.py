@@ -75,8 +75,10 @@ class GLMParams:
     # content-addressed cache of the spilled stream chunks (io/tensor_cache):
     # a warm run over unchanged inputs skips decode + re-spill entirely
     tensor_cache_dir: Optional[str] = None
-    # persistent XLA compilation cache (photon_ml_tpu.compat shims): a warm
-    # run skips compilation entirely — composes with --tensor-cache
+    # persistent XLA compilation cache directory, used when
+    # JAX_COMPILATION_CACHE_DIR is unset (compat.enable_persistent_cache;
+    # None = the fixed in-checkout default): a warm run skips compilation
+    # entirely — composes with --tensor-cache
     persistent_cache_dir: Optional[str] = None
     # canonical shape ladder (photon_ml_tpu.compile): "off" | "on" |
     # "BASE:GROWTH" — stream-chunk row counts round up a geometric ladder
@@ -205,8 +207,10 @@ def build_parser() -> argparse.ArgumentParser:
            "(keyed by source file stats + ingest config): a warm "
            "--streaming-chunk-rows run skips decode + re-spill")
     a("--persistent-cache", dest="persistent_cache_dir", default=None,
-      help="persistent XLA compilation cache dir: warm runs skip "
-           "compilation entirely (composes with --tensor-cache)")
+      help="persistent XLA compilation cache dir (JAX_COMPILATION_CACHE_DIR "
+           "wins when set; default: .jax_compilation_cache in the "
+           "checkout): warm runs skip compilation entirely (composes "
+           "with --tensor-cache)")
     a("--shape-canonicalization", dest="shape_canonicalization", default="off",
       help="round stream-chunk row counts up a geometric ladder of "
            "canonical shapes (masked padding; the tail chunk stops "

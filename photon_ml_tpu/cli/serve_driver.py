@@ -7,7 +7,7 @@ sequence is the whole point:
 
   1. resolve the model store (export a saved GAME model into the mmap'd
      serving layout if the store does not exist yet),
-  2. enable the persistent XLA cache (compat.enable_persistent_cache),
+  2. log the device and enable the persistent XLA cache (compat.start_up),
   3. warm every (rows, nnz) ladder rung the request path can produce,
   4. log ``compile_stats.summary()`` and — on a warm cache — "serving
      fully warm: zero new XLA compiles" (``--assert-warm`` makes that a
@@ -85,28 +85,15 @@ class GameServeDriver:
         from photon_ml_tpu.serve import ModelSwapper, ScoringServer
 
         p = self.params
-        cache_ok = False
-        if p.persistent_cache_dir:
-            cache_ok = compat.enable_persistent_cache(p.persistent_cache_dir)
-            if cache_ok:
-                self.logger.info(
-                    f"persistent XLA compilation cache: {p.persistent_cache_dir}"
-                )
-            else:
-                self.logger.warn(
-                    "--persistent-cache requested but this jax has no "
-                    "compilation-cache API; compiling uncached"
-                )
+        compat.start_up(self.logger.info, p.persistent_cache_dir)
         listeners_ok = compile_stats.install_xla_listeners()
-        if p.assert_warm and not (cache_ok and listeners_ok):
-            # the gate must not be vacuously satisfiable: with no cache the
-            # start cannot be warm, and with no monitoring API the miss
-            # counter would stay 0 no matter how much XLA compiled
+        if p.assert_warm and not listeners_ok:
+            # the gate must not be vacuously satisfiable: with no
+            # monitoring API the miss counter would stay 0 no matter how
+            # much XLA compiled
             raise RuntimeError(
-                "--assert-warm needs a working persistent cache "
-                f"(enabled={cache_ok}) and the jax.monitoring compile "
-                f"listeners (installed={listeners_ok}) to be verifiable "
-                "on this jax version"
+                "--assert-warm needs the jax.monitoring compile listeners "
+                "to be verifiable"
             )
         store = self.resolve_store()
         if p.build_store_only:
@@ -130,7 +117,7 @@ class GameServeDriver:
                 f"{self.warm_report['new_xla_misses']} new XLA compiles"
             )
         self.logger.info(compile_stats.summary())
-        if cache_ok and listeners_ok and self.server.fully_warm():
+        if listeners_ok and self.server.fully_warm():
             self.logger.info("serving fully warm: zero new XLA compiles")
         elif p.assert_warm:
             raise RuntimeError(

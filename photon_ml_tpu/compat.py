@@ -1,156 +1,92 @@
-"""Version-gated JAX API shims.
+"""Process start-up shared by every entry point: which device jax gave
+us, where the persistent XLA compilation cache lives, and the forced
+multi-device CPU mesh the test harnesses ride.
 
-The baked image pins one JAX version; developer machines and CI may run
-another. Every cross-version API difference the package depends on is
-resolved HERE, once, instead of try/excepting at each call site — part of
-the resilience story: an import-time AttributeError in a leaf module would
-otherwise take down the whole ``parallel`` package (and every driver that
-lazily imports it) on a version skew.
-
-Currently shimmed:
-
-  * ``shard_map`` — stable ``jax.shard_map`` (jax >= 0.6) with the
-    ``check_vma`` kwarg, vs ``jax.experimental.shard_map.shard_map`` (older
-    jax) where the same knob is spelled ``check_rep``. Callers use the
-    modern spelling; the shim translates when running on the older API.
-  * ``distributed_is_initialized`` — ``jax.distributed.is_initialized()``
-    does not exist on older jax; fall back to probing the internal
-    distributed global state for a live client.
-  * ``enable_persistent_cache`` — the persistent XLA compilation cache is
-    spelled three ways across jax versions (``jax_compilation_cache_dir``
-    config + tuning knobs, vs the experimental
-    ``compilation_cache.set_cache_dir``); one call resolves whichever this
-    jax has, so warm driver runs skip XLA compilation entirely.
+Written for the one installation the repo runs on (jax 0.9.0): call sites
+use ``jax.shard_map``, ``jax.enable_x64``, ``jax.distributed.is_initialized``
+and ``pallas.tpu.CompilerParams`` directly.
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+import os
+from typing import Callable, Optional
 
-try:  # modern spelling (jax >= 0.6): stable, check_vma kwarg
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
-    _NEEDS_TRANSLATION = False
-except ImportError:  # older jax: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NEEDS_TRANSLATION = True
-
-
-def shard_map(f: Callable[..., Any], **kwargs: Any):
-    """``jax.shard_map`` facade accepting the modern kwargs on any jax.
-
-    On the legacy API the ``check_rep`` validator has no replication rule
-    for ``lax.while_loop`` (NotImplementedError at trace time), which every
-    solver kernel here carries — so when translating, validation is turned
-    OFF rather than crashing the solve. The modern ``check_vma`` validator
-    handles while_loop and stays at the caller's setting; the compensating
-    sharded-vs-local equivalence tests (tests/test_checkvma_fence.py
-    registry) hold on both APIs.
-    """
-    if _NEEDS_TRANSLATION:
-        kwargs.pop("check_vma", None)
-        kwargs["check_rep"] = False
-    return _shard_map(f, **kwargs)
+#: where the compile cache goes when neither the environment nor the caller
+#: names a directory: fixed and inside the checkout (git-ignored). The path
+#: is part of the cache's key, so it must never carry a temporary name, a
+#: pid or a time — a directory that moves never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compilation_cache",
+)
 
 
-def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` on any jax version."""
+def device_summary() -> str:
+    """``platform: …, device_kind: …, count: …`` of the devices jax gave
+    this process — every entry point logs it once at start, so a run that
+    silently landed on the CPU says so in its first lines."""
     import jax
 
-    try:
-        return bool(jax.distributed.is_initialized())
-    except AttributeError:
-        from jax._src import distributed as _dist
-
-        return getattr(_dist.global_state, "client", None) is not None
-
-
-def enable_x64():
-    """``jax.enable_x64()`` context manager on any jax version (older jax
-    spells it ``jax.experimental.enable_x64``)."""
-    import jax
-
-    try:
-        return jax.enable_x64()
-    except AttributeError:
-        from jax.experimental import enable_x64 as _enable_x64
-
-        return _enable_x64()
-
-
-def enable_persistent_cache(path: str) -> bool:
-    """Point jax's persistent XLA compilation cache at ``path``.
-
-    Modern jax: the ``jax_compilation_cache_dir`` config option, plus the
-    two tuning knobs that default to skipping small/fast entries — both
-    zeroed here, because the GLMix solver sites are exactly the many-small-
-    executables workload those defaults would exclude (a "warm" run that
-    still recompiles every solver kernel reports zero benefit). Older jax:
-    ``jax.experimental.compilation_cache.set_cache_dir``. Returns False
-    when no spelling exists on this jax (the caller logs and moves on —
-    an absent cache must never fail a training run).
-    """
-    import os
-
-    import jax
-
-    os.makedirs(path, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-    except AttributeError:
-        try:
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.set_cache_dir(path)
-            return True
-        except (ImportError, AttributeError):
-            return False
-    # cache EVERYTHING: -1 disables the min-entry-size filter; 0 disables
-    # the min-compile-seconds filter (knobs absent on some versions)
-    for knob, value in (
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except (AttributeError, ValueError):
-            pass  # knob not on this jax: defaults still cache solver-sized entries
-    try:
-        # jax LATCHES cache-used at the first compile of the process; a
-        # driver that touched the device before reaching this call (backend
-        # probe, data placement) would silently never cache without a reset
-        from jax._src import compilation_cache as _cc_internal
-
-        _cc_internal.reset_cache()
-    except (ImportError, AttributeError):
-        pass  # no latch on this jax: the config alone suffices
-    return True
-
-
-def pallas_tpu_compiler_params(**kwargs: Any):
-    """Pallas TPU ``CompilerParams`` across jax versions.
-
-    Newer jax spells the Mosaic compiler-params struct
-    ``pallas.tpu.CompilerParams``; 0.4.x spells the same struct
-    ``TPUCompilerParams`` (and the very oldest releases only accept a plain
-    dict through ``compiler_params=``). Kernel call sites pass the modern
-    kwargs (``dimension_semantics=...``) and this resolves whichever
-    spelling the running jax has — the fused-GLM Pallas family must
-    compile on both the baked image and developer jax. (The fused-sparse
-    kernels pass no compiler params: their row-block grid axis carries a
-    sequential VMEM accumulator, so the default ordering is required.)
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
+    devs = jax.devices()
+    text = (
+        f"platform: {devs[0].platform}, device_kind: {devs[0].device_kind}, "
+        f"count: {len(devs)}"
     )
-    if cls is None:  # ancient pallas: a bare dict is the accepted form
-        return dict(kwargs)
-    return cls(**kwargs)
+    if jax.process_count() > 1:
+        text += (
+            f" (process {jax.process_index()}/{jax.process_count()}, "
+            f"{jax.local_device_count()} local)"
+        )
+    return text
+
+
+def enable_persistent_cache(path: Optional[str] = None) -> str:
+    """Turn on jax's persistent XLA compilation cache and return its
+    directory — THE one rule every entry point shares, in priority order:
+    ``JAX_COMPILATION_CACHE_DIR`` (whoever runs the program places the
+    cache; nothing in code names another directory), else ``path`` (a
+    driver's ``--persistent-cache``), else
+    :data:`DEFAULT_COMPILE_CACHE_DIR`.
+
+    The two tuning knobs that default to skipping small/fast entries are
+    both zeroed, because the GLMix solver sites are exactly the
+    many-small-executables workload those defaults would exclude (a "warm"
+    run that still recompiles every solver kernel reports zero benefit).
+    """
+    import jax
+    from jax._src import compilation_cache
+
+    from photon_ml_tpu.compile import overrides
+
+    cache_dir = (
+        overrides.env_read(COMPILE_CACHE_ENV)
+        or path
+        or DEFAULT_COMPILE_CACHE_DIR
+    )
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # cache EVERYTHING: -1 disables the min-entry-size filter; 0 disables
+    # the min-compile-seconds filter
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # jax LATCHES cache-used at the first compile of the process; a driver
+    # that touched the device before reaching this call (device summary,
+    # data placement) would silently never cache without a reset
+    compilation_cache.reset_cache()
+    return cache_dir
+
+
+def start_up(log: Callable[[str], None], cache_arg: Optional[str] = None) -> str:
+    """Every entry point's first lines: log the device jax gave the process
+    and turn on the compile cache (``cache_arg`` is the driver's
+    ``--persistent-cache``). Returns the cache directory."""
+    log(device_summary())
+    cache_dir = enable_persistent_cache(cache_arg)
+    log(f"persistent XLA compilation cache: {cache_dir}")
+    return cache_dir
 
 
 _FORCE_CPU_FLAG = "--xla_force_host_platform_device_count"
@@ -180,14 +116,10 @@ def forced_cpu_device_count(flags: Optional[str] = None) -> Optional[int]:
 def backends_initialized() -> bool:
     """Whether jax has already instantiated a PJRT backend — after which
     ``XLA_FLAGS`` edits are silently ignored. Probes the backend registry
-    WITHOUT initializing it; when the registry moved (version skew), the
-    conservative answer is True (treat flags as latched)."""
-    try:
-        from jax._src import xla_bridge
+    WITHOUT initializing it."""
+    from jax._src import xla_bridge
 
-        return bool(xla_bridge._backends)
-    except (ImportError, AttributeError):
-        return True
+    return bool(xla_bridge._backends)
 
 
 def force_cpu_devices(n: int) -> bool:
@@ -203,8 +135,6 @@ def force_cpu_devices(n: int) -> bool:
         the caller knows to skip or re-exec in a fresh subprocess (the
         bench psum arm's structured ``preflight:`` skip).
     """
-    import os
-
     if n < 1:
         raise ValueError(f"force_cpu_devices needs n >= 1, got {n}")
     if backends_initialized():
@@ -225,19 +155,3 @@ def force_cpu_devices(n: int) -> bool:
     parts.append(f"{_FORCE_CPU_FLAG}={n}")
     os.environ["XLA_FLAGS"] = " ".join(parts)
     return True
-
-
-def ensure_cpu_collectives() -> None:
-    """Select the Gloo CPU collectives implementation where it is opt-in.
-
-    Older jax ships multiprocess CPU collectives behind
-    ``jax_cpu_collectives_implementation`` (default ``none`` -> cross-host
-    psums fail with "Multiprocess computations aren't implemented on the
-    CPU backend"); newer jax enables a CPU collectives backend by default.
-    Harmless on TPU — the option only affects the CPU PJRT client."""
-    import jax
-
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except (AttributeError, ValueError):
-        pass  # option gone (newer jax: CPU collectives are on by default)

@@ -10,10 +10,10 @@ overhead (ISSUE 3; the Snap ML / DrJAX compile-amortization idea):
   * **Compile telemetry** (:mod:`.stats`): per-site trace/call counters
     (:func:`instrumented_jit`) plus XLA persistent-cache hit/miss counts
     and backend-compile seconds via ``jax.monitoring``.
-  * **Persistent compilation cache**: enabled through
-    :func:`photon_ml_tpu.compat.enable_persistent_cache` (version-gated
-    jax config shims) — warm driver runs skip XLA compilation entirely and
-    report it through the same telemetry.
+  * **Persistent compilation cache**: always on, through
+    :func:`photon_ml_tpu.compat.enable_persistent_cache` (one directory
+    rule for every entry point) — warm driver runs skip XLA compilation
+    entirely and report it through the same telemetry.
 
 Buffer donation rides the same layer: :func:`donation_enabled` gates the
 ``donate_argnums`` annotations on the coordinate-descent update/cycle

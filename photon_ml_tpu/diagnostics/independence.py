@@ -56,7 +56,7 @@ def _pair_census(a: Array, b: Array):
     concordant = jnp.sum((sa * sb > 0) & upper)
     discordant = jnp.sum((sa * sb < 0) & upper)
     ties_a = jnp.sum((sa == 0) & upper)
-    # Reference tie taxonomy (KendallTauAnalysis.checkConcordance): a pair
+    # Reference tie classification (KendallTauAnalysis.checkConcordance): a pair
     # tied in A is counted as TIES_IN_A regardless of B; TIES_IN_B only
     # counts pairs with distinct A values.
     ties_b = jnp.sum((sa != 0) & (sb == 0) & upper)

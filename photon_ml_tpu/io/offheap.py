@@ -26,14 +26,13 @@ import logging
 import mmap as mmap_mod
 import os
 import struct
-import subprocess
-import tempfile
 import zlib
 from typing import Dict, Iterable, List, Optional
 
 import numpy as np
 
 from photon_ml_tpu.io.index_map import INTERCEPT_KEY, IndexMap, partition_keys
+from photon_ml_tpu.io.native_build import load_native_lib
 
 logger = logging.getLogger(__name__)
 
@@ -69,66 +68,30 @@ def _next_pow2(v: int) -> int:
 # native library (lazy compile + ctypes)
 # ---------------------------------------------------------------------------
 
-_NATIVE_SOURCE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    "native",
-    "pmix_store.cpp",
-)
-_native_lib = None
-_native_failed = False
+def _configure_native(lib) -> None:
+    lib.pmix_open.restype = ctypes.c_void_p
+    lib.pmix_open.argtypes = [ctypes.c_char_p]
+    lib.pmix_close.argtypes = [ctypes.c_void_p]
+    lib.pmix_size.restype = ctypes.c_long
+    lib.pmix_size.argtypes = [ctypes.c_void_p]
+    lib.pmix_get_index.restype = ctypes.c_long
+    lib.pmix_get_index.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long]
+    lib.pmix_get_name.restype = ctypes.c_long
+    lib.pmix_get_name.argtypes = [
+        ctypes.c_void_p, ctypes.c_long, ctypes.c_char_p, ctypes.c_long,
+    ]
+    lib.pmix_build.restype = ctypes.c_int
+    lib.pmix_build.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_uint64), ctypes.c_long,
+    ]
 
 
 def _load_native():
-    """Compile (once, cached by source hash) and load the C++ store."""
-    global _native_lib, _native_failed
-    if _native_lib is not None or _native_failed:
-        return _native_lib
-    try:
-        with open(_NATIVE_SOURCE, "rb") as f:
-            src = f.read()
-        tag = f"{zlib.crc32(src):08x}"
-        cache_dir = os.path.join(
-            os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-            "photon_ml_tpu",
-        )
-        os.makedirs(cache_dir, exist_ok=True)
-        lib_path = os.path.join(cache_dir, f"libpmix-{tag}.so")
-        if not os.path.exists(lib_path):
-            with tempfile.TemporaryDirectory() as tmp:
-                tmp_lib = os.path.join(tmp, "libpmix.so")
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                     "-o", tmp_lib, _NATIVE_SOURCE],
-                    check=True,
-                    capture_output=True,
-                )
-                os.replace(tmp_lib, lib_path)
-        lib = ctypes.CDLL(lib_path)
-        lib.pmix_open.restype = ctypes.c_void_p
-        lib.pmix_open.argtypes = [ctypes.c_char_p]
-        lib.pmix_close.argtypes = [ctypes.c_void_p]
-        lib.pmix_size.restype = ctypes.c_long
-        lib.pmix_size.argtypes = [ctypes.c_void_p]
-        lib.pmix_get_index.restype = ctypes.c_long
-        lib.pmix_get_index.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_long]
-        lib.pmix_get_name.restype = ctypes.c_long
-        lib.pmix_get_name.argtypes = [
-            ctypes.c_void_p, ctypes.c_long, ctypes.c_char_p, ctypes.c_long,
-        ]
-        lib.pmix_build.restype = ctypes.c_int
-        lib.pmix_build.argtypes = [
-            ctypes.c_char_p, ctypes.c_char_p,
-            ctypes.POINTER(ctypes.c_uint64), ctypes.c_long,
-        ]
-        _native_lib = lib
-    except (OSError, subprocess.CalledProcessError, AttributeError) as e:
-        # expected degradations: no source file / no g++ / CDLL load failure /
-        # a library missing an entry point — fall back to the pure-Python
-        # reader, loudly (anything else, e.g. a ctypes misuse bug, raises)
-        logger.warning("native pmix store unavailable (%s); using pure-Python reader", e)
-        _native_failed = True
-        _native_lib = None
-    return _native_lib
+    """Compile (once, cached by source hash) and load the C++ store; None —
+    with a warning — where it cannot be built (the pure-Python reader of the
+    same file format takes over)."""
+    return load_native_lib("pmix_store.cpp", _configure_native)
 
 
 def native_available() -> bool:

@@ -68,6 +68,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import os
 import time
 import warnings
@@ -82,9 +83,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from photon_ml_tpu.ops.features import _acc_dtype
+from photon_ml_tpu.ops.fused_glm import _first_line, _interpret_default
 from photon_ml_tpu.ops.losses import PointwiseLoss
 
 Array = jax.Array
+
+logger = logging.getLogger(__name__)
 
 _SPARSE_ENV = "PHOTON_SPARSE_KERNEL"
 
@@ -356,12 +360,6 @@ def slab_nnz_stats(slab: SparseSlab) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _on_tpu() -> bool:
-    from photon_ml_tpu.ops.fused_glm import _on_tpu as _impl
-
-    return _impl()
-
-
 def _make_gevm_kernel(loss: PointwiseLoss, block_rows: int, m: int):
     """One-pass (row_wl, grad, row_d) over a lane's (M, K) slab rows.
 
@@ -574,7 +572,7 @@ def fused_value_grad_parts(
     caller owns the shift/factor/L2 algebra, like the dense fused path).
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret_default()
     m, k = slab.idx.shape[-2:]
     _, block = _family_block(slab.kernel)
     fn = _gevm_fn(loss, _resolve_block(block, m), m, k, slab.dim, interpret)
@@ -595,7 +593,7 @@ def fused_hvp_parts(
     """Raw one-pass HVP pieces for one lane: (X^T c, sum c) with
     c = weight * l''(z) * (X v + vshift)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = _interpret_default()
     m, k = slab.idx.shape[-2:]
     _, block = _family_block(slab.kernel)
     fn = _hvp_fn(loss, _resolve_block(block, m), m, k, slab.dim, interpret)
@@ -733,6 +731,9 @@ def race_sparse_kernels(
             timings[fam] = _time_lane_vg(vg, w0, data)
         except Exception as exc:  # noqa: BLE001 — race probe: failure disqualifies the candidate (recorded, not dropped)
             report[fam] = {"failed": f"error: {type(exc).__name__}: {exc}"[:300]}
+            logger.warning(
+                "sparse race: candidate %s refused (%s)", fam, _first_line(exc)
+            )
             outputs.pop(fam, None)
             continue
 
@@ -762,6 +763,9 @@ def race_sparse_kernels(
             timings["dense"] = _time_lane_vg(vg, w0, data_d)
         except Exception as exc:  # noqa: BLE001 — incumbent probe failure: sparse race proceeds without it (recorded)
             report["dense"] = {"failed": f"error: {type(exc).__name__}: {exc}"[:300]}
+            logger.warning(
+                "sparse race: dense incumbent refused (%s)", _first_line(exc)
+            )
 
     rows = int(slab_p.idx.shape[0]) * m
     for fam, sec in timings.items():

@@ -46,8 +46,9 @@ Selection: ``SolveSchedule(loop="device")`` — spelled ``--solve-compaction
 device[:CHUNK]`` or ``PHOTON_SOLVE_CHUNK=device[:CHUNK]`` via
 ``compile/overrides.py``; default stays the host loop, bitwise. The
 ``optim.device_drain`` fault site (resilience/sites.py) guards the
-dispatch: ANY failure inside the fused device path degrades the solve to
-the host chunk loop (results stay bitwise), recorded in the log.
+dispatch: an injected fault degrades the solve to the host chunk loop
+(results stay bitwise), recorded in the log; a real compile or runtime
+error of the fused program raises.
 """
 
 from __future__ import annotations

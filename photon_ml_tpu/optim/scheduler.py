@@ -589,11 +589,12 @@ def compacted_solve(
     ``schedule.loop == "device"`` routes the solve through the fused
     on-device loop (optim/fused_schedule.py) instead — same bitwise
     results, O(#rungs) host dispatches. The ``optim.device_drain`` fault
-    site guards that dispatch: ANY failure inside the fused path (an
-    injected fault, or a real XLA/runtime error) degrades THIS solve to
+    site guards that dispatch: an INJECTED fault degrades THIS solve to
     the host chunk loop below, which recomputes from scratch — lane
     arithmetic is batch-independent, so the degraded results are still
-    bitwise. Preemption is never a failure: a device-loop
+    bitwise. A real compile or runtime error of the fused program raises:
+    a run asked for the device loop must not report the host loop's
+    behaviour under its name. A device-loop
     :class:`~photon_ml_tpu.resilience.preemption.Preempted` propagates
     with its rung-boundary snapshot intact.
     """
@@ -611,16 +612,16 @@ def compacted_solve(
             faults.inject(
                 "optim.device_drain", label=label, lanes=int(w0.shape[0])
             )
+        except (faults.InjectedIOError, faults.InjectedFatalError) as e:
+            logger.warning(
+                "fused device solve (%s): injected fault (%s: %s); "
+                "degrading to the host chunk loop",
+                label, type(e).__name__, e,
+            )
+        else:
             return fused_schedule.device_solve(
                 data, w0, schedule=schedule, label=label, resume=resume,
                 **cfg,
-            )
-        except preemption.Preempted:
-            raise
-        except Exception as e:  # noqa: BLE001 — ANY device-loop failure means the fused program is untrusted; the host chunk loop is the bitwise-safe degrade
-            logger.warning(
-                "fused device solve (%s) failed (%s: %s); degrading to "
-                "the host chunk loop", label, type(e).__name__, e,
             )
     lanes = int(w0.shape[0])
     max_iter = optimizer_config.max_iterations

@@ -25,7 +25,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from photon_ml_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from photon_ml_tpu.data.game import RandomEffectDataset

@@ -194,13 +194,7 @@ def initialize(
     on CPU/GPU test clusters pass all three of coordinator/num/process-id.
     """
     if (num_processes is not None and num_processes > 1) or coordinator_address:
-        from photon_ml_tpu.compat import (
-            distributed_is_initialized,
-            ensure_cpu_collectives,
-        )
-
-        if not distributed_is_initialized():
-            ensure_cpu_collectives()
+        if not jax.distributed.is_initialized():
             kwargs = {}
             if local_device_count is not None:
                 # spelled local_device_ids in this jax version

@@ -36,7 +36,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from photon_ml_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.parallel.mesh import MeshContext

@@ -123,14 +123,12 @@ def merge_disjoint(arr: np.ndarray, ctx: Optional[MeshContext],
         return np.asarray(flat, np.float32).reshape(a.shape)
     from jax.experimental import multihost_utils
 
-    from photon_ml_tpu import compat
-
     flat = a.reshape(-1)
     # x64 for the transport: process_allgather device_puts the host array,
     # and WITHOUT x64 that canonicalizes float64 -> float32 — exactly the
     # truncation this branch exists to avoid (same rule as the int64
     # reduces in shuffle._collective_reduce)
-    with compat.enable_x64():
+    with jax.enable_x64():
         gathered = np.asarray(
             multihost_utils.process_allgather(flat, tiled=True)
         ).reshape(num_processes, -1)
@@ -166,7 +164,7 @@ def merge_disjoint_devices(shards, ctx: MeshContext) -> np.ndarray:
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from photon_ml_tpu import compat, resilience
+    from photon_ml_tpu import resilience
     from photon_ml_tpu.resilience import faults
 
     a = np.asarray(shards)
@@ -191,7 +189,7 @@ def merge_disjoint_devices(shards, ctx: MeshContext) -> np.ndarray:
         return a[0].copy()
     g = jax.device_put(a, NamedSharding(ctx.mesh, P(ctx.axis)))
     merged = jax.jit(  # jit-ok: one-shot exact-merge collective, inputs are live partials (nothing to donate)
-        compat.shard_map(
+        jax.shard_map(
             lambda s: jax.lax.psum(s[0], ctx.axis),
             mesh=ctx.mesh, in_specs=P(ctx.axis), out_specs=P(),
         )

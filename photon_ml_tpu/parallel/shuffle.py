@@ -32,9 +32,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from photon_ml_tpu import compat
-from photon_ml_tpu.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from photon_ml_tpu.parallel.mesh import MeshContext
@@ -142,7 +140,7 @@ def _collective_reduce(
     # and (b) wraps the int64 min fill to 0, poisoning negative maxes
     is_i64 = np.issubdtype(block.dtype, np.integer) and block.dtype.itemsize == 8
     try:
-        with compat.enable_x64() if is_i64 else contextlib.nullcontext():
+        with jax.enable_x64() if is_i64 else contextlib.nullcontext():
             g = jax.make_array_from_process_local_data(sharding, block)
             out = jax.jit(
                 lambda a: fn(a, axis=0), out_shardings=NamedSharding(ctx.mesh, P())

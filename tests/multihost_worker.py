@@ -167,7 +167,7 @@ print(f"MHOK proc={proc_id} coefs={','.join(f'{c:.6f}' for c in coefs)}", flush=
 # host granularity), solves its entities' local GLMs with the vmapped
 # kernel under shard_map, and scores its own rows locally ---------------------
 import jax.numpy as jnp2  # noqa: E402 (alias to keep the FE section intact)
-from photon_ml_tpu.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from photon_ml_tpu.optim.lbfgs import lbfgs_minimize_  # noqa: E402

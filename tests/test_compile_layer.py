@@ -389,28 +389,93 @@ class TestCompileStats:
 
 
 class TestPersistentCache:
-    def test_enable_writes_and_hits(self, tmp_path):
+    @pytest.fixture(autouse=True)
+    def _restore_cache_config(self):
+        saved = {
+            k: getattr(jax.config, k) for k in (
+                "jax_compilation_cache_dir",
+                "jax_persistent_cache_min_entry_size_bytes",
+                "jax_persistent_cache_min_compile_time_secs",
+            )
+        }
+        yield
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        from jax._src import compilation_cache as _cc
+
+        _cc.reset_cache()
+
+    def test_enable_writes_and_hits(self, tmp_path, monkeypatch):
         from photon_ml_tpu import compat
 
+        monkeypatch.delenv(compat.COMPILE_CACHE_ENV, raising=False)
         cache_dir = str(tmp_path / "xla-cache")
         compile_stats.install_xla_listeners()
-        assert compat.enable_persistent_cache(cache_dir)
-        try:
-            compile_stats.reset()
-            jax.jit(lambda x: x * 3 + 2)(jnp.ones((64,)))
-            assert os.listdir(cache_dir), "no cache entries written"
-            misses = compile_stats.xla_cache_misses
-            assert misses >= 1
-            # an IDENTICAL computation under a fresh jit wrapper must come
-            # from the persistent cache, not a new XLA compile
-            jax.jit(lambda x: x * 3 + 2)(jnp.ones((64,)))
-            assert compile_stats.xla_cache_hits >= 1
-            assert compile_stats.xla_cache_misses == misses
-        finally:
-            jax.config.update("jax_compilation_cache_dir", None)
-            from jax._src import compilation_cache as _cc
+        assert compat.enable_persistent_cache(cache_dir) == cache_dir
+        compile_stats.reset()
+        jax.jit(lambda x: x * 3 + 2)(jnp.ones((64,)))
+        assert os.listdir(cache_dir), "no cache entries written"
+        misses = compile_stats.xla_cache_misses
+        assert misses >= 1
+        # an IDENTICAL computation under a fresh jit wrapper must come
+        # from the persistent cache, not a new XLA compile
+        jax.jit(lambda x: x * 3 + 2)(jnp.ones((64,)))
+        assert compile_stats.xla_cache_hits >= 1
+        assert compile_stats.xla_cache_misses == misses
 
-            _cc.reset_cache()
+    @staticmethod
+    def _min_knobs():
+        return (
+            jax.config.jax_persistent_cache_min_entry_size_bytes,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+        )
+
+    def test_environment_places_the_cache(self, tmp_path, monkeypatch):
+        """With JAX_COMPILATION_CACHE_DIR set, no other directory is set in
+        code — not even a driver's --persistent-cache."""
+        from photon_ml_tpu import compat
+
+        env_dir = str(tmp_path / "from-env")
+        monkeypatch.setenv(compat.COMPILE_CACHE_ENV, env_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        logged = []
+        got = compat.start_up(logged.append, str(tmp_path / "from-flag"))
+        assert got == env_dir
+        assert jax.config.jax_compilation_cache_dir == env_dir
+        assert not os.path.exists(tmp_path / "from-flag")
+        assert self._min_knobs() == (-1, 0)
+        assert logged[0].startswith("platform: cpu, device_kind: ")
+        assert env_dir in logged[1]
+
+    def test_default_is_one_fixed_path_in_the_checkout(self, tmp_path,
+                                                       monkeypatch):
+        """Unset, the directory is the same in-checkout path whatever the
+        cwd or pid of the caller — the path is part of the cache key."""
+        import subprocess
+        import sys
+
+        from photon_ml_tpu import compat
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_compilation_cache")
+        monkeypatch.delenv(compat.COMPILE_CACHE_ENV, raising=False)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+        assert compat.enable_persistent_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert self._min_knobs() == (-1, 0)
+        # another process, another cwd
+        env = {k: v for k, v in os.environ.items()
+               if k != compat.COMPILE_CACHE_ENV}
+        env["PYTHONPATH"] = repo
+        out = subprocess.run(
+            [sys.executable, "-c",
+             "from photon_ml_tpu import compat; "
+             "print(compat.enable_persistent_cache())"],
+            cwd=str(tmp_path), env=env, capture_output=True, text=True,
+            timeout=120,
+        )
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip().splitlines()[-1] == want
 
 
 class TestDescentDonation:

@@ -369,7 +369,7 @@ class TestSelectionRace:
         assert report["baseline"] == SPARSE_BASELINE
 
     def test_f64_disqualifies_pallas_with_reason(self, rng):
-        from photon_ml_tpu.compat import enable_x64
+        from jax import enable_x64
         from photon_ml_tpu.types import TaskType
 
         x, y, wt, off = _skewed_dense(rng, 3, 8, 8)
@@ -388,7 +388,7 @@ class TestSelectionRace:
         family that actually executes (the objective's f64 gate falls back
         to the generic scatter) instead of lying in telemetry and keying a
         duplicate executable on a "pallas" static field."""
-        from photon_ml_tpu.compat import enable_x64
+        from jax import enable_x64
         from photon_ml_tpu.types import TaskType
 
         x, y, wt, off = _skewed_dense(rng, 3, 8, 6)

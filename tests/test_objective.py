@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from photon_ml_tpu.compat import shard_map
+from jax import shard_map
 
 from photon_ml_tpu.ops import losses
 from photon_ml_tpu.ops.features import DenseFeatures, SparseFeatures

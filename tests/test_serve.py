@@ -707,8 +707,9 @@ class TestServeDriver:
 
         with pytest.raises(ValueError, match="model-store-dir"):
             GameServeParams().validate()
-        with pytest.raises(ValueError, match="assert-warm"):
-            GameServeParams(model_store_dir="x", assert_warm=True).validate()
+        # the compile cache is always on (compat.enable_persistent_cache),
+        # so --assert-warm alone is a checkable request
+        GameServeParams(model_store_dir="x", assert_warm=True).validate()
         with pytest.raises(ValueError, match="max-batch-rows"):
             GameServeParams(model_store_dir="x", max_batch_rows=0).validate()
         with pytest.raises(ValueError, match="shape-canonicalization"):

@@ -337,7 +337,16 @@ def run_day(cfg: DayConfig, enforce: bool = True) -> dict:
     tmp = tempfile.mkdtemp(prefix="day-in-life-")
     spec = build_spec(cfg)
     ledger = SLOLedger(spec, exact_limit=cfg.exact_limit)
-    extra: dict = {"config": dataclasses.asdict(cfg)}
+    import jax
+
+    # the in-process servers run on the device jax gave this process; the
+    # kill arm's TCP replicas are pinned children (a chip belongs to one
+    # process at a time), so that arm is a CPU harness wherever this runs
+    extra: dict = {
+        "config": dataclasses.asdict(cfg),
+        "platform": jax.devices()[0].platform,
+        "replica_platform": "cpu",
+    }
     rng = np.random.default_rng(cfg.seed)
 
     def single_oracle(model_dir: str, reqs: List[dict],
@@ -1191,6 +1200,8 @@ def main(argv=None) -> dict:
     result = run_day(cfg, enforce=not args.no_enforce)
     led = result["ledger"]
     print(json.dumps({
+        "platform": result["extra"]["platform"],
+        "replica_platform": result["extra"]["replica_platform"],
         "ok": led["ok"],
         "violations_total": led["violations_total"],
         "totals": led["totals"],

@@ -10,11 +10,17 @@ tutorial configuration: fixed effect + per-user RE logistic regression on
 rating >= 4). Labels come from a planted fixed+per-user model so AUC has
 a real signal to recover.
 
-Writes Avro (the real wire format), builds the off-heap feature index via
-the feature-indexing job path, trains through cli/game_training_driver with
-AUC + sec/iter recorded, and updates BASELINE.json.published.
+Writes Avro (the real wire format), trains through
+cli/game_training_driver (which scans the feature index itself) with AUC +
+sec/iter recorded, and updates BASELINE.json.published with the platform
+it ran on.
 
 Run:  python tools/movielens_baseline.py [--rows N] [--out DIR]
+
+Runs on jax's default device, like the drivers (set JAX_PLATFORMS=cpu for
+a CPU record). ``chip_smoke.py`` at the repo root drives the same
+generator and the same driver flags (:func:`synthesize`,
+:func:`write_dataset`, :func:`game_args`) as the on-chip smoke.
 """
 
 import argparse
@@ -29,21 +35,7 @@ sys.path.insert(0, REPO)
 
 import numpy as np
 
-import jax
-
-if not os.environ.get("PHOTON_ML_TPU_BASELINE_TPU"):
-    jax.config.update("jax_platforms", "cpu")
-if os.environ.get("PHOTON_ML_TPU_SYNC_DISPATCH"):
-    # single-physical-core boxes: async dispatch lets a second program's
-    # device threads occupy the thread pool while an earlier program's
-    # collective rendezvous starves -> livelock -> XLA's termination
-    # timeout kills the run (observed 3x on the 20M run). Synchronous
-    # dispatch serializes programs and removes the hazard.
-    jax.config.update("jax_cpu_enable_async_dispatch", False)
-
-N_RATINGS = 1_000_209
-N_USERS = 6_040
-N_MOVIES = 3_706
+SEED = 20260730
 N_GENRES = 18
 D_MOVIE = N_GENRES + 3  # genres + year + popularity + intercept-less numerics
 
@@ -53,35 +45,36 @@ SCALES = {
     "ml1m": (1_000_209, 6_040, 3_706),
     "ml20m": (20_000_263, 138_493, 26_744),
 }
+N_RATINGS, N_USERS, N_MOVIES = SCALES["ml1m"]  # defaults: the ML-1M shape
 
 
 def log(msg):
     print(f"[{time.strftime('%H:%M:%S')}] {msg}", flush=True)
 
 
-def synthesize(rows, rng):
+def synthesize(rows, rng, n_users=N_USERS, n_movies=N_MOVIES):
     """(user, movie, features, label) with ML-1M-like skew."""
     # power-law activity/popularity (ML-1M: top user ~2300 ratings, median ~96)
-    user_w = rng.pareto(1.3, N_USERS) + 1.0
-    movie_w = rng.pareto(1.1, N_MOVIES) + 1.0
-    users = rng.choice(N_USERS, size=rows, p=user_w / user_w.sum())
-    movies = rng.choice(N_MOVIES, size=rows, p=movie_w / movie_w.sum())
+    user_w = rng.pareto(1.3, n_users) + 1.0
+    movie_w = rng.pareto(1.1, n_movies) + 1.0
+    users = rng.choice(n_users, size=rows, p=user_w / user_w.sum())
+    movies = rng.choice(n_movies, size=rows, p=movie_w / movie_w.sum())
 
     # movie features: 1-3 genres, year, log-popularity
-    genres = np.zeros((N_MOVIES, N_GENRES), np.float32)
-    for m in range(N_MOVIES):
+    genres = np.zeros((n_movies, N_GENRES), np.float32)
+    for m in range(n_movies):
         for g in rng.choice(N_GENRES, size=rng.integers(1, 4), replace=False):
             genres[m, g] = 1.0
-    year = rng.uniform(-1, 1, N_MOVIES).astype(np.float32)
+    year = rng.uniform(-1, 1, n_movies).astype(np.float32)
     pop = np.log1p(movie_w / movie_w.mean()).astype(np.float32)
     movie_feats = np.concatenate(
         [genres, year[:, None], pop[:, None],
-         rng.normal(size=(N_MOVIES, 1)).astype(np.float32)], axis=1,
+         rng.normal(size=(n_movies, 1)).astype(np.float32)], axis=1,
     )  # (M, D_MOVIE)
 
     # planted model: global weights + per-user weights (GLMix structure)
     w_fixed = rng.normal(size=D_MOVIE).astype(np.float32) * 0.8
-    w_user = rng.normal(size=(N_USERS, D_MOVIE)).astype(np.float32) * 0.6
+    w_user = rng.normal(size=(n_users, D_MOVIE)).astype(np.float32) * 0.6
     x = movie_feats[movies]  # (rows, D_MOVIE)
     z = x @ w_fixed + np.einsum("rd,rd->r", x, w_user[users]) + rng.normal(
         scale=0.5, size=rows
@@ -136,9 +129,71 @@ def write_avro(dirpath, users, movies, x, label, rows_slice, parts=4):
         )
 
 
-def main():
-    global N_RATINGS, N_USERS, N_MOVIES
+def write_dataset(out, rows, n_users=N_USERS, n_movies=N_MOVIES):
+    """Synthesize ``rows`` ratings from :data:`SEED` and write them under
+    ``out``: the first 90 % as ``train/`` (4 parts), the held-out 10 % as
+    ``validate/`` (1 part). Returns ``(train_rows, validate_rows)``."""
+    users, movies, x, label = synthesize(
+        rows, np.random.default_rng(SEED), n_users, n_movies
+    )
+    n_train = int(rows * 0.9)
+    write_avro(os.path.join(out, "train"), users, movies, x, label,
+               slice(0, n_train))
+    write_avro(os.path.join(out, "validate"), users, movies, x, label,
+               slice(n_train, rows), parts=1)
+    return n_train, rows - n_train
 
+
+def game_args(out, iterations=2, active_cap=512, full_game=False,
+              bucketed=False, distributed=False):
+    """The ``cli.game_training_driver`` flags of BASELINE config 4 (GLMix:
+    fixed effect + per-user random effect, logistic) — or config 5 with
+    ``full_game`` — over the dataset :func:`write_dataset` left in ``out``."""
+    args = [
+        "--train-input-dirs", os.path.join(out, "train"),
+        "--validate-input-dirs", os.path.join(out, "validate"),
+        "--task-type", "LOGISTIC_REGRESSION",
+        "--output-dir", os.path.join(out, "model"),
+        "--feature-shard-id-to-feature-section-keys-map",
+        "global:movieFeatures|per_user:userMovieFeatures",
+        "--fixed-effect-optimization-configurations",
+        "global:60,1e-9,1.0,1,LBFGS,l2",
+        "--fixed-effect-data-configurations", "global:global,4",
+        "--num-iterations", str(iterations),
+        "--evaluator-type", "AUC",
+        "--delete-output-dir-if-exists", "true",
+    ]
+    if full_game:
+        # config-5 shape: fixed + per-user RE + per-movie RE + factored MF
+        # (per-movie latent over the shared feature space, latent dim 4)
+        args += [
+            "--updating-sequence", "global,per-user,per-movie,mf",
+            "--random-effect-optimization-configurations",
+            "per-user:40,1e-8,1.0,1,LBFGS,l2|"
+            "per-movie:40,1e-8,1.0,1,LBFGS,l2",
+            "--random-effect-data-configurations",
+            f"per-user:userId,per_user,4,{active_cap},0,-1,index_map|"
+            f"per-movie:movieId,per_user,4,{active_cap},0,-1,index_map|"
+            f"mf:movieId,per_user,4,{active_cap},0,-1,IDENTITY",
+            "--factored-random-effect-optimization-configurations",
+            "mf:30,1e-8,1.0,1,LBFGS,l2:30,1e-8,1.0,1,LBFGS,l2:2,4",
+        ]
+    else:
+        args += [
+            "--updating-sequence", "global,per-user",
+            "--random-effect-optimization-configurations",
+            "per-user:40,1e-8,1.0,1,LBFGS,l2",
+            "--random-effect-data-configurations",
+            f"per-user:userId,per_user,4,{active_cap},0,-1,index_map",
+        ]
+    if bucketed:
+        args += ["--bucketed-random-effects", "true"]
+    if distributed:
+        args += ["--distributed", "true"]
+    return args
+
+
+def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", choices=sorted(SCALES), default="ml1m",
                     help="dataset shape: ml1m (default) or ml20m "
@@ -162,11 +217,20 @@ def main():
                          "train/ and validate/ (the 20M write takes ~45 min; "
                          "a crashed training run should not pay it twice)")
     ns = ap.parse_args()
-    N_RATINGS, N_USERS, N_MOVIES = SCALES[ns.scale]
+    n_ratings, n_users, n_movies = SCALES[ns.scale]
     if ns.rows is None:
-        ns.rows = N_RATINGS
+        ns.rows = n_ratings
 
-    rng = np.random.default_rng(20260730)
+    import jax
+
+    if os.environ.get("PHOTON_ML_TPU_SYNC_DISPATCH"):
+        # single-physical-core boxes: async dispatch lets a second program's
+        # device threads occupy the thread pool while an earlier program's
+        # collective rendezvous starves -> livelock -> XLA's termination
+        # timeout kills the run (observed 3x on the 20M run). Synchronous
+        # dispatch serializes programs and removes the hazard.
+        jax.config.update("jax_cpu_enable_async_dispatch", False)
+
     t0 = time.time()
     # a manifest written AFTER the last avro byte is the only acceptable
     # reuse evidence: train/ and validate/ existing proves nothing (the dirs
@@ -191,18 +255,12 @@ def main():
     if reusable:
         log(f"reusing data in {ns.out} (--reuse-data, manifest verified)")
     else:
-        log(f"synthesizing {ns.rows:,} ratings ({N_USERS:,} users x {N_MOVIES:,} movies)")
-        users, movies, x, label = synthesize(ns.rows, rng)
-        n_train = int(ns.rows * 0.9)
-        log(f"writing avro ({n_train:,} train / {ns.rows - n_train:,} validation rows)")
+        log(f"synthesizing + writing {ns.rows:,} ratings "
+            f"({n_users:,} users x {n_movies:,} movies) as avro")
         if os.path.exists(ns.out):
             shutil.rmtree(ns.out)
-        write_avro(os.path.join(ns.out, "train"), users, movies, x, label,
-                   slice(0, n_train))
-        write_avro(
-            os.path.join(ns.out, "validate"), users, movies, x, label,
-            slice(n_train, ns.rows), parts=1,
-        )
+        n_train, n_val = write_dataset(ns.out, ns.rows, n_users, n_movies)
+        log(f"wrote {n_train:,} train / {n_val:,} validation rows")
         with open(manifest_path + ".tmp", "w") as f:
             json.dump(manifest, f)
         os.replace(manifest_path + ".tmp", manifest_path)
@@ -211,49 +269,11 @@ def main():
 
     from photon_ml_tpu.cli.game_training_driver import main as game_main
 
-    args = [
-        "--train-input-dirs", os.path.join(ns.out, "train"),
-        "--validate-input-dirs", os.path.join(ns.out, "validate"),
-        "--task-type", "LOGISTIC_REGRESSION",
-        "--output-dir", os.path.join(ns.out, "model"),
-        "--feature-shard-id-to-feature-section-keys-map",
-        "global:movieFeatures|per_user:userMovieFeatures",
-        "--fixed-effect-optimization-configurations",
-        "global:60,1e-9,1.0,1,LBFGS,l2",
-        "--fixed-effect-data-configurations", "global:global,4",
-        "--num-iterations", str(ns.iterations),
-        "--evaluator-type", "AUC",
-        "--delete-output-dir-if-exists", "true",
-    ]
-    if ns.full_game:
-        # config-5 shape: fixed + per-user RE + per-movie RE + factored MF
-        # (per-movie latent over the shared feature space, latent dim 4)
-        args += [
-            "--updating-sequence", "global,per-user,per-movie,mf",
-            "--random-effect-optimization-configurations",
-            "per-user:40,1e-8,1.0,1,LBFGS,l2|"
-            "per-movie:40,1e-8,1.0,1,LBFGS,l2",
-            "--random-effect-data-configurations",
-            f"per-user:userId,per_user,4,{ns.active_cap},0,-1,index_map|"
-            f"per-movie:movieId,per_user,4,{ns.active_cap},0,-1,index_map|"
-            f"mf:movieId,per_user,4,{ns.active_cap},0,-1,IDENTITY",
-            "--factored-random-effect-optimization-configurations",
-            "mf:30,1e-8,1.0,1,LBFGS,l2:30,1e-8,1.0,1,LBFGS,l2:2,4",
-        ]
-    else:
-        args += [
-            "--updating-sequence", "global,per-user",
-            "--random-effect-optimization-configurations",
-            "per-user:40,1e-8,1.0,1,LBFGS,l2",
-            "--random-effect-data-configurations",
-            f"per-user:userId,per_user,4,{ns.active_cap},0,-1,index_map",
-        ]
-    if ns.bucketed:
-        args += ["--bucketed-random-effects", "true"]
-    if ns.distributed:
-        args += ["--distributed", "true"]
     t0 = time.time()
-    driver = game_main(args)
+    driver = game_main(game_args(
+        ns.out, ns.iterations, ns.active_cap, full_game=ns.full_game,
+        bucketed=ns.bucketed, distributed=ns.distributed,
+    ))
     wall = time.time() - t0
     _, result, metrics = driver.results[driver.best_index]
     auc = float(metrics["AUC"])
@@ -278,8 +298,8 @@ def main():
         "dataset": (
             f"synthetic MovieLens-{ns.scale[2:].upper()}-scale GLMix "
             f"(zero-egress environment: real data unavailable; same "
-            f"shape/skew: {ns.rows:,} ratings, {N_USERS:,} users, "
-            f"{N_MOVIES:,} movies, planted fixed+per-user logistic model)"
+            f"shape/skew: {ns.rows:,} ratings, {n_users:,} users, "
+            f"{n_movies:,} movies, planted fixed+per-user logistic model)"
         ),
         "model": (
             "fixed + per-user RE + per-movie RE + factored MF (latent 4)"
@@ -294,6 +314,7 @@ def main():
         "distributed": bool(ns.distributed),
         "peak_rss_gb": round(peak_rss_gb, 2),
         "platform": platform,
+        "device_kind": jax.devices()[0].device_kind,
         "captured": time.strftime("%Y-%m-%d"),
     }
     with open(baseline_path, "w") as f:

@@ -45,8 +45,7 @@ ALLOWLIST = {
     # infrastructure knobs with no bearing on the training plan
     "photon_ml_tpu/parallel/multihost.py:resolve_barrier_timeout": "infra timeout, not a plan knob",
     "photon_ml_tpu/io/native_build.py:native_enabled": "build-time toggle",
-    "photon_ml_tpu/io/native_build.py:load_native_lib": "XDG cache dir",
-    "photon_ml_tpu/io/offheap.py:_load_native": "XDG cache dir",
+    "photon_ml_tpu/io/native_build.py:build_cached": "XDG cache dir",
     # fault/preemption/retry injection plans: test harness controls that
     # must stay readable without importing the compile layer
     "photon_ml_tpu/resilience/faults.py:active_plan": "fault-injection harness",
