@@ -1,0 +1,123 @@
+"""`correct` has been shown to fail: the control and the planted faults.
+
+Run on the CPU at the families' ``TINY`` sizes:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q -p no:cacheprovider
+
+* the control: the plain reference computed with bfloat16 storage, put in
+  the program's place, fails at least one compared number of every cell;
+* the faults: a whole run of the harness (all but its look for a chip) with
+  the timed path broken underneath comes out with ``correct`` false: the
+  solver returning its state unchanged, and half of the rows left out with
+  the rest counted double.
+The same comparisons were read on the chip at the cells' own sizes
+(PERF.md, "How correct is decided").
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import importlib
+import io
+import json
+import os
+import sys
+import time
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+CELLS = [w["name"] for w in MANIFEST["workloads"]]
+
+
+def _build(cell_name, seed):
+    from benchmark import harness
+
+    _, cell_file, _, config = harness.find_cell(MANIFEST, cell_name)
+    family = importlib.import_module(f"benchmark.families.{config['family']}")
+    return family.build(config, cell_file["job"], seed, tiny=True), config
+
+
+def _run(cell_name, seed):
+    """A run of the harness without its look for a chip; the result line."""
+    import jax
+
+    from benchmark import harness
+
+    jax.clear_caches()
+    out = io.StringIO()
+    args = argparse.Namespace(workload=cell_name, seed=seed, seconds=0.1, trace=0)
+    with contextlib.redirect_stdout(out):
+        code = harness.run(args, time.time(), allow_cpu=True, tiny=True)
+    jax.clear_caches()
+    assert code == 0
+    return json.loads(out.getvalue().strip().splitlines()[-1])
+
+
+def _solver_returns_state_unchanged(monkeypatch):
+    from photon_ml_tpu.optim import lbfgs
+
+    monkeypatch.setattr(lbfgs, "lbfgs_advance_",
+                        lambda vg, state, *a, **k: state)
+
+
+def _half_of_the_rows_left_out(monkeypatch, family):
+    module = importlib.import_module(f"benchmark.families.{family}")
+    sound = module.program_inputs
+
+    def halved(*args, **kwargs):
+        import jax.numpy as jnp
+
+        data = sound(*args, **kwargs)
+        if hasattr(data, "weight"):  # glmix: host GameData
+            data.weight = data.weight.copy()
+            data.weight[1::2] = 0.0
+            data.weight[0::2] = 2.0
+        else:  # glm_sparse: device GLMBatch
+            data.weights = jnp.asarray(data.weights).at[1::2].set(0.0) * 2.0
+        return data
+
+    monkeypatch.setattr(module, "program_inputs", halved)
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_sound_run_is_correct(cell_name):
+    last = _run(cell_name, seed=3)
+    assert last["correct"], last["compared"]
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+@pytest.mark.parametrize("seed", [5, 6, 7])
+def test_control_in_bfloat16_fails(cell_name, seed):
+    cell, _ = _build(cell_name, seed)
+    ref = cell.reference()
+    numbers = cell.compare(cell.reference("bfloat16"), ref)
+    assert cell.limits
+    assert any(numbers[n] > limit for n, limit in cell.limits.items()), numbers
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_half_batch_in_the_reference_fails(cell_name):
+    cell, _ = _build(cell_name, 8)
+    numbers = cell.compare(cell.reference(half_batch=True), cell.reference())
+    assert any(numbers[n] > limit for n, limit in cell.limits.items()), numbers
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_state_unchanged_fails_the_run(cell_name, monkeypatch):
+    _solver_returns_state_unchanged(monkeypatch)
+    last = _run(cell_name, seed=9)
+    assert not last["correct"], last["compared"]
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_half_batch_fails_the_run(cell_name, monkeypatch):
+    _, config = _build(cell_name, 10)
+    _half_of_the_rows_left_out(monkeypatch, config["family"])
+    last = _run(cell_name, seed=10)
+    assert not last["correct"], last["compared"]
