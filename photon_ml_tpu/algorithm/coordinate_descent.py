@@ -26,6 +26,7 @@ from photon_ml_tpu.evaluation.evaluators import Evaluator
 from photon_ml_tpu.resilience import faults as _faults
 from photon_ml_tpu.resilience import preemption as _preemption
 from photon_ml_tpu.types import real_dtype
+from photon_ml_tpu.utils import profiling
 
 if TYPE_CHECKING:  # pragma: no cover
     from photon_ml_tpu.checkpoint import CoordinateDescentCheckpointer
@@ -42,7 +43,12 @@ class CoordinateDescentResult:
     total_scores: Array  # (N,) final summed training scores
     objective_history: List[float]  # after every coordinate update
     validation_history: List[Dict[str, float]]  # per update, per evaluator
-    timings: Dict[str, float]  # coordinate name -> cumulative solve seconds
+    # coordinate name (or "(fused-cycle)") -> cumulative HOST seconds from
+    # dispatching its update to the dispatch returning: the device may still
+    # be solving, so this is not solve time ("(grid)" alone is a combo's
+    # whole wall time, it blocks at the end). It marks which path ran; a
+    # trace's pml.cd.* spans give the times
+    timings: Dict[str, float]
     # coordinate name -> the LAST update's OptResult (vmapped solves carry a
     # leading entity axis; bucketed coordinates a tuple per bucket) — the
     # raw material of the reference's OptimizationTracker summaries
@@ -68,7 +74,6 @@ class CoordinateDescent:
         training_loss: Callable[[Array], Array],
         validation_scorer: Optional[Callable[[Dict[str, Array]], Array]] = None,
         validation_evaluators: Optional[Dict[str, Tuple[Evaluator, dict]]] = None,
-        collect_timings: bool = False,
         fused_cycle: bool = False,
         divergence_guard: Optional["DivergenceGuard"] = None,
     ):
@@ -80,11 +85,12 @@ class CoordinateDescent:
         validation scores; each validation evaluator is (Evaluator, kwargs
         for evaluate, e.g. labels/weights arrays).
 
-        ``collect_timings=True`` blocks on every coordinate's result so the
-        per-coordinate ``timings`` are real solve seconds; the default keeps
-        the whole descent async — objective/validation values stay on device
-        until the end of the run, so dispatch is never serialized on a host
-        round-trip per update.
+        The whole descent stays async: objective/validation values stay on
+        device until the end of the run, so dispatch is never serialized on a
+        host round-trip per update. Where the time goes is read from a
+        profiler trace, not from the host clock: every step below opens a
+        host span (``pml.cd.*``, utils/profiling.py) that a trace lays
+        against the device's idle gaps.
 
         ``fused_cycle=True`` compiles ONE XLA program per full descent
         iteration — every coordinate's update + rescore + objective (+
@@ -104,7 +110,6 @@ class CoordinateDescent:
         self.training_loss = training_loss
         self.validation_scorer = validation_scorer
         self.validation_evaluators = validation_evaluators or {}
-        self.collect_timings = collect_timings
         self.fused_cycle = fused_cycle
         self.divergence_guard = divergence_guard
         self._cycle_fn = None
@@ -367,59 +372,63 @@ class CoordinateDescent:
             def _drain():
                 # one batched transfer each, like run()'s _drain — never
                 # one device-to-host sync per scalar
-                if objective_dev:
-                    objective_history.extend(
-                        float(o[0]) for o in jax.device_get(objective_dev)
-                    )
-                    objective_dev.clear()
-                if validation_dev:
-                    validation_history.extend(
-                        {k: float(v[0]) for k, v in m.items()}
-                        for m in jax.device_get(validation_dev)
-                    )
-                    validation_dev.clear()
+                with profiling.span("pml.cd.drain"):
+                    if objective_dev:
+                        objective_history.extend(
+                            float(o[0]) for o in jax.device_get(objective_dev)
+                        )
+                        objective_dev.clear()
+                    if validation_dev:
+                        validation_history.extend(
+                            {k: float(v[0]) for k, v in m.items()}
+                            for m in jax.device_get(validation_dev)
+                        )
+                        validation_dev.clear()
 
             def _save(step):
-                from photon_ml_tpu.checkpoint import CheckpointState
+                with profiling.span("pml.cd.checkpoint", step=step):
+                    from photon_ml_tpu.checkpoint import CheckpointState
 
-                _drain()
-                ck.save(
-                    CheckpointState(
-                        step=step,
-                        params=params,
-                        scores=scores,
-                        total_scores=total,
-                        objective_history=objective_history,
-                        validation_history=validation_history,
+                    _drain()
+                    ck.save(
+                        CheckpointState(
+                            step=step,
+                            params=params,
+                            scores=scores,
+                            total_scores=total,
+                            objective_history=objective_history,
+                            validation_history=validation_history,
+                        )
                     )
-                )
 
             for it in range(start_iter, num_iterations):
-                step = (it + 1) * n_coords
-                params, scores, total, objs, vals = cycle_v(
-                    params, scores, total, lam_i
-                )
-                objective_dev.extend(objs)
-                validation_dev.extend(vals)
-                is_last = it == num_iterations - 1
-                saved_here = ck is not None and (
-                    step % ck.save_every < n_coords or is_last
-                )
-                if saved_here:
-                    _save(step)
-                if not is_last and _preemption.check(
-                    "cycle", step=step, combo=i
-                ):
-                    if ck is not None:
-                        if not saved_here:
-                            _save(step)
-                        if hasattr(ck, "wait"):
-                            ck.wait()
-                    raise _preemption.Preempted(
-                        f"preempted at grid iteration boundary (combo {i}, "
-                        f"step {step}): {_preemption.reason()}",
-                        site="cycle",
+                with profiling.span("pml.cd.iteration", iteration=it, combo=i):
+                    step = (it + 1) * n_coords
+                    with profiling.span("pml.cd.cycle", iteration=it):
+                        params, scores, total, objs, vals = cycle_v(
+                            params, scores, total, lam_i
+                        )
+                    objective_dev.extend(objs)
+                    validation_dev.extend(vals)
+                    is_last = it == num_iterations - 1
+                    saved_here = ck is not None and (
+                        step % ck.save_every < n_coords or is_last
                     )
+                    if saved_here:
+                        _save(step)
+                    if not is_last and _preemption.check(
+                        "cycle", step=step, combo=i
+                    ):
+                        if ck is not None:
+                            if not saved_here:
+                                _save(step)
+                            if hasattr(ck, "wait"):
+                                ck.wait()
+                        raise _preemption.Preempted(
+                            f"preempted at grid iteration boundary (combo {i}, "
+                            f"step {step}): {_preemption.reason()}",
+                            site="cycle",
+                        )
             jax.block_until_ready(total)
             elapsed = time.perf_counter() - t0
 
@@ -463,6 +472,13 @@ class CoordinateDescent:
         indistinguishable from a converged one to everything downstream.
         Every frozen name must be warm-started (freezing an uninitialized
         coordinate would freeze zeros)."""
+        with profiling.span("pml.cd.run", iterations=num_iterations):
+            return self._run(
+                num_iterations, num_rows, checkpointer, initial_params, frozen
+            )
+
+    def _run(self, num_iterations, num_rows, checkpointer, initial_params,
+             frozen) -> CoordinateDescentResult:
         names = list(self.coordinates)
         frozen = frozenset(frozen or ())
         if frozen:
@@ -539,15 +555,16 @@ class CoordinateDescent:
 
         def _drain():
             """Pull accumulated device scalars to host (one batched transfer)."""
-            if objective_dev:
-                objective_history.extend(float(v) for v in jax.device_get(objective_dev))
-                objective_dev.clear()
-            if validation_dev:
-                host = jax.device_get(validation_dev)
-                validation_history.extend(
-                    {k: float(v) for k, v in m.items()} for m in host
-                )
-                validation_dev.clear()
+            with profiling.span("pml.cd.drain"):
+                if objective_dev:
+                    objective_history.extend(float(v) for v in jax.device_get(objective_dev))
+                    objective_dev.clear()
+                if validation_dev:
+                    host = jax.device_get(validation_dev)
+                    validation_history.extend(
+                        {k: float(v) for k, v in m.items()} for m in host
+                    )
+                    validation_dev.clear()
 
         def _emergency_save(at_step: int, partial=None, already_saved=False):
             """Drain-to-boundary checkpoint for a preemption exit: make the
@@ -560,27 +577,28 @@ class CoordinateDescent:
                 return None
             from photon_ml_tpu.checkpoint import STEP_PREFIX, CheckpointState
 
-            _drain()
-            # the boundary save a moment ago already covers this step
-            path = os.path.join(
-                checkpointer.directory, f"{STEP_PREFIX}{at_step}"
-            )
-            if not already_saved or partial is not None:
-                path = checkpointer.save(
-                    CheckpointState(
-                        step=at_step,
-                        params=params,
-                        scores=scores,
-                        total_scores=total,
-                        objective_history=objective_history,
-                        validation_history=validation_history,
-                        partial=partial,
-                    )
+            with profiling.span("pml.cd.checkpoint", step=at_step):
+                _drain()
+                # the boundary save a moment ago already covers this step
+                path = os.path.join(
+                    checkpointer.directory, f"{STEP_PREFIX}{at_step}"
                 )
-            # the fence: an async commit must be durable before the process
-            # exits on the preemption path
-            if hasattr(checkpointer, "wait"):
-                checkpointer.wait()
+                if not already_saved or partial is not None:
+                    path = checkpointer.save(
+                        CheckpointState(
+                            step=at_step,
+                            params=params,
+                            scores=scores,
+                            total_scores=total,
+                            objective_history=objective_history,
+                            validation_history=validation_history,
+                            partial=partial,
+                        )
+                    )
+                # the fence: an async commit must be durable before the process
+                # exits on the preemption path
+                if hasattr(checkpointer, "wait"):
+                    checkpointer.wait()
             return path
 
         guard = self.divergence_guard
@@ -600,79 +618,81 @@ class CoordinateDescent:
                 step = (it + 1) * n_coords
                 if step <= start_step:
                     continue
-                t0 = time.perf_counter()
-                new_params, new_scores, new_total, objs, vals = self._cycle_fn(
-                    params, scores, total
-                )
-                if guard is not None:
-                    # iteration granularity: the per-update states live
-                    # inside the compiled cycle, so a non-finite outcome
-                    # rolls the WHOLE iteration back to its entry state
-                    new_params, new_total, ok = guard.filter_update(
-                        "(fused-cycle)", step, new_params, new_total, params, total
-                    )
-                    if not ok:
-                        new_scores = scores
-                        # re-evaluate the rolled-back state once and repeat
-                        # it per update so histories (and the step-aligned
-                        # checkpoint contract) keep one entry per update
-                        obj = self.training_loss(total) + sum(
-                            self.coordinates[n].regularization_term(params[n])
-                            for n in names
+                with profiling.span("pml.cd.iteration", iteration=it):
+                    t0 = time.perf_counter()
+                    with profiling.span("pml.cd.cycle", iteration=it):
+                        new_params, new_scores, new_total, objs, vals = self._cycle_fn(
+                            params, scores, total
                         )
-                        objs = [obj] * n_coords
-                        if self.validation_scorer is not None:
-                            v_scores = self.validation_scorer(params)
-                            vals = [
-                                {
-                                    key: ev.evaluate(v_scores, **kw)
-                                    for key, (ev, kw) in self.validation_evaluators.items()
-                                }
-                            ] * n_coords
-                        else:
-                            vals = []
-                params, scores, total = new_params, new_scores, new_total
-                if self.collect_timings:
-                    jax.block_until_ready(total)
-                timings["(fused-cycle)"] = (
-                    timings.get("(fused-cycle)", 0.0) + time.perf_counter() - t0
-                )
-                objective_dev.extend(objs)
-                validation_dev.extend(vals)
-                is_last = it == num_iterations - 1
-                # steps advance n_coords at a time here: fire whenever a
-                # save_every boundary was CROSSED this iteration, not only
-                # when step lands exactly on a multiple
-                saved_here = checkpointer is not None and (
-                    step % checkpointer.save_every < n_coords or is_last
-                )
-                if saved_here:
-                    from photon_ml_tpu.checkpoint import CheckpointState
+                    if guard is not None:
+                        with profiling.span("pml.cd.guard", coordinate="(fused-cycle)"):
+                            # iteration granularity: the per-update states live
+                            # inside the compiled cycle, so a non-finite outcome
+                            # rolls the WHOLE iteration back to its entry state
+                            new_params, new_total, ok = guard.filter_update(
+                                "(fused-cycle)", step, new_params, new_total, params, total
+                            )
+                            if not ok:
+                                new_scores = scores
+                                # re-evaluate the rolled-back state once and repeat
+                                # it per update so histories (and the step-aligned
+                                # checkpoint contract) keep one entry per update
+                                obj = self.training_loss(total) + sum(
+                                    self.coordinates[n].regularization_term(params[n])
+                                    for n in names
+                                )
+                                objs = [obj] * n_coords
+                                if self.validation_scorer is not None:
+                                    v_scores = self.validation_scorer(params)
+                                    vals = [
+                                        {
+                                            key: ev.evaluate(v_scores, **kw)
+                                            for key, (ev, kw) in self.validation_evaluators.items()
+                                        }
+                                    ] * n_coords
+                                else:
+                                    vals = []
+                    params, scores, total = new_params, new_scores, new_total
+                    timings["(fused-cycle)"] = (
+                        timings.get("(fused-cycle)", 0.0) + time.perf_counter() - t0
+                    )
+                    objective_dev.extend(objs)
+                    validation_dev.extend(vals)
+                    is_last = it == num_iterations - 1
+                    # steps advance n_coords at a time here: fire whenever a
+                    # save_every boundary was CROSSED this iteration, not only
+                    # when step lands exactly on a multiple
+                    saved_here = checkpointer is not None and (
+                        step % checkpointer.save_every < n_coords or is_last
+                    )
+                    if saved_here:
+                        with profiling.span("pml.cd.checkpoint", step=step):
+                            from photon_ml_tpu.checkpoint import CheckpointState
 
-                    _drain()
-                    checkpointer.save(
-                        CheckpointState(
-                            step=step,
-                            params=params,
-                            scores=scores,
-                            total_scores=total,
-                            objective_history=objective_history,
-                            validation_history=validation_history,
+                            _drain()
+                            checkpointer.save(
+                                CheckpointState(
+                                    step=step,
+                                    params=params,
+                                    scores=scores,
+                                    total_scores=total,
+                                    objective_history=objective_history,
+                                    validation_history=validation_history,
+                                )
+                            )
+                    # cooperative preemption: iteration boundaries are the fused
+                    # cycle's only safe points (per-update state lives inside
+                    # the compiled program) — and they are iteration-ALIGNED, so
+                    # an emergency checkpoint here always satisfies the fused
+                    # resume contract above
+                    if not is_last and _preemption.check("cycle", step=step):
+                        path = _emergency_save(step, already_saved=saved_here)
+                        raise _preemption.Preempted(
+                            f"preempted at iteration boundary (step {step}): "
+                            f"{_preemption.reason()}",
+                            site="cycle",
+                            checkpoint_path=path,
                         )
-                    )
-                # cooperative preemption: iteration boundaries are the fused
-                # cycle's only safe points (per-update state lives inside
-                # the compiled program) — and they are iteration-ALIGNED, so
-                # an emergency checkpoint here always satisfies the fused
-                # resume contract above
-                if not is_last and _preemption.check("cycle", step=step):
-                    path = _emergency_save(step, already_saved=saved_here)
-                    raise _preemption.Preempted(
-                        f"preempted at iteration boundary (step {step}): "
-                        f"{_preemption.reason()}",
-                        site="cycle",
-                        checkpoint_path=path,
-                    )
             _drain()
             return CoordinateDescentResult(
                 coefficients=params,
@@ -689,123 +709,128 @@ class CoordinateDescent:
 
         step = 0
         for it in range(num_iterations):
-            skip_rest_of_cycle = False
-            for name in names:
-                step += 1
-                if step <= start_step:
-                    continue  # already completed before the restart
-                if not skip_rest_of_cycle and name not in frozen:
-                    partial = total - scores[name]  # sum of the OTHER coordinates
-                    t0 = time.perf_counter()
-                    try:
-                        if midstep is not None and step == int(
-                            midstep["meta"].get("resume_step", -1)
-                        ):
-                            # the emergency checkpoint interrupted THIS step:
-                            # hand the in-flight coordinate its paused state
-                            # (scheduler carries / per-block progress) so it
-                            # finishes instead of restarting — bitwise the
-                            # same coefficients either way
-                            mid_name = midstep["meta"].get("coordinate")
-                            if mid_name != name:
-                                raise ValueError(
-                                    f"checkpoint partial targets coordinate "
-                                    f"{mid_name!r} at step {step} but the "
-                                    f"sequence reaches {name!r} — updating "
-                                    "sequence changed; refusing to resume"
+            with profiling.span("pml.cd.iteration", iteration=it):
+                skip_rest_of_cycle = False
+                for name in names:
+                    step += 1
+                    if step <= start_step:
+                        continue  # already completed before the restart
+                    if not skip_rest_of_cycle and name not in frozen:
+                        partial = total - scores[name]  # sum of the OTHER coordinates
+                        t0 = time.perf_counter()
+                        with profiling.span("pml.cd.update", coordinate=name):
+                            try:
+                                if midstep is not None and step == int(
+                                    midstep["meta"].get("resume_step", -1)
+                                ):
+                                    # the emergency checkpoint interrupted THIS step:
+                                    # hand the in-flight coordinate its paused state
+                                    # (scheduler carries / per-block progress) so it
+                                    # finishes instead of restarting — bitwise the
+                                    # same coefficients either way
+                                    mid_name = midstep["meta"].get("coordinate")
+                                    if mid_name != name:
+                                        raise ValueError(
+                                            f"checkpoint partial targets coordinate "
+                                            f"{mid_name!r} at step {step} but the "
+                                            f"sequence reaches {name!r} — updating "
+                                            "sequence changed; refusing to resume"
+                                        )
+                                    new_params, trackers[name] = self.coordinates[
+                                        name
+                                    ].update(partial, params[name], resume=midstep)
+                                    midstep = None
+                                else:
+                                    new_params, trackers[name] = self._update_fns[name](
+                                        partial, params[name]
+                                    )
+                            except _preemption.Preempted as e:
+                                # an inner loop drained at a block/chunk boundary:
+                                # checkpoint the completed steps PLUS the in-flight
+                                # coordinate's progress, then unwind to the driver
+                                payload = dict(e.partial) if e.partial else None
+                                if payload is not None:
+                                    payload["meta"] = dict(
+                                        payload.get("meta") or {},
+                                        coordinate=name,
+                                        resume_step=step,
+                                    )
+                                e.checkpoint_path = _emergency_save(
+                                    step - 1, partial=payload
                                 )
-                            new_params, trackers[name] = self.coordinates[
-                                name
-                            ].update(partial, params[name], resume=midstep)
-                            midstep = None
-                        else:
-                            new_params, trackers[name] = self._update_fns[name](
-                                partial, params[name]
+                                raise
+                        # chaos-test hook: a kind="nan" fault at this site
+                        # corrupts the update exactly like a diverged solve
+                        new_params = _faults.corrupt(
+                            "optim.step", new_params, coordinate=name, step=step
+                        )
+                        with profiling.span("pml.cd.score", coordinate=name):
+                            new_score = self._score_fns[name](new_params)
+                        if guard is not None:
+                            with profiling.span("pml.cd.guard", coordinate=name):
+                                new_params, new_score, ok = guard.filter_update(
+                                    name, step, new_params, new_score,
+                                    params[name], scores[name],
+                                )
+                                if not ok and guard.mode == "skip_cycle":
+                                    skip_rest_of_cycle = True
+                        timings[name] += time.perf_counter() - t0
+                        params[name] = new_params
+                        total = partial + new_score
+                        scores[name] = new_score
+                    # else: guard abandoned this cycle OR the coordinate is
+                    # frozen (delta retrain) — state is unchanged, but
+                    # histories and checkpoints below stay step-aligned
+
+                    # objective = loss(total scores) + sum of reg terms
+                    # (CoordinateDescent.scala:172-178) — stays on device
+                    with profiling.span("pml.cd.objective", coordinate=name):
+                        obj = self.training_loss(total) + sum(
+                            self.coordinates[n].regularization_term(params[n]) for n in names
+                        )
+                        objective_dev.append(obj)
+
+                    if self.validation_scorer is not None:
+                        with profiling.span("pml.cd.validate", coordinate=name):
+                            v_scores = self.validation_scorer(params)
+                            validation_dev.append(
+                                {
+                                    key: ev.evaluate(v_scores, **kw)
+                                    for key, (ev, kw) in self.validation_evaluators.items()
+                                }
                             )
-                    except _preemption.Preempted as e:
-                        # an inner loop drained at a block/chunk boundary:
-                        # checkpoint the completed steps PLUS the in-flight
-                        # coordinate's progress, then unwind to the driver
-                        payload = dict(e.partial) if e.partial else None
-                        if payload is not None:
-                            payload["meta"] = dict(
-                                payload.get("meta") or {},
-                                coordinate=name,
-                                resume_step=step,
+
+                    is_last = it == num_iterations - 1 and name == names[-1]
+                    saved_here = checkpointer is not None and (
+                        step % checkpointer.save_every == 0 or is_last
+                    )
+                    if saved_here:
+                        with profiling.span("pml.cd.checkpoint", step=step):
+                            from photon_ml_tpu.checkpoint import CheckpointState
+
+                            _drain()
+                            checkpointer.save(
+                                CheckpointState(
+                                    step=step,
+                                    params=params,
+                                    scores=scores,
+                                    total_scores=total,
+                                    objective_history=objective_history,
+                                    validation_history=validation_history,
+                                )
                             )
-                        e.checkpoint_path = _emergency_save(
-                            step - 1, partial=payload
+                    # cooperative preemption: every update boundary is a safe
+                    # drain point — make the finished step durable and unwind
+                    # with the distinct exit path (the final update just
+                    # finishes; there is nothing left to preempt)
+                    if not is_last and _preemption.check("cycle", step=step):
+                        path = _emergency_save(step, already_saved=saved_here)
+                        raise _preemption.Preempted(
+                            f"preempted at update boundary (step {step}): "
+                            f"{_preemption.reason()}",
+                            site="cycle",
+                            checkpoint_path=path,
                         )
-                        raise
-                    # chaos-test hook: a kind="nan" fault at this site
-                    # corrupts the update exactly like a diverged solve
-                    new_params = _faults.corrupt(
-                        "optim.step", new_params, coordinate=name, step=step
-                    )
-                    new_score = self._score_fns[name](new_params)
-                    if guard is not None:
-                        new_params, new_score, ok = guard.filter_update(
-                            name, step, new_params, new_score,
-                            params[name], scores[name],
-                        )
-                        if not ok and guard.mode == "skip_cycle":
-                            skip_rest_of_cycle = True
-                    if self.collect_timings:
-                        jax.block_until_ready(new_score)
-                    timings[name] += time.perf_counter() - t0
-                    params[name] = new_params
-                    total = partial + new_score
-                    scores[name] = new_score
-                # else: guard abandoned this cycle OR the coordinate is
-                # frozen (delta retrain) — state is unchanged, but
-                # histories and checkpoints below stay step-aligned
-
-                # objective = loss(total scores) + sum of reg terms
-                # (CoordinateDescent.scala:172-178) — stays on device
-                obj = self.training_loss(total) + sum(
-                    self.coordinates[n].regularization_term(params[n]) for n in names
-                )
-                objective_dev.append(obj)
-
-                if self.validation_scorer is not None:
-                    v_scores = self.validation_scorer(params)
-                    validation_dev.append(
-                        {
-                            key: ev.evaluate(v_scores, **kw)
-                            for key, (ev, kw) in self.validation_evaluators.items()
-                        }
-                    )
-
-                is_last = it == num_iterations - 1 and name == names[-1]
-                saved_here = checkpointer is not None and (
-                    step % checkpointer.save_every == 0 or is_last
-                )
-                if saved_here:
-                    from photon_ml_tpu.checkpoint import CheckpointState
-
-                    _drain()
-                    checkpointer.save(
-                        CheckpointState(
-                            step=step,
-                            params=params,
-                            scores=scores,
-                            total_scores=total,
-                            objective_history=objective_history,
-                            validation_history=validation_history,
-                        )
-                    )
-                # cooperative preemption: every update boundary is a safe
-                # drain point — make the finished step durable and unwind
-                # with the distinct exit path (the final update just
-                # finishes; there is nothing left to preempt)
-                if not is_last and _preemption.check("cycle", step=step):
-                    path = _emergency_save(step, already_saved=saved_here)
-                    raise _preemption.Preempted(
-                        f"preempted at update boundary (step {step}): "
-                        f"{_preemption.reason()}",
-                        site="cycle",
-                        checkpoint_path=path,
-                    )
 
         _drain()
         return CoordinateDescentResult(

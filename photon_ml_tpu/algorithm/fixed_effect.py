@@ -43,6 +43,7 @@ class FixedEffectCoordinate:
     def initial_coefficients(self) -> Array:
         return jnp.zeros((self.dim,), real_dtype())
 
+    @jax.named_scope("pml.fe.solve")
     def update(self, residual_offsets: Array, init_coefficients: Array,
                reg_weight: Optional[Array] = None) -> Tuple[Array, OptResult]:
         """Solve on residuals: offsets = base + other coordinates' scores.
@@ -68,6 +69,7 @@ class FixedEffectCoordinate:
         )
         return model.coefficients.means, result
 
+    @jax.named_scope("pml.fe.score")
     def score(self, coefficients: Array) -> Array:
         """Raw margins x.w (NO offset, NO mean function): GAME scores are
         additive margin contributions (FixedEffectModel.scala:91-100)."""

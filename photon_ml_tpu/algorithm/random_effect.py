@@ -54,6 +54,11 @@ def entity_lane_fns(task, optimizer, optimizer_config, regularization,
         convergence or the absolute iteration ``limit`` (traced ok);
       * ``result_of(state) -> OptResult`` — view of a final state (works on
         lane-stacked states too).
+
+    The three solving closures run under the device scope
+    ``pml.re.lane_solve``, so the one-shot program, the scheduler's
+    init/chunk programs and the fused rung program all show the per-lane
+    solve under one name in a trace.
     """
     from photon_ml_tpu.optim.lbfgs import (
         lbfgs_advance_,
@@ -87,14 +92,17 @@ def entity_lane_fns(task, optimizer, optimizer_config, regularization,
             batch = GLMBatch(feats_of(x), y, off_e, w_e)
             return lambda wt, v: obj.hessian_vector(wt, v, batch, norm, l2)
 
+        @jax.named_scope("pml.re.lane_solve")
         def solve_one(x, y, off_e, w_e, w0):
             return tron_minimize_(
                 vg_of(x, y, off_e, w_e), hvp_of(x, y, off_e, w_e), w0, cfg
             )
 
+        @jax.named_scope("pml.re.lane_solve")
         def init_one(x, y, off_e, w_e, w0):
             return tron_init_(vg_of(x, y, off_e, w_e), w0, cfg)
 
+        @jax.named_scope("pml.re.lane_solve")
         def advance_one(x, y, off_e, w_e, state, limit):
             return tron_advance_(
                 vg_of(x, y, off_e, w_e), hvp_of(x, y, off_e, w_e), state, cfg,
@@ -103,12 +111,15 @@ def entity_lane_fns(task, optimizer, optimizer_config, regularization,
 
         return solve_one, init_one, advance_one, tron_result
 
+    @jax.named_scope("pml.re.lane_solve")
     def solve_one(x, y, off_e, w_e, w0):
         return lbfgs_minimize_(vg_of(x, y, off_e, w_e), w0, cfg, l1_weight=l1)
 
+    @jax.named_scope("pml.re.lane_solve")
     def init_one(x, y, off_e, w_e, w0):
         return lbfgs_init_(vg_of(x, y, off_e, w_e), w0, cfg, l1_weight=l1)
 
+    @jax.named_scope("pml.re.lane_solve")
     def advance_one(x, y, off_e, w_e, state, limit):
         return lbfgs_advance_(
             vg_of(x, y, off_e, w_e), state, cfg, l1_weight=l1,
@@ -353,6 +364,7 @@ class RandomEffectCoordinate:
         return variances_from_hessian_diag(diag)
 
     # ------------------------------------------------------------------
+    @jax.named_scope("pml.re.score")
     def score(self, coefficients: Array) -> Array:
         """Global (N,) scores for ALL rows (active + passive).
 

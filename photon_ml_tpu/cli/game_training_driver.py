@@ -1337,7 +1337,7 @@ class GameTrainingDriver:
         from photon_ml_tpu.utils.profiling import maybe_trace
 
         try:
-            with self.timer.measure("shared-compile-grid"), maybe_trace("game-grid"):
+            with maybe_trace("game-grid"), self.timer.measure("shared-compile-grid"):
                 grid_results = cd.run_grid(
                     lam, p.num_iterations, self.train_data.num_rows,
                     init_params=init_params,
@@ -1430,7 +1430,7 @@ class GameTrainingDriver:
             from photon_ml_tpu.utils.profiling import maybe_trace
 
             try:
-                with self.timer.measure(f"combo-{i}"), maybe_trace(f"game-combo-{i}"):
+                with maybe_trace(f"game-combo-{i}"), self.timer.measure(f"combo-{i}"):
                     result = cd.run(
                         p.num_iterations, self.train_data.num_rows,
                         checkpointer,

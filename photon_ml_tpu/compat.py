@@ -72,6 +72,18 @@ def enable_persistent_cache(path: Optional[str] = None) -> str:
     # the min-compile-seconds filter
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    # jax keys the cache on the program WITHOUT its metadata unless told
+    # otherwise, so a warm cache hands back whichever build compiled the
+    # program first, with that build's scope names (jax.named_scope, the
+    # names a trace is read by: PERF.md, "Spans, scopes and counters") or
+    # with none. With the metadata in the key a trace shows the names of
+    # the code that ran; the price is a recompile when a traced line moves.
+    # One frame per operation, not the callers' stack: the key must not
+    # depend on which line of which entry point made the first call.
+    # (jax_include_full_tracebacks_in_locations=False would do that too,
+    # but in jax 0.9.0 it also drops the scope names from op_name.)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     # jax LATCHES cache-used at the first compile of the process; a driver
     # that touched the device before reaching this call (device summary,
     # data placement) would silently never cache without a reset

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 import threading
 import time
 from typing import Callable, Dict, Optional
@@ -195,6 +196,12 @@ class CompileWatermark:
 compile_stats = CompileStats()
 
 
+def module_name(site: str) -> str:
+    """The function name a site's program is jitted under (its XLA module is
+    ``jit_<this>``)."""
+    return re.sub(r"[^0-9A-Za-z]+", "_", site).strip("_")
+
+
 def instrumented_jit(
     fn: Callable,
     site: Optional[str] = None,
@@ -207,6 +214,11 @@ def instrumented_jit(
     this site). Calls that skip the body hit the compiled executable.
     ``jit_kwargs`` pass through (``static_argnames``, ``donate_argnums``,
     ...), so instrumentation composes with donation.
+
+    A ``site`` also names the XLA module: the jitted function's ``__name__``
+    is the site with every run of other characters turned into ``_``
+    (``cd.update[per-user]`` -> ``jit_cd_update_per_user``), so a profiler
+    trace shows which program ran and not ``jit__lambda_`` or ``jit_impl``.
     """
     name = site or f"{fn.__module__}.{getattr(fn, '__qualname__', fn.__name__)}"
 
@@ -215,6 +227,8 @@ def instrumented_jit(
         return fn(*args, **kwargs)
 
     functools.update_wrapper(traced, fn)
+    if site:
+        traced.__name__ = traced.__qualname__ = module_name(site)
     jitted = jax.jit(traced, **jit_kwargs)
 
     @functools.wraps(fn)

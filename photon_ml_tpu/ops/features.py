@@ -25,6 +25,10 @@ Both expose the same protocol so the objective is layout-agnostic:
   sq_rmatvec(d)    -> (X*X)^T @ d                shape (D,)  (Hessian diag)
   col_stats()      -> per-column summary helpers used by normalization
 
+Each of the three runs under the device scope ``pml.features.<name>``, the
+same name whatever the layout, so a trace attributes gather, scatter-add and
+matmul time to the layer and a kernel swap keeps the name.
+
 Reference behavior spec: function/ValueAndGradientAggregator.scala:87-139,
 HessianVectorAggregator.scala:90-116 (re-derived algebra, batched here).
 """
@@ -73,6 +77,7 @@ class DenseFeatures:
     def dim(self) -> int:
         return self.matrix.shape[1]
 
+    @jax.named_scope("pml.features.matvec")
     def matvec(self, w: Array) -> Array:
         acc = _acc_dtype(self.matrix.dtype)
         return jnp.dot(
@@ -80,6 +85,7 @@ class DenseFeatures:
             preferred_element_type=acc,
         )
 
+    @jax.named_scope("pml.features.rmatvec")
     def rmatvec(self, d: Array) -> Array:
         acc = _acc_dtype(self.matrix.dtype)
         return jnp.dot(
@@ -87,6 +93,7 @@ class DenseFeatures:
             preferred_element_type=acc,
         )
 
+    @jax.named_scope("pml.features.sq_rmatvec")
     def sq_rmatvec(self, d: Array) -> Array:
         acc = _acc_dtype(self.matrix.dtype)
         sq = jnp.square(self.matrix.astype(acc))
@@ -160,11 +167,13 @@ class SparseFeatures:
             t_val=jnp.asarray(val[order]),
         )
 
+    @jax.named_scope("pml.features.matvec")
     def matvec(self, w: Array) -> Array:
         acc = _acc_dtype(self.values.dtype)
         prods = w[self.indices].astype(acc) * self.values.astype(acc)
         return jnp.sum(prods, axis=-1)
 
+    @jax.named_scope("pml.features.rmatvec")
     def rmatvec(self, d: Array) -> Array:
         acc = _acc_dtype(self.values.dtype)
         if self.t_idx is not None:
@@ -178,6 +187,7 @@ class SparseFeatures:
             contrib.reshape(-1)
         )
 
+    @jax.named_scope("pml.features.sq_rmatvec")
     def sq_rmatvec(self, d: Array) -> Array:
         acc = _acc_dtype(self.values.dtype)
         if self.t_idx is not None:

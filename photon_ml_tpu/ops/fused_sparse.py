@@ -230,6 +230,7 @@ class SparseSlab:
         return self.idx.shape[-1]
 
     # -- Features protocol (lane-level (M, K); batched shapes also work) ----
+    @jax.named_scope("pml.features.matvec")
     def matvec(self, w: Array) -> Array:
         acc = _acc_dtype(self.val.dtype)
         return jnp.sum(w[self.idx].astype(acc) * self.val.astype(acc), axis=-1)
@@ -239,11 +240,13 @@ class SparseSlab:
         contrib = self.val.astype(acc) * d.astype(acc)[..., None]
         return self.idx.reshape(-1), contrib.reshape(-1)
 
+    @jax.named_scope("pml.features.rmatvec")
     def rmatvec(self, d: Array) -> Array:
         acc = _acc_dtype(self.val.dtype)
         flat_idx, flat_contrib = self._flat_contrib(d)
         return self._transpose_apply(flat_idx, flat_contrib, acc)
 
+    @jax.named_scope("pml.features.sq_rmatvec")
     def sq_rmatvec(self, d: Array) -> Array:
         acc = _acc_dtype(self.val.dtype)
         contrib = jnp.square(self.val.astype(acc)) * d.astype(acc)[..., None]

@@ -118,6 +118,10 @@ class GLMObjective:
 
     All methods take ``l2_weight`` as a (traceable) scalar so a lambda-grid
     sweep does not retrigger compilation.
+
+    Each of value, value_and_grad, hessian_vector and hessian_diagonal runs
+    under one device scope (``pml.objective.*``) whichever branch computes
+    it, so the fused kernels keep the generic path's name in a trace.
     """
 
     loss: PointwiseLoss
@@ -130,6 +134,7 @@ class GLMObjective:
         return batch.features.matvec(w_eff) + norm.margin_shift(w_eff) + batch.offsets
 
     # -- value --------------------------------------------------------------
+    @jax.named_scope("pml.objective.value")
     def value(self, w, batch, norm, l2_weight=0.0) -> Array:
         z = self.margins(w, batch, norm)
         total = _row_sum(
@@ -139,6 +144,7 @@ class GLMObjective:
         return total + 0.5 * l2_weight * jnp.sum(jnp.square(w))  # lint: bitwise-reduction — l2 reg over the fixed (D,) w; pinned arithmetic of the bitwise gates
 
     # -- value + gradient (one fused pass) ----------------------------------
+    @jax.named_scope("pml.objective.value_and_grad")
     def value_and_grad(self, w, batch, norm, l2_weight=0.0) -> Tuple[Array, Array]:
         w_eff = norm.effective_coefficients(w)
         if self._use_fused(batch):
@@ -208,6 +214,7 @@ class GLMObjective:
         return self.value_and_grad(w, batch, norm, l2_weight)[1]
 
     # -- Hessian-vector product (TRON's CG inner loop) ----------------------
+    @jax.named_scope("pml.objective.hvp")
     def hessian_vector(self, w, v, batch, norm, l2_weight=0.0) -> Array:
         """H(w) @ v.  (HessianVectorAggregator.scala:90-116 algebra, batched.)"""
         w_eff = norm.effective_coefficients(w)
@@ -239,6 +246,7 @@ class GLMObjective:
         return hv + l2_weight * v
 
     # -- Hessian diagonal (coefficient variance: 1/H_jj) ---------------------
+    @jax.named_scope("pml.objective.hessian_diagonal")
     def hessian_diagonal(self, w, batch, norm, l2_weight=0.0) -> Array:
         """diag(H) = sum_i d2_i * ((x_i - shift) * factor)_j^2  + l2.
 

@@ -66,6 +66,7 @@ from jax import lax
 from photon_ml_tpu.compile import instrumented_jit
 from photon_ml_tpu.optim.common import OptResult
 from photon_ml_tpu.resilience import preemption
+from photon_ml_tpu.utils import profiling
 
 logger = logging.getLogger(__name__)
 
@@ -239,14 +240,16 @@ def device_solve(
             if preemption.requested()
             else max_iter
         )
-        state, lim_d, exec_d, dch_d, act_d = _rung_step(
-            data, state, jnp.int32(limit), jnp.int32(horizon),
-            jnp.int32(target), jnp.int32(chunk), rung=rung, **cfg
-        )
+        with profiling.span("pml.rung.step", rung=rung, limit=limit):
+            state, lim_d, exec_d, dch_d, act_d = _rung_step(
+                data, state, jnp.int32(limit), jnp.int32(horizon),
+                jnp.int32(target), jnp.int32(chunk), rung=rung, **cfg
+            )
         # the ONLY per-hop D2H: four scalars (the state stays on device)
-        new_limit, exec_d, dch_d, act_d = (
-            int(v) for v in jax.device_get((lim_d, exec_d, dch_d, act_d))
-        )
+        with profiling.span("pml.rung.sync"):
+            new_limit, exec_d, dch_d, act_d = (
+                int(v) for v in jax.device_get((lim_d, exec_d, dch_d, act_d))
+            )
         chunks.append(
             ChunkRecord(
                 chunk=len(chunks),

@@ -42,6 +42,7 @@ def _pseudo_gradient(w: Array, g: Array, l1: Array) -> Array:
     return jnp.where(w != 0.0, g + l1 * jnp.sign(w), at_zero)
 
 
+@jax.named_scope("pml.lbfgs.direction")
 def _two_loop_direction(pg, S, Y, rho, k, m):
     """Limited-memory two-loop recursion over ring buffers (newest-first)."""
     n_valid = jnp.minimum(k, m)
@@ -261,7 +262,10 @@ def lbfgs_advance_(
             return (t_next, w_t, f_t, g_t, F_t, steps + 1, ok_t)
 
         init = (t0, s.w, s.f, s.g, s.F, jnp.zeros((), jnp.int32), jnp.zeros((), bool))
-        t, w_new, f_new, g_new, F_new, _, ls_ok = lax.while_loop(ls_cond, ls_body, init)
+        with jax.named_scope("pml.lbfgs.line_search"):
+            t, w_new, f_new, g_new, F_new, _, ls_ok = lax.while_loop(
+                ls_cond, ls_body, init
+            )
 
         # divergence guard (resilience): a trial point with a non-finite
         # value, gradient, or coefficient vector is rejected exactly like a
@@ -276,15 +280,18 @@ def lbfgs_advance_(
         ls_ok = ls_ok & finite
 
         # ---- curvature pair update --------------------------------------
-        sv = w_new - s.w
-        yv = g_new - s.g
-        sy = jnp.dot(sv, yv)
-        store = ls_ok & (sy > _EPS)
-        pos = jnp.mod(s.k, m)
-        S = jnp.where(store, s.S.at[pos].set(sv), s.S)
-        Y = jnp.where(store, s.Y.at[pos].set(yv), s.Y)
-        rho = jnp.where(store, s.rho.at[pos].set(1.0 / jnp.maximum(sy, _EPS)), s.rho)
-        k = jnp.where(store, s.k + 1, s.k)
+        with jax.named_scope("pml.lbfgs.pair_update"):
+            sv = w_new - s.w
+            yv = g_new - s.g
+            sy = jnp.dot(sv, yv)
+            store = ls_ok & (sy > _EPS)
+            pos = jnp.mod(s.k, m)
+            S = jnp.where(store, s.S.at[pos].set(sv), s.S)
+            Y = jnp.where(store, s.Y.at[pos].set(yv), s.Y)
+            rho = jnp.where(
+                store, s.rho.at[pos].set(1.0 / jnp.maximum(sy, _EPS)), s.rho
+            )
+            k = jnp.where(store, s.k + 1, s.k)
 
         w_out = jnp.where(ls_ok, w_new, s.w)
         f_out = jnp.where(ls_ok, f_new, s.f)

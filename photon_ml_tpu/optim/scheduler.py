@@ -69,6 +69,7 @@ import jax.numpy as jnp
 from photon_ml_tpu.compile import ShapeBucketer, instrumented_jit
 from photon_ml_tpu.optim.common import OptResult
 from photon_ml_tpu.resilience import preemption
+from photon_ml_tpu.utils import profiling
 
 Array = jax.Array
 
@@ -604,27 +605,34 @@ def compacted_solve(
         optimizer_config=optimizer_config,
         regularization=regularization,
     )
-    if schedule.loop == "device":
-        from photon_ml_tpu.optim import fused_schedule
-        from photon_ml_tpu.resilience import faults
+    with profiling.span("pml.sched.solve", label=label,
+                        lanes=int(w0.shape[0]), loop=schedule.loop):
+        if schedule.loop == "device":
+            from photon_ml_tpu.optim import fused_schedule
+            from photon_ml_tpu.resilience import faults
 
-        try:
-            faults.inject(
-                "optim.device_drain", label=label, lanes=int(w0.shape[0])
-            )
-        except (faults.InjectedIOError, faults.InjectedFatalError) as e:
-            logger.warning(
-                "fused device solve (%s): injected fault (%s: %s); "
-                "degrading to the host chunk loop",
-                label, type(e).__name__, e,
-            )
-        else:
-            return fused_schedule.device_solve(
-                data, w0, schedule=schedule, label=label, resume=resume,
-                **cfg,
-            )
+            try:
+                faults.inject(
+                    "optim.device_drain", label=label, lanes=int(w0.shape[0])
+                )
+            except (faults.InjectedIOError, faults.InjectedFatalError) as e:
+                logger.warning(
+                    "fused device solve (%s): injected fault (%s: %s); "
+                    "degrading to the host chunk loop",
+                    label, type(e).__name__, e,
+                )
+            else:
+                return fused_schedule.device_solve(
+                    data, w0, schedule=schedule, label=label, resume=resume,
+                    **cfg,
+                )
+        return _host_solve(data, w0, cfg, schedule, label, resume)
+
+
+def _host_solve(data, w0, cfg, schedule, label, resume) -> OptResult:
+    """The host chunk loop of :func:`compacted_solve`."""
     lanes = int(w0.shape[0])
-    max_iter = optimizer_config.max_iterations
+    max_iter = cfg["optimizer_config"].max_iterations
     chunk = schedule.chunk_size
     bucketer = schedule.bucketer
 
@@ -658,18 +666,23 @@ def compacted_solve(
     while True:
         prev_limit = limit
         limit = min(limit + chunk, max_iter)
-        cur_state = _chunk_batch(cur_data, cur_state, jnp.int32(limit), **cfg)
+        with profiling.span("pml.sched.chunk", limit=limit,
+                            lanes=len(cur_ids), active=cur_active):
+            cur_state = _chunk_batch(cur_data, cur_state, jnp.int32(limit), **cfg)
         if compacted:
-            state = _scatter_batch(
-                state, cur_state, jnp.asarray(cur_ids, jnp.int32),
-                jnp.int32(cur_active),
-            )
+            with profiling.span("pml.sched.scatter", lanes=len(cur_ids),
+                                active=cur_active):
+                state = _scatter_batch(
+                    state, cur_state, jnp.asarray(cur_ids, jnp.int32),
+                    jnp.int32(cur_active),
+                )
         else:
             state = cur_state
         # one tiny D2H per chunk: the lane flags + iteration counters that
         # drive compaction and the iteration ledger
-        reasons = np.asarray(state.reason)
-        iters = np.asarray(state.iteration)
+        with profiling.span("pml.sched.sync"):
+            reasons = np.asarray(state.reason)
+            iters = np.asarray(state.iteration)
         advanced = (
             int(min(int(iters.max(initial=0)), limit) - prev_limit)
             if lanes
@@ -712,9 +725,11 @@ def compacted_solve(
             idx = np.concatenate(
                 [active_idx, np.full(rung - active_idx.size, active_idx[0])]
             ).astype(np.int32)
-            cur_data, cur_state = _gather_batch(
-                data, state, jnp.asarray(idx), jnp.int32(active_idx.size)
-            )
+            with profiling.span("pml.sched.gather", lanes=rung,
+                                active=int(active_idx.size)):
+                cur_data, cur_state = _gather_batch(
+                    data, state, jnp.asarray(idx), jnp.int32(active_idx.size)
+                )
             cur_ids = idx
             compacted = True
         cur_active = int(active_idx.size)

@@ -40,6 +40,7 @@ _SIGMA1, _SIGMA2, _SIGMA3 = 0.25, 0.5, 4.0
 _CG_TOL = 0.1  # inner CG solves to ||r|| <= 0.1 * ||g||
 
 
+@jax.named_scope("pml.tron.cg")
 def _truncated_cg(hvp, g, delta, max_cg_iter, dtype):
     """Steihaug truncated CG: approximately solve H s = -g, ||s|| <= delta.
 

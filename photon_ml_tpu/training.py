@@ -25,6 +25,7 @@ from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.ops.objective import GLMBatch
 from photon_ml_tpu.optim.common import OptResult
 from photon_ml_tpu.optim.problem import GLMOptimizationProblem
+from photon_ml_tpu.utils import profiling
 
 
 @dataclasses.dataclass
@@ -91,19 +92,20 @@ def train_glm_grid(
             lambda w0, lam: problem.run(batch, norm, init_coefficients=w0, reg_weight=lam)
         )
 
-    if warm_start_models:
-        max_lambda = max(warm_start_models.keys())
-        w = warm_start_models[max_lambda].coefficients.means
-    else:
-        w = jnp.zeros((batch.dim,), real_dtype())
-
     weights, models, results = [], [], []
-    for lam in sorted_weights:
-        model, res = solve(w, jnp.asarray(lam, real_dtype()))
-        w = model.coefficients.means
-        weights.append(lam)
-        models.append(model)
-        results.append(res)
+    with profiling.span("pml.glm.grid", lambdas=len(sorted_weights)):
+        if warm_start_models:
+            max_lambda = max(warm_start_models.keys())
+            w = warm_start_models[max_lambda].coefficients.means
+        else:
+            w = jnp.zeros((batch.dim,), real_dtype())
+        for lam in sorted_weights:
+            with profiling.span("pml.glm.solve", reg_weight=lam):
+                model, res = solve(w, jnp.asarray(lam, real_dtype()))
+            w = model.coefficients.means
+            weights.append(lam)
+            models.append(model)
+            results.append(res)
 
     return TrainedModelList(weights, models, results)
 

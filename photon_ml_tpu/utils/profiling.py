@@ -8,9 +8,17 @@ the missing piece is a DEVICE-side trace: set
     PHOTON_ML_TPU_PROFILE=/path/to/tracedir
 
 and every CLI driver wraps its train stage in a ``jax.profiler`` trace
-(viewable in XProf/TensorBoard — per-kernel HBM/MXU timelines), with
-training phases annotated via ``TraceAnnotation``. No env var -> zero
-overhead no-ops.
+(viewable in XProf/TensorBoard, or as a table of seconds per scope and idle
+seconds per host span with ``python -m benchmark.trace_scopes <tracedir>``).
+
+One vocabulary of names lands in that trace, on its one clock (PERF.md has
+the table of them):
+
+  * device scopes are ``jax.named_scope("pml.<layer>.<what>")`` where the
+    work is traced — HLO metadata only, nothing at run time;
+  * host spans are :func:`span`, a ``TraceAnnotation``: the name is a
+    constant and whatever varies (coordinate, iteration, lanes, ...) is
+    keyword metadata, which the profiler formats only while a trace is on.
 """
 
 from __future__ import annotations
@@ -38,18 +46,21 @@ def maybe_trace(stage: str) -> Iterator[None]:
 
     out = os.path.join(base, stage)
     os.makedirs(out, exist_ok=True)
-    jax.profiler.start_trace(out)
+    # the spans say what the host does; the Python tracer would add every
+    # call to the trace and seconds to the run
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(out, profiler_options=options)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named sub-span inside an active trace (TraceAnnotation); no-op
-    without an active trace but cheap enough to leave on."""
+def span(name: str, **metadata):
+    """Host span ``name`` on the calling thread, with ``metadata`` beside it
+    in the trace. With no trace running it is one enter/exit that builds no
+    string: the metadata values are never formatted."""
     import jax
 
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    return jax.profiler.TraceAnnotation(name, **metadata)

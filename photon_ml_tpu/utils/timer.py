@@ -10,6 +10,8 @@ import contextlib
 import time
 from typing import Callable, Dict, Optional
 
+from photon_ml_tpu.utils import profiling
+
 
 class Timer:
     """Named wall-clock spans with cumulative totals."""
@@ -35,9 +37,12 @@ class Timer:
 
     @contextlib.contextmanager
     def measure(self, name: str):
+        """A driver stage: the log line it always was, and the same interval
+        as the host span ``pml.stage`` (``stage=name``) in a trace."""
         self.start(name)
         try:
-            yield
+            with profiling.span("pml.stage", stage=name):
+                yield
         finally:
             self.stop(name)
 

@@ -396,6 +396,8 @@ class TestPersistentCache:
                 "jax_compilation_cache_dir",
                 "jax_persistent_cache_min_entry_size_bytes",
                 "jax_persistent_cache_min_compile_time_secs",
+                "jax_compilation_cache_include_metadata_in_key",
+                "jax_traceback_in_locations_limit",
             )
         }
         yield
@@ -413,15 +415,28 @@ class TestPersistentCache:
         compile_stats.install_xla_listeners()
         assert compat.enable_persistent_cache(cache_dir) == cache_dir
         compile_stats.reset()
-        jax.jit(lambda x: x * 3 + 2)(jnp.ones((64,)))
+
+        def scoped(name):
+            def f(x):
+                with jax.named_scope(name):
+                    return x * 3 + 2
+
+            return jax.jit(f)
+
+        scoped("pml.test.a")(jnp.ones((64,)))
         assert os.listdir(cache_dir), "no cache entries written"
         misses = compile_stats.xla_cache_misses
         assert misses >= 1
         # an IDENTICAL computation under a fresh jit wrapper must come
         # from the persistent cache, not a new XLA compile
-        jax.jit(lambda x: x * 3 + 2)(jnp.ones((64,)))
+        scoped("pml.test.a")(jnp.ones((64,)))
         assert compile_stats.xla_cache_hits >= 1
         assert compile_stats.xla_cache_misses == misses
+        # the same arithmetic under another scope name is another entry: a
+        # trace must show the names of the code that ran, not those of the
+        # build that filled the cache
+        scoped("pml.test.b")(jnp.ones((64,)))
+        assert compile_stats.xla_cache_misses == misses + 1
 
     @staticmethod
     def _min_knobs():

@@ -12,6 +12,8 @@ from photon_ml_tpu.io.index_map import IndexMap, feature_key
 from photon_ml_tpu.models.glm import Coefficients, GeneralizedLinearModel
 from photon_ml_tpu.ops.features import DenseFeatures
 from photon_ml_tpu.ops.objective import GLMBatch
+
+from trace_utils import trace_spans
 from photon_ml_tpu.types import DataValidationType, TaskType
 from photon_ml_tpu.utils import (
     DateRange,
@@ -182,20 +184,44 @@ class TestProfilerHooks:
         with maybe_trace("stage"):
             pass  # must not require a profiler session
 
-    @pytest.mark.slow  # ~20s: a real jax.profiler device trace; the hook's noop/enable contract stays tier-1 in test_no_env_is_noop
     def test_trace_writes_artifacts(self, monkeypatch, tmp_path):
         import jax.numpy as jnp
 
-        from photon_ml_tpu.utils.profiling import annotate, maybe_trace
+        from photon_ml_tpu.utils.profiling import maybe_trace, span
 
         monkeypatch.setenv("PHOTON_ML_TPU_PROFILE", str(tmp_path))
         with maybe_trace("unit"):
-            with annotate("solve"):
+            with span("pml.test.solve", lanes=3):
                 jnp.sum(jnp.ones((64, 64))).block_until_ready()
         stage_dir = tmp_path / "unit"
         assert stage_dir.is_dir()
-        # a trace run produces at least one artifact under the stage dir
-        assert any(stage_dir.rglob("*")), "no profiler artifacts written"
+        # the trace holds the span, with its metadata beside it
+        found = [e for e in trace_spans(stage_dir) if e.name == "pml.test.solve"]
+        assert len(found) == 1 and found[0].meta == {"lanes": "3"}
+
+    def test_span_builds_no_string_without_a_trace(self):
+        from photon_ml_tpu.utils.profiling import span
+
+        class Unprintable:
+            def __str__(self):
+                raise AssertionError("formatted with no trace running")
+
+            __repr__ = __str__
+
+        with span("pml.test.quiet", value=Unprintable()):
+            pass
+
+    def test_timer_stage_is_a_span(self, monkeypatch, tmp_path):
+        from photon_ml_tpu.utils.profiling import maybe_trace
+        from photon_ml_tpu.utils.timer import Timer
+
+        monkeypatch.setenv("PHOTON_ML_TPU_PROFILE", str(tmp_path))
+        timer = Timer()
+        with maybe_trace("unit"), timer.measure("train"):
+            pass
+        assert "train" in timer.totals
+        stages = [e for e in trace_spans(tmp_path / "unit") if e.name == "pml.stage"]
+        assert [e.meta for e in stages] == [{"stage": "train"}]
 
 
 class TestNativeLibsvmParser:
