@@ -174,18 +174,21 @@ class SparseFeatures:
         return jnp.sum(prods, axis=-1)
 
     @jax.named_scope("pml.features.rmatvec")
-    def rmatvec(self, d: Array) -> Array:
+    def rmatvec(self, d: Array, into: Optional[Array] = None) -> Array:
+        """X^T d. ``into``: a ``(dim,)`` sum the products are scatter-added
+        onto instead of zeros (the row-blocked gradient pass carries one
+        across its blocks); the row-order layout only."""
         acc = _acc_dtype(self.values.dtype)
         if self.t_idx is not None:
+            assert into is None, "the sorted transpose is never cut by rows"
             contrib = self.t_val.astype(acc) * d.astype(acc)[self.t_row]
             return jax.ops.segment_sum(
                 contrib, self.t_idx, num_segments=self.dim,
                 indices_are_sorted=True,
             )
         contrib = self.values.astype(acc) * d.astype(acc)[:, None]
-        return jnp.zeros((self.dim,), acc).at[self.indices.reshape(-1)].add(
-            contrib.reshape(-1)
-        )
+        base = jnp.zeros((self.dim,), acc) if into is None else into
+        return base.at[self.indices.reshape(-1)].add(contrib.reshape(-1))
 
     @jax.named_scope("pml.features.sq_rmatvec")
     def sq_rmatvec(self, d: Array) -> Array:
@@ -254,17 +257,15 @@ def from_scipy_like(rows, dim: int, dtype=jnp.float32) -> SparseFeatures:
     return SparseFeatures(jnp.asarray(indices), jnp.asarray(values, dtype), dim)
 
 
-# Production rule for the transpose layout, set by MEASUREMENT, not theory.
-# The theory said the sorted-segment-sum CSC gradient should win on TPU in
-# the wide regime (random scatter into a 2^20-wide vector being the hostile
-# op); the v5e says otherwise: BENCH_SELFRUN_r05 measured scatter-add at
-# 1.08e6 ex/s vs 0.66e6 for the sorted view at (N=131072, D=2^20, nnz=64)
-# — the sort/gather machinery costs more than the scatter it avoids. The
-# default is therefore the scatter layout everywhere; the bench races both
-# every round (sparse_wide_examples_per_sec_{scatter,sorted}) so a future
-# chip/compiler that flips the ordering shows up in the record, and
-# ``PHOTON_ML_TPU_SPARSE_TRANSPOSE=1`` forces the CSC view back on for
-# comparison without a code change.
+# Production rule for the transpose layout. The two layouts (the random
+# scatter-add into a (dim,)-wide vector, and the sorted segment sum over the
+# CSC view) have not been raced on a benchmark cell (ROADMAP S2), so no
+# record says which the v5e prefers in the wide regime. Until one does, the
+# default is the scatter layout everywhere, and
+# ``PHOTON_ML_TPU_SPARSE_TRANSPOSE=1`` forces the CSC view on for a
+# comparison without a code change. The row-blocked value-and-gradient pass
+# (``ops/objective.py``) needs the row-order layout: a batch with the CSC
+# view keeps the whole-batch pass.
 SPARSE_TRANSPOSE_MIN_DIM = 1 << 16
 
 

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from photon_ml_tpu.ops.features import Features
+from photon_ml_tpu.ops.features import Features, SparseFeatures, _acc_dtype
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.normalization import NormalizationContext
 
@@ -70,6 +70,33 @@ class GLMBatch:
     @classmethod
     def tree_unflatten(cls, aux, children):
         return cls(*children)
+
+
+#: Stored non-zeros (rows x padded K) one row block of the blocked
+#: value-and-gradient pass aims at. A padded-sparse batch that holds more is
+#: walked in blocks of ``ROW_BLOCK_NNZ // K`` rows; one at or under it is one
+#: block, which is the whole-batch pass. Set from a sweep on the v5e at
+#: (2^22, 64) over 2^21 features (PERF.md section 6, PR 26).
+ROW_BLOCK_NNZ = 1 << 22
+
+
+def _block_rows(features) -> Optional[int]:
+    """Rows per block of the blocked value-and-gradient pass, from the
+    batch's static shape alone; None where the whole batch is one block.
+
+    Only ``SparseFeatures`` in row order blocks: the sorted-transpose arrays
+    (``t_idx``) are in feature order and cannot be cut by rows. Under
+    ``shard_map`` the shape seen here is the device's local shard, so every
+    device blocks its own rows; no caller hands ``value_and_grad`` a
+    ``SparseFeatures`` whose rows plain ``jit`` has sharded.
+    """
+    if not isinstance(features, SparseFeatures) or features.t_idx is not None:
+        return None
+    n, k = features.indices.shape
+    if n * k <= ROW_BLOCK_NNZ:
+        return None
+    rows = max(ROW_BLOCK_NNZ // k, 1)
+    return rows - rows % 8 if rows > 8 else rows
 
 
 def _maybe_psum(x, axis_name: Optional[str]):
@@ -147,6 +174,7 @@ class GLMObjective:
     @jax.named_scope("pml.objective.value_and_grad")
     def value_and_grad(self, w, batch, norm, l2_weight=0.0) -> Tuple[Array, Array]:
         w_eff = norm.effective_coefficients(w)
+        rows = _block_rows(batch.features)  # None: the whole batch at once
         if self._use_fused(batch):
             from photon_ml_tpu.ops import fused_glm
 
@@ -171,6 +199,12 @@ class GLMObjective:
             )
             if norm.shifts is not None:
                 grad_eff = grad_eff - norm.shifts * sum_d
+        elif rows is not None:
+            lv, grad_eff, sum_d = self._blocked_value_grad_parts(
+                w_eff, norm.margin_shift(w_eff), batch, rows
+            )
+            if norm.shifts is not None:
+                grad_eff = grad_eff - norm.shifts * sum_d
         else:
             z = batch.features.matvec(w_eff) + norm.margin_shift(w_eff) + batch.offsets
             lv = _row_sum(
@@ -186,7 +220,63 @@ class GLMObjective:
         grad = grad_eff * norm.factors if norm.factors is not None else grad_eff
         value = lv + 0.5 * l2_weight * jnp.sum(jnp.square(w))  # lint: bitwise-reduction — l2 reg over the fixed (D,) w; pinned arithmetic of the bitwise gates
         grad = grad + l2_weight * w
+        if rows is not None:
+            # The solver reduces this gradient (its norm) right after. Left
+            # free, the compiler fuses that reduction with the scan's epilogue
+            # above, and a float32 reduction of 2^21 squares on the v5e moves
+            # by 5e-7 of itself with the fusion it is in (it is 2e-6 from the
+            # float64 sum either way). Behind the barrier it is fused as it
+            # is after the whole-batch pass and gives the same bits.
+            grad = lax.optimization_barrier(grad)
         return value, grad
+
+    def _blocked_value_grad_parts(self, w_eff, margin_shift, batch, rows: int):
+        """(loss sum, X^T d, sum d) of a padded-sparse batch, walked in
+        contiguous blocks of ``rows`` rows: one ``lax.scan`` whose step does a
+        block's gather, margin, loss, slope and scatter-add before the next
+        block's gather starts, so the (rows, K) temporaries of one block are
+        all the pass holds. The same arithmetic as the whole-batch pass, in
+        the same precision: the scatter-add lands on the carried gradient in
+        row order, as the whole-batch one does on zeros, and the blocks' loss
+        and slope sums are added up after the scan (a sum carried through
+        thousands of steps would round thousands of times). Rows the block
+        does not divide are a last, shorter step after the scan."""
+        feats = batch.features
+        n = feats.num_rows
+        # Every block gathers from w_eff. A buffer this evaluation makes the
+        # compiler keeps in the chip's fast memory for the whole scan; the
+        # solver's starting point is the program's own parameter, stays in
+        # HBM, and the gather from there takes 1.8 times as long (PERF.md
+        # section 6, PR 26). Times a one the compiler cannot see through: the
+        # same bits in a buffer of the evaluation's own.
+        w_eff = w_eff * lax.optimization_barrier(jnp.ones((), w_eff.dtype))
+
+        @jax.named_scope("pml.objective.row_block")
+        def add_block(grad_eff, start, size: int):
+            cut = lambda a: lax.dynamic_slice_in_dim(
+                a, start, size, allow_negative_indices=False)
+            block = SparseFeatures(cut(feats.indices), cut(feats.values), feats.dim)
+            labels, weights = cut(batch.labels), cut(batch.weights)
+            z = block.matvec(w_eff) + margin_shift + cut(batch.offsets)
+            d = _wmul(weights, self.loss.d1(z, labels))
+            lv = _row_sum(block, _wmul(weights, self.loss.loss(z, labels)))
+            return block.rmatvec(d, into=grad_eff), (lv, _row_sum(block, d))
+
+        grad_eff = jnp.zeros((feats.dim,), _acc_dtype(feats.values.dtype))
+        if self.axis_name is not None:
+            # under shard_map every block's sum varies over the mesh axis
+            grad_eff = lax.pcast(grad_eff, self.axis_name, to="varying")
+        starts = jnp.arange(n // rows, dtype=jnp.int32) * rows
+        grad_eff, (lv, sum_d) = lax.scan(
+            lambda g, start: add_block(g, start, rows), grad_eff, starts
+        )
+        lv, sum_d = _row_sum(feats, lv), _row_sum(feats, sum_d)  # over blocks
+        if n % rows:
+            grad_eff, (lv_tail, sum_d_tail) = add_block(
+                grad_eff, n - n % rows, n % rows
+            )
+            lv, sum_d = lv + lv_tail, sum_d + sum_d_tail
+        return lv, grad_eff, sum_d
 
     def _use_fused(self, batch: GLMBatch) -> bool:
         """Static (trace-time) dispatch to the single-pass Pallas kernel."""

@@ -77,23 +77,56 @@ GLM_PATHS = {
 }
 
 
-@pytest.mark.parametrize("path", sorted(GLM_PATHS))
-def test_glm_solve_lowers_with_its_scopes(rng, path):
-    sparse, optimizer, variance, want = GLM_PATHS[path]
+def _lowered_solve(batch, optimizer=OptimizerType.LBFGS, variance=False):
     problem = GLMOptimizationProblem(
         LOGISTIC, optimizer, OptimizerConfig(max_iterations=3, tolerance=1e-6),
         RegularizationContext.l2(1.0), compute_variance=variance)
-    batch = _glm_batch(rng, sparse)
-    text = training._solve.lower(
+    return training._solve.lower(
         problem, batch, NormalizationContext.identity(),
-        jnp.zeros((batch.dim,), jnp.float32), jnp.float32(1.0),
-    ).as_text(debug_info=True)
+        jnp.zeros((batch.dim,), jnp.float32), jnp.float32(1.0))
+
+
+@pytest.mark.parametrize("path", sorted(GLM_PATHS))
+def test_glm_solve_lowers_with_its_scopes(rng, path):
+    sparse, optimizer, variance, want = GLM_PATHS[path]
+    text = _lowered_solve(_glm_batch(rng, sparse), optimizer, variance).as_text(
+        debug_info=True)
     assert scopes_in(text) == want
     assert "module @jit__solve" in text  # the name fe_solve_roofline reads
     # the kernels sit inside the objective's scope, so a kernel swap keeps it
     assert "pml.objective.value_and_grad/pml.features.matvec" in text
     if not sparse:
         assert "pml.tron.cg/while/body/pml.objective.hvp/pml.features.rmatvec" in text
+
+
+def test_blocked_solve_lowers_with_the_row_block_scope(rng, monkeypatch):
+    """A sparse batch over the target walks its rows in blocks: the scan's
+    step is ``pml.objective.row_block`` with both feature kernels under it,
+    inside the evaluation's scope, and the program keeps its name."""
+    from photon_ml_tpu.ops import objective
+
+    monkeypatch.setattr(objective, "ROW_BLOCK_NNZ", 8 * 4)  # (32, 4): 4 blocks
+    training._solve.clear_cache()
+    lowered = _lowered_solve(_glm_batch(rng, sparse=True))
+    training._solve.clear_cache()
+    text = lowered.as_text(debug_info=True)
+    assert scopes_in(text) == GLM_PATHS["sparse-lbfgs"][3] | {
+        "pml.objective.row_block"}
+    assert "module @jit__solve" in text
+    # a scan's step is lowered as a function of its own, so the whole path
+    # of an operation is in the compiled program's op_name, not in the text
+    paths = set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+    # (a reduction's or a scatter's combiner keeps a path of its own)
+    kernels = {p for p in paths
+               if p.startswith("jit(_solve)/") and "/pml.features." in p}
+    block = (r"/pml\.objective\.value_and_grad/while/body/(closed_call/)?"
+             r"pml\.objective\.row_block/pml\.features\.(matvec|rmatvec)/")
+    assert kernels and all(re.search(block, p) for p in kernels), kernels
+    assert {m for p in kernels for m in re.findall(r"features\.(\w+)", p)} == {
+        "matvec", "rmatvec"}
+    # the evaluation before the loop and the line search's both block
+    assert any("pml.lbfgs.line_search" in p for p in kernels)
+    assert any("pml.lbfgs.line_search" not in p for p in kernels)
 
 
 def test_objective_value_has_its_scope(rng):
