@@ -14,6 +14,11 @@ def peak_hbm_gib(ctx):
 
 
 def launches_per_job(ctx):
+    """Program launches in the whole trace over the jobs traced. The harness
+    traces nothing but the window's jobs (the warm-up job has ended before the
+    profiler starts, the outputs are read after it stops), and the window's
+    edge on the device's clock would cut the first programs of a job off from
+    run to run (PERF.md section 3)."""
     trace = ctx["trace"]
     if not trace or not trace["launches"] or not ctx["jobs"]:
         return None

@@ -39,6 +39,7 @@ class Solve(NamedTuple):
     stopped: jax.Array
     values: jax.Array  # (max_iter + 1,) value after each iteration, NaN beyond
     grad_norms: jax.Array
+    first_grad: jax.Array  # the gradient at ``w0``
 
 
 def _direction(g, S, Y, rho, n_pairs, m):
@@ -155,7 +156,7 @@ def lbfgs(value_and_grad, w0, max_iterations: int, tolerance: float,
 
     s = lax.while_loop(keep_going, iterate, state)
     return Solve(s["w"], s["f"], s["g_norm"], s["it"], s["evals"],
-                 s["stopped"], s["values"], s["grad_norms"])
+                 s["stopped"], s["values"], s["grad_norms"], g0)
 
 
 def logistic_loss(z, y):

@@ -11,7 +11,10 @@ Run on the CPU at the families' ``TINY`` sizes:
   solver returning its state unchanged, and half of the rows left out with
   the rest counted double.
 The same comparisons were read on the chip at the cells' own sizes
-(PERF.md, "How correct is decided").
+(PERF.md, "How correct is decided"). The ``glm_sparse`` family's first
+gradient is compared by its norm: the timed solve's own float32 norm against
+the float64 norm of the reference's vector, so that the reading is the
+program's rounding and never the reference's.
 """
 
 from __future__ import annotations
@@ -25,6 +28,7 @@ import os
 import sys
 import time
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -99,6 +103,40 @@ def test_control_in_bfloat16_fails(cell_name, seed):
     numbers = cell.compare(cell.reference("bfloat16"), ref)
     assert cell.limits
     assert any(numbers[n] > limit for n, limit in cell.limits.items()), numbers
+
+
+@pytest.mark.parametrize("seed", [5, 6, 7])
+def test_glm_sparse_control_fails_three_numbers(seed):
+    """As on the chip at the cell's own size (PERF.md section 2): the
+    bfloat16 control is over the limit on the values and on both coefficient
+    numbers."""
+    cell, _ = _build("glm-sparse-2m.lbfgs", seed)
+    numbers = cell.compare(cell.reference("bfloat16"), cell.reference())
+    failed = [n for n, limit in cell.limits.items() if numbers[n] > limit]
+    assert {"values_gap", "change_norm_gap", "coefficients_gap"} <= set(failed), numbers
+
+
+def test_first_gradient_norm_reads_the_programs_rounding_alone():
+    """One gradient, two float32 norms rounded apart (on the chip 5e-7, each
+    by its own fusion: PR 26). Each reads its own distance from the float64
+    norm of the reference's vector, under the limit; a norm that is off by
+    2^-10 reads that and fails."""
+    cell, _ = _build("glm-sparse-2m.lbfgs", 11)
+    ref = cell.reference()
+    grad = ref["first_grad"]
+    exact = float(np.linalg.norm(grad.astype(np.float64)))
+    squares = (grad * grad).astype(np.float32)
+    in_order = float(np.sqrt(np.cumsum(squares, dtype=np.float32)[-1]))
+    pairwise = float(np.sqrt(np.sum(squares, dtype=np.float32)))
+    assert in_order != pairwise
+    for norm in (in_order, pairwise):
+        numbers = cell.compare(dict(ref, first_grad_norm=norm), ref)
+        assert numbers["first_grad_gap"] == pytest.approx(
+            abs(norm - exact) / exact, rel=1e-9, abs=1e-15)
+        assert all(numbers[n] <= limit for n, limit in cell.limits.items())
+    off = cell.compare(dict(ref, first_grad_norm=exact * (1.0 + 2.0 ** -10)), ref)
+    assert off["first_grad_gap"] == pytest.approx(2.0 ** -10, rel=1e-6)
+    assert off["first_grad_gap"] > cell.limits["first_grad_gap"]
 
 
 @pytest.mark.parametrize("cell_name", CELLS)

@@ -191,44 +191,232 @@ def _entry(key, message):
 def _xspace():
     stat_meta = _field(5, _entry(7, _field(1, 7) + _field(2, "tf_op"))) \
         + _field(5, _entry(8, _field(1, 8) + _field(2, "flops")))
-    gather = _field(1, 1) + _field(2, "%fusion.21 = f32[8] fusion(...)") \
+    gather = _field(1, 1) + _field(2, "%fusion.21 = f32[8]{0:T(8)} fusion(f32[4]{0} %p.1), kind=kCustom, calls=%fused.3") \
         + _field(5, _field(1, 8) + _field(3, 99)) \
         + _field(5, _field(1, 7) + _field(5, f"jit(_solve)/{VG}/{MV}/gather:"))
     copy = _field(1, 2) + _field(2, "%copy-start.1 = ...")
+    scatter = _field(1, 3) + _field(2, "%fusion.22 = f32[4]{0} fusion(f32[8]{0} %p.2), kind=kLoop") \
+        + _field(5, _field(1, 7) + _field(5, f"jit(_solve)/{VG}/{RMV}/scatter-add:"))
     ops_line = _field(2, "XLA Ops") + _field(3, 1000) \
         + _field(4, _field(1, 1) + _field(2, 2_000_000) + _field(3, 3_000_000)) \
-        + _field(4, _field(1, 2) + _field(2, 5_000_000) + _field(3, 1_000_000))
-    device = _field(2, "/device:TPU:0") + _field(3, ops_line) \
-        + _field(4, _entry(1, gather)) + _field(4, _entry(2, copy)) + stat_meta
+        + _field(4, _field(1, 2) + _field(2, 5_000_000) + _field(3, 1_000_000)) \
+        + _field(4, _field(1, 3) + _field(2, 6_000_000) + _field(3, 500_000))
+    # the job's program at 3 us and, 0.1 us before the host's window span
+    # opens at 1 us, one of the fillers jax launches at a job's start: 400 ps
+    solve = _field(1, 4) + _field(2, "jit__solve(123)")
+    filler = _field(1, 5) + _field(2, "jit_convert_element_type(7)")
+    modules_line = _field(2, "XLA Modules") + _field(3, 0) \
+        + _field(4, _field(1, 5) + _field(2, 900_000) + _field(3, 400)) \
+        + _field(4, _field(1, 4) + _field(2, 3_000_000) + _field(3, 4_500_000))
+    device = _field(2, "/device:TPU:0") + _field(3, modules_line) \
+        + _field(3, ops_line) + _field(4, _entry(1, gather)) \
+        + _field(4, _entry(2, copy)) + _field(4, _entry(3, scatter)) \
+        + _field(4, _entry(4, solve)) + _field(4, _entry(5, filler)) + stat_meta
     window = _field(1, 1) + _field(2, trace_reduce.WINDOW_SPAN)
     span = _field(1, 2) + _field(2, "pml.glm.grid")
     thread = _field(2, "python3") + _field(3, 1000) \
         + _field(4, _field(1, 1) + _field(2, 0) + _field(3, 8_000_000)) \
-        + _field(4, _field(1, 2) + _field(2, 1_000_000) + _field(3, 6_000_000))
+        + _field(4, _field(1, 2) + _field(2, 500_000) + _field(3, 6_500_000))
     host = _field(2, "/host:CPU") + _field(3, thread) \
         + _field(4, _entry(1, window)) + _field(4, _entry(2, span))
     return _field(1, device) + _field(1, host)
 
 
-def test_read_xspace_and_read_scopes(tmp_path):
+@pytest.fixture
+def trace_dir(tmp_path):
     run = tmp_path / "plugins" / "profile" / "2026_01_01"
     run.mkdir(parents=True)
     (run / "host.xplane.pb").write_bytes(_xspace())
-    planes = ts.read_xspace(str(run / "host.xplane.pb"))
+    return tmp_path
+
+
+def test_read_xspace_and_read_scopes(trace_dir):
+    (path,) = (trace_dir / "plugins" / "profile" / "2026_01_01").iterdir()
+    assert ts.newest_xplane(str(trace_dir)) == str(path)
+    planes = ts.read_xspace(str(path))
     assert [p["name"] for p in planes] == ["/device:TPU:0", "/host:CPU"]
-    name, op_name, start, dur = planes[0]["lines"][0]["events"][0]
+    ops = planes[0]["lines"][1]
+    name, op_name, start, dur, module, run_id = ops["events"][0]
+    assert (module, run_id) == ("", None)  # a TPU's operations carry neither
     assert name.startswith("%fusion.21") and op_name.endswith("/gather:")
     assert start == pytest.approx(1e-6 + 2e-6) and dur == pytest.approx(3e-6)
-    assert planes[0]["lines"][0]["events"][1][1] == ""  # compiler-made
-    (reduced,) = ts.read_scopes(str(tmp_path))
-    # window 1..9 us; the gather 3..6 us, the copy 6..7 us; idle 1..3 and 7..9,
-    # the span covers 2..8: 1 + 1 of the 4 idle microseconds have no span
+    assert ops["events"][1][1] == ""  # compiler-made
+    (reduced,) = ts.read_scopes(str(trace_dir))
+    # window 1..9 us; the gather 3..6 us, the copy 6..7 us, the scatter-add
+    # 7..7.5 us; idle 1..3 and 7.5..9, the span covers 1.5..8: 0.5 + 1 of the
+    # 3.5 idle microseconds have no span
     assert reduced["scopes"][MV]["total_s"] == pytest.approx(3e-6)
-    assert reduced["scope_runs"] == {VG: 1, MV: 1}
-    assert reduced["host_gaps"]["idle_s"] == pytest.approx(4e-6)
-    assert reduced["host_gaps"]["unattributed_s"] == pytest.approx(2e-6)
-    assert ts.main([str(tmp_path)]) == 0
-    assert ts.main([str(tmp_path / "nothing-here")]) == 1
+    assert reduced["scopes"][RMV]["total_s"] == pytest.approx(0.5e-6)
+    assert reduced["scope_runs"] == {VG: 1, MV: 1, RMV: 1}
+    assert reduced["host_gaps"]["idle_s"] == pytest.approx(3.5e-6)
+    assert reduced["host_gaps"]["unattributed_s"] == pytest.approx(1.5e-6)
+    assert ts.main([str(trace_dir)]) == 0
+    assert ts.main([str(trace_dir / "nothing-here")]) == 1
+
+
+# -- one way from a trace directory to the readers ----------------------------
+
+
+def _metric(name):
+    import json
+
+    with open(os.path.join(os.path.dirname(trace_reduce.__file__), "metrics",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+def test_read_xplane_hands_the_readers_the_trace(trace_dir):
+    """What ``harness.traced_window`` gets before it deletes the directory:
+    the old reducer's keys and the scopes' three, from one parse; the four
+    scope metrics as shipped read numbers from it."""
+    from benchmark.metrics.readers import device
+
+    (reduced,) = trace_reduce.read_xplane(str(trace_dir))
+    (scoped,) = ts.read_scopes(str(trace_dir))
+    for key in ("scopes", "scope_runs", "host_gaps"):
+        assert reduced[key] == scoped[key]
+    assert reduced["busy_s"] == pytest.approx(4.5e-6)
+    assert reduced["window_s"] == pytest.approx(8e-6)
+    assert reduced["programs"]["jit__solve"]["launches"] == 1
+    ctx = {"trace": reduced, "jobs": 1}
+
+    def read(name):
+        spec = _metric(name)
+        module, func = spec["reader"].split(":")
+        assert module == "scopes"
+        return getattr(readers, func)(dict(ctx, metric=spec))
+
+    assert read("fe_evaluations_per_job") == 1.0
+    assert read("fe_matvec_s_per_eval") == pytest.approx(3e-6)
+    assert read("fe_rmatvec_s_per_eval") == pytest.approx(0.5e-6)
+    assert read("idle_unattributed_share") == pytest.approx(100 * 1.5 / 3.5)
+    # the names of the breakdown: scope, instruction without its number,
+    # result shape; the gap a span of the program covers says so
+    assert [name for name, _ in reduced["device_ops"]] == [
+        f"{MV} | fusion f32[8]", "copy-start = ...", f"{RMV} | fusion f32[4]"]
+    assert reduced["idle_gaps"][0] == [
+        "after window start before jit__solve | host pml.glm.grid",
+        pytest.approx(2e-6)]
+    assert reduced["idle_gaps"][1] == [
+        "after jit__solve before window end", pytest.approx(1.5e-6)]
+    # the filler lies before the window and in no number but the launch
+    # count, which is of the whole trace
+    assert list(reduced["programs"]) == ["jit__solve"]
+    assert reduced["launches"] == 2
+    assert device.launches_per_job(
+        dict(ctx, metric=_metric("device_launches_per_job"))) == 2.0
+
+
+def test_launch_count_is_of_the_whole_trace():
+    """The cell's launches as the chip's traces held them (PERF.md section
+    3): three fillers of microseconds and the solve, the window's edge on the
+    device's clock before, between or after the fillers. One count."""
+    from benchmark.metrics.readers import device
+
+    spec = _metric("device_launches_per_job")
+    launches = [("jit_convert_element_type", 0.9998, 6e-7),
+                ("jit_broadcast_in_dim", 0.9999, 1.34e-5),
+                ("jit_convert_element_type", 1.0002, 6e-7),
+                ("jit__solve", 1.0013, 15.0)]
+    for edge in (0.9990, 1.0000, 1.0005):
+        reduced = trace_reduce.reduce_events([], launches, (edge, 16.1))
+        assert device.launches_per_job(
+            {"trace": reduced, "jobs": 1, "metric": spec}) == 4.0
+        assert reduced["programs"]["jit__solve"]["launches"] == 1
+    assert device.launches_per_job(
+        {"trace": reduced, "jobs": 2, "metric": spec}) == 2.0
+    empty = trace_reduce.reduce_events([], [], None)
+    assert device.launches_per_job(
+        {"trace": empty, "jobs": 1, "metric": spec}) is None
+
+
+# -- the CPU backend's trace goes the same way ---------------------------------
+
+
+def _host_xspace():
+    """What the CPU backend writes (the rehearsal): no device plane; the
+    host's operations carry ``hlo_module`` (a reference to a name) and
+    ``run_id`` as stats of the event itself."""
+    stat_meta = _field(5, _entry(3, _field(1, 3) + _field(2, "run_id"))) \
+        + _field(5, _entry(10, _field(1, 10) + _field(2, "hlo_module"))) \
+        + _field(5, _entry(11, _field(1, 11) + _field(2, "jit_f")))
+
+    def op(meta, offset_ps, duration_ps, run):
+        return _field(4, _field(1, meta) + _field(2, offset_ps)
+                      + _field(3, duration_ps)
+                      + _field(4, _field(1, 10) + _field(7, 11))
+                      + _field(4, _field(1, 3) + _field(4, run)))
+
+    python = _field(2, "python") + _field(3, 1000) \
+        + _field(4, _field(1, 1) + _field(2, 0) + _field(3, 8_000_000)) \
+        + _field(4, _field(1, 2) + _field(2, 500_000) + _field(3, 6_500_000))
+    # two runs of jit_f on a worker thread: 2..4 us (copy, dot) and 6.5..7 us;
+    # the marker between them carries no module and is no operation
+    worker = _field(2, "tf_XLAPjRtCpuClient/1") + _field(3, 1000) \
+        + op(3, 1_000_000, 500_000, 77) + op(4, 1_500_000, 1_500_000, 77) \
+        + _field(4, _field(1, 5) + _field(2, 3_000_000) + _field(3, 100_000)) \
+        + op(4, 5_500_000, 500_000, 78)
+    host = _field(2, "/host:CPU") + _field(3, python) + _field(3, worker) \
+        + _field(4, _entry(1, _field(1, 1) + _field(2, trace_reduce.WINDOW_SPAN))) \
+        + _field(4, _entry(2, _field(1, 2) + _field(2, "pml.glm.grid"))) \
+        + _field(4, _entry(3, _field(1, 3) + _field(2, "copy.6"))) \
+        + _field(4, _entry(4, _field(1, 4) + _field(2, "dot.7"))) \
+        + _field(4, _entry(5, _field(1, 5) + _field(2, "end: copy.6"))) \
+        + stat_meta
+    return _field(1, host)
+
+
+def test_cpu_trace_goes_through_the_same_parser(tmp_path):
+    run = tmp_path / "plugins" / "profile" / "2026_01_01"
+    run.mkdir(parents=True)
+    (run / "host.xplane.pb").write_bytes(_host_xspace())
+    (reduced,) = trace_reduce.read_xplane(str(tmp_path))
+    assert reduced["window_s"] == pytest.approx(8e-6)
+    assert reduced["busy_s"] == pytest.approx(2.5e-6)
+    assert reduced["launches"] == 2  # two run_ids of one module
+    assert reduced["programs"]["jit_f"]["seconds"] == pytest.approx(2.5e-6)
+    assert dict(map(tuple, reduced["device_ops"])) == {
+        "dot": pytest.approx(2e-6), "copy": pytest.approx(0.5e-6)}
+    assert reduced["idle_gaps"][0] == [
+        "after jit_f before jit_f | host pml.glm.grid", pytest.approx(2.5e-6)]
+    # no operation of the CPU backend has a scope: the scope readers get
+    # nothing to read
+    assert list(reduced["scopes"]) == [ts.NO_SCOPE] and not reduced["scope_runs"]
+    ctx = {"trace": reduced, "jobs": 1}
+    assert readers.executions_per_job(dict(ctx, metric={"scope": VG})) is None
+    assert readers.seconds_per_execution(
+        dict(ctx, metric={"scope": MV, "per": VG})) is None
+
+
+def test_device_ops_keys_hold_no_fusion_number():
+    key = trace_reduce.op_key
+    path = f"jit(_solve)/while/body/{LS}/while/body/{VG}/pml.objective.row_block/"
+    gather = ("%fusion.23 = f32[4194304]{0:T(1024)} fusion(f32[2097152]{0:T(1024)S(1)} "
+              "%multiply.7, s32[4194304]{0} %bitcast.5), kind=kCustom, "
+              "calls=%fused_computation.19")
+    assert key(path + MV + "/gather:", gather) == f"{MV} | fusion f32[4194304]"
+    assert key(path + MV + "/gather:", gather.replace("fusion.23", "fusion.19")) \
+        == f"{MV} | fusion f32[4194304]"
+    pair = ("%compare_select_fusion.16 = (s32[65536,64]{1,0}, s32[65536,64]{1,0}) "
+            "fusion(s32[4194304]{0} %p), kind=kLoop, calls=%fused.2")
+    assert key(path + "select_n:", pair) == \
+        "pml.objective.row_block | compare_select_fusion (s32[65536,64], s32[65536,64])"
+    # under no scope: today's label, less the number
+    loop = "%while.148 = (s32[], f32[2097152]{0}) while((s32[], f32[2097152]{0}) %tuple.3), condition=%c, body=%b"
+    assert key("", loop) == \
+        "while = (s32[], f32[2097152]) while((s32[], f32[2097152])), condition=%c, body=%b"
+    assert key("jit(_solve)/jit(norm)/reduce_sum:", "%fusion.12") == "fusion"
+    long = "%fusion.5 = (" + ", ".join(["f32[2097152]{0}"] * 40) + ") fusion()"
+    assert len(key(path + "x:", long)) == 160 and len(key("", long)) == 160
+    # operations of one key are summed, whatever their numbers: the hand-made
+    # job's three gathers (%fusion.1 before the loop, %fusion.21 twice in it)
+    reduced = trace_reduce.reduce_events(
+        [(key(o, i), s, d) for o, i, s, d in OPS], [], (0.0, 10.0))
+    top = dict(map(tuple, reduced["device_ops"]))
+    assert top[f"{MV} | fusion"] == 6.0
+    assert top[f"{RMV} | fusion"] == 2.25 and top[f"{RMV} | reshape"] == 0.125
+    import re
+    assert not any(re.search(r"fusion\.\d", name) for name in top)
 
 
 # -- the old reducer is left alone --------------------------------------------
@@ -236,7 +424,7 @@ def test_read_xspace_and_read_scopes(tmp_path):
 
 def test_old_reducer_returns_what_it_returned():
     """The stored list of benchmark/check.py through the parent's reducer:
-    every key and number it gave at PR 24, whatever this PR adds beside it."""
+    every key and number it gave at PR 24, whatever is added beside it."""
     ops = [("loop", 0.0, 4.0), ("gather", 0.5, 1.0), ("scatter", 1.5, 2.0),
            ("dot", 6.0, 2.0), ("add", 8.0, 1.0)]
     launches = [("a", 0.0, 4.0), ("b", 6.0, 2.0), ("a", 8.0, 1.0)]

@@ -15,7 +15,9 @@ HLO ``op_name`` of the operation, for a fusion that of its root), which
 ``jax.profiler.ProfileData`` does not expose, so :func:`read_xspace` walks the
 protobuf's wire format itself: the five messages of ``xplane.proto`` it needs
 and nothing else. Host spans are the events of the host plane's thread lines
-whose names start with :data:`PREFIX`.
+whose names start with :data:`PREFIX`. It is the one parser of a trace:
+:func:`benchmark.trace_reduce.read_xplane` takes its tuples from
+:func:`read_devices`, the CPU backend's (the rehearsal) too.
 """
 
 from __future__ import annotations
@@ -260,14 +262,31 @@ def _map_entry(buf):
     return key, value
 
 
+def _stat(buf, stat_names: Dict[int, str]):
+    """(stat's name, its value) of one XStat: text for a string or a
+    reference to a name, else the integer."""
+    stat = dict(_fields(buf))
+    if 5 in stat:
+        value = _text(stat[5])
+    elif 7 in stat:
+        value = stat_names.get(stat[7], "")
+    else:
+        value = stat.get(3, stat.get(4))
+    return stat_names.get(stat.get(1), ""), value
+
+
 def read_xspace(path: str) -> List[dict]:
     """The planes of an ``.xplane.pb``: ``{"name", "lines": [{"name",
-    "events": [(event name, op_name or "", start_s, duration_s)]}]}``. Field
-    numbers are those of ``tsl/profiler/protobuf/xplane.proto``: XSpace.planes
-    1; XPlane.name 2, lines 3, event_metadata 4, stat_metadata 5; XLine.name
-    2, timestamp_ns 3, events 4; XEvent.metadata_id 1, offset_ps 2,
-    duration_ps 3; XEventMetadata.name 2, stats 5; XStat.metadata_id 1,
-    str_value 5, ref_value 7; XStatMetadata.name 2."""
+    "events": [(event name, op_name or "", start_s, duration_s, hlo_module
+    or "", run_id or None)]}]}``. The ``op_name`` is the ``tf_op`` stat of
+    the event's metadata (a TPU's operations); ``hlo_module`` and ``run_id``
+    are stats of the event itself (the CPU backend's operations, which carry
+    nothing else to tell them by). Field numbers are those of
+    ``tsl/profiler/protobuf/xplane.proto``: XSpace.planes 1; XPlane.name 2,
+    lines 3, event_metadata 4, stat_metadata 5; XLine.name 2, timestamp_ns
+    3, events 4; XEvent.metadata_id 1, offset_ps 2, duration_ps 3, stats 4;
+    XEventMetadata.name 2, stats 5; XStat.metadata_id 1, uint64_value 3,
+    int64_value 4, str_value 5, ref_value 7; XStatMetadata.name 2."""
     with open(path, "rb") as f:
         space = memoryview(f.read())
     planes = []
@@ -287,18 +306,17 @@ def read_xspace(path: str) -> List[dict]:
                 key, value = _map_entry(field)
                 stat_names[key] = next(
                     (_text(v) for n, v in _fields(value) if n == 2), "")
-        op_stat = next((k for k, v in stat_names.items() if v == "tf_op"), None)
+        known = set(stat_names.values())
         named = {}
         for key, meta in events.items():
             event_name, op_name = "", ""
             for number, field in _fields(meta):
                 if number == 2:
                     event_name = _text(field)
-                elif number == 5 and op_stat is not None:
-                    stat = dict(_fields(field))
-                    if stat.get(1) == op_stat:
-                        op_name = (_text(stat[5]) if 5 in stat
-                                   else stat_names.get(stat.get(7), ""))
+                elif number == 5 and "tf_op" in known:
+                    stat, value = _stat(field, stat_names)
+                    if stat == "tf_op":
+                        op_name = value
             named[key] = (event_name, op_name)
         out_lines = []
         for line in lines:
@@ -309,43 +327,93 @@ def read_xspace(path: str) -> List[dict]:
                 elif number == 3:
                     t0_ns = field
                 elif number == 4:
-                    event = dict(_fields(field))
+                    event, module, run_id = {}, "", None
+                    for number, value in _fields(field):
+                        if number != 4:
+                            event[number] = value
+                        elif "hlo_module" in known:
+                            stat, value = _stat(value, stat_names)
+                            if stat == "hlo_module":
+                                module = value
+                            elif stat == "run_id":
+                                run_id = value
                     event_name, op_name = named.get(event.get(1), ("", ""))
                     out_events.append((
                         event_name, op_name,
                         t0_ns * 1e-9 + event.get(2, 0) * 1e-12,
-                        event.get(3, 0) * 1e-12))
+                        event.get(3, 0) * 1e-12, module, run_id))
             out_lines.append({"name": line_name, "events": out_events})
         planes.append({"name": name, "lines": out_lines})
     return planes
 
 
-def read_scopes(trace_dir: str) -> List[dict]:
-    """One :func:`reduce_scopes` dict per TPU device in the newest trace under
-    ``trace_dir``, in the order :func:`benchmark.trace_reduce.read_xplane`
-    gives its own. A trace of the CPU backend has no such plane (and its
-    operations carry no ``op_name``): nothing is returned for it."""
+def newest_xplane(trace_dir: str) -> Optional[str]:
     files = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not files:
-        return []
-    window = window_thread = None
-    devices: Dict[str, List[ScopedOp]] = {}
-    threads: Dict[object, List[Span]] = {}
-    for plane in read_xspace(files[-1]):
+    return files[-1] if files else None
+
+
+HOST_DEVICE = "/host:CPU"
+
+
+def read_devices(trace_dir: str) -> dict:
+    """The newest trace under ``trace_dir`` as tuples, parsed once:
+    ``devices`` maps each TPU plane's name to its ``ops``
+    (:data:`ScopedOp`, the ``XLA Ops`` line) and ``launches`` (name, start_s,
+    duration_s of the ``XLA Modules`` line), ``threads`` each host thread to
+    its ``pml.*`` spans, ``window`` and ``window_thread`` say where the
+    benchmark's window span lies. A trace of the CPU backend (the rehearsal)
+    has no such plane: its one device is :data:`HOST_DEVICE`, the operations
+    are the host's events that carry an ``hlo_module`` (they have no
+    ``op_name``, so no scope), a launch is one run of a module from its first
+    operation to its last. ``path`` is the file read, or nothing where there
+    is none."""
+    path = newest_xplane(trace_dir)
+    found = {"path": path, "devices": {}, "threads": {}, "window": None,
+             "window_thread": None}
+    host_ops: List[ScopedOp] = []
+    host_runs: Dict[tuple, List[float]] = {}
+    for plane in read_xspace(path) if path else ():
         is_device = plane["name"].startswith("/device:") and "TPU" in plane["name"]
         for index, line in enumerate(plane["lines"]):
             thread = (plane["name"], index)
-            for name, op_name, start, dur in line["events"]:
+            for name, op_name, start, dur, module, run_id in line["events"]:
                 if name == WINDOW_SPAN:
-                    window, window_thread = (start, start + dur), thread
-                elif is_device and line["name"] == "XLA Ops":
-                    devices.setdefault(plane["name"], []).append(
-                        (op_name, name, start, dur))
+                    found["window"] = (start, start + dur)
+                    found["window_thread"] = thread
+                elif is_device and line["name"] in ("XLA Ops", "XLA Modules"):
+                    device = found["devices"].setdefault(
+                        plane["name"], {"ops": [], "launches": []})
+                    if line["name"] == "XLA Ops":
+                        device["ops"].append((op_name, name, start, dur))
+                    else:
+                        device["launches"].append((name, start, dur))
                 elif not is_device and name.startswith(PREFIX):
-                    threads.setdefault(thread, []).append((name, start, dur))
-    return [reduce_scopes(ops, threads, window, window_thread)
-            for _, ops in sorted(devices.items())]
+                    found["threads"].setdefault(thread, []).append(
+                        (name, start, dur))
+                elif not is_device and module and dur > 0:
+                    host_ops.append((op_name, name, start, dur))
+                    run = host_runs.setdefault((module, run_id),
+                                               [start, start + dur])
+                    run[0] = min(run[0], start)
+                    run[1] = max(run[1], start + dur)
+    if host_ops and not found["devices"]:
+        found["devices"][HOST_DEVICE] = {
+            "ops": host_ops,
+            "launches": [(module, lo, hi - lo)
+                         for (module, _), (lo, hi) in host_runs.items()]}
+    return found
+
+
+def read_scopes(trace_dir: str) -> List[dict]:
+    """One :func:`reduce_scopes` dict per device in the newest trace under
+    ``trace_dir``, in the order :func:`benchmark.trace_reduce.read_xplane`
+    gives its own (which holds these keys too: this is the command line's
+    way in)."""
+    found = read_devices(trace_dir)
+    return [reduce_scopes(device["ops"], found["threads"], found["window"],
+                          found["window_thread"])
+            for _, device in sorted(found["devices"].items())]
 
 
 def main(argv=None) -> int:
@@ -355,7 +423,7 @@ def main(argv=None) -> int:
         return 2
     devices = read_scopes(args[0])
     if not devices:
-        print(f"no .xplane.pb with TPU operations under {args[0]}",
+        print(f"no .xplane.pb with operations under {args[0]}",
               file=sys.stderr)
         return 1
     for index, reduced in enumerate(devices):
