@@ -15,9 +15,10 @@ Sub-benchmarks:
   1. Dense GLM hot loop (primary metric): L2 logistic value+gradient passes
      (the reference's ValueAndGradientAggregator treeAggregate, SURVEY.md
      §2.2) on N=262144 x D=512, bfloat16 feature storage. The path is
-     AUTOTUNED at runtime: the single-pass fused Pallas kernel
-     (ops/fused_glm.py) races the two-pass XLA pipeline on the live device
-     and the winner is measured.
+     raced here, by the bench: the single-pass fused Pallas kernel families
+     (ops/fused_glm.py race_fused_block_rows) against the two-pass XLA
+     pipeline on the live device, and the winner is measured. (Training
+     does not race: it selects from the shape, select_fused_block_rows.)
   2. Sparse-wide regime: D=1,048,576 features, 64 nnz/row through
      SparseFeatures (the reference's actual production shape — ~2M features,
      Driver.scala:334) — gather + segment-sum margins, scatter-add gradient.

@@ -1,29 +1,29 @@
-"""Fused GLM value+gradient Pallas kernel — the training hot loop.
+"""Fused GLM value+gradient Pallas kernels — the training hot loop.
 
 The GLM hot loop (ValueAndGradientAggregator semantics, SURVEY.md §2.2,
 reference spec function/ValueAndGradientAggregator.scala:120-139) is
-HBM-bandwidth-bound on TPU: the two XLA GEMV passes (margin ``X @ w``,
-gradient ``d @ X``) each stream the whole (N, D) feature matrix from HBM.
-This kernel fuses them into ONE pass — each row block is loaded into VMEM
-once and used for both the margin matmul and the gradient outer-product —
-and pairs with bfloat16 feature storage (f32 accumulation on the MXU) for
-another 2x traffic cut: ~4x less HBM traffic than the naive f32 two-pass.
+HBM-bandwidth-bound on TPU: the two XLA passes (margin ``X @ w``, gradient
+``d @ X``) each stream the whole (N, D) feature matrix from HBM. The kernels
+here make them ONE pass — each block of rows is loaded into VMEM once and
+used for the margins and for the gradient — which pairs with bfloat16
+feature storage for another 2x traffic cut.
 
-The kernel is generic over any :class:`PointwiseLoss` and also accumulates
+The kernels are generic over any :class:`PointwiseLoss` and also give
 ``sum(d)`` so callers can reconstruct the normalization-shift gradient term
-(``grad_eff = X^T d - shifts * sum(d)``) without a second data pass. It
-therefore slots directly into ``GLMObjective.value_and_grad`` (see
-``fused_block_rows`` there) behind a runtime autotune:
-:func:`select_fused_block_rows` times the kernel against the two-pass XLA
-path on the live device and returns the winning block size — or ``None``
-when XLA wins or the shape/platform is ineligible — so the fused path is
-the default exactly where it is faster.
+(``grad_eff = X^T d - shifts * sum(d)``) without a second data pass. They
+slot into ``GLMObjective.value_and_grad`` (see ``fused_block_rows`` there)
+behind :func:`select_fused_block_rows`, a pure function of platform, dtype
+and shape: the ``vpu`` family's kernel on a TPU for a matrix of more than
+``MIN_MATRIX_BYTES``, ``None`` (the two-pass path) elsewhere.
+That family multiplies and reduces on the vector unit, so float32 storage is
+float32 arithmetic, and reads the matrix in the layout the device holds it
+in (:func:`held_column_major`), so nothing is padded or copied. The other
+families (``grid`` on the matrix unit, ``manual``, the pure-XLA ``scan``)
+stay for :func:`race_fused_block_rows`, the bench's race.
 
-Numerically: margins/loss/derivative are computed in f32; only the feature
-matrix (and the per-block derivative entering the second matmul) are bf16.
-Padding rows carry weight 0 and contribute exactly nothing (hard-masked, so
-even inf/nan garbage in padding rows is zeroed). Runs in interpreter mode
-on the CPU backend only (tests).
+Numerically: margins/loss/derivative are computed in f32. Zero-weight rows
+contribute exactly nothing (hard-masked, so even an inf/nan loss on such a
+row is zeroed). Runs in interpreter mode on the CPU backend only (tests).
 """
 
 from __future__ import annotations
@@ -168,50 +168,6 @@ def _unpack_outputs(loss_sum, grad, sumd):
     return loss_sum[0, 0], grad[0], sumd[0, 0]
 
 
-def _make_vpu_kernel(loss: PointwiseLoss):
-    """Grid kernel with BOTH contractions as elementwise multiply +
-    reduction on the VPU (no matmuls): z via a lane reduction over D,
-    the gradient via a sublane reduction over the row block. Escapes the
-    M=1 MXU GEVM ceiling (see AUTOTUNE_CANDIDATES) at the cost of f32
-    elementwise work the VPU can sustain at full HBM rate."""
-
-    def _kernel(
-        x_ref, y_ref, wt_ref, off_ref, w_ref,
-        loss_out, grad_out, sumd_out,
-        acc_grad, acc_loss, acc_sumd,
-    ):
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _():
-            acc_grad[:] = jnp.zeros_like(acc_grad)
-            acc_loss[:] = jnp.zeros_like(acc_loss)
-            acc_sumd[:] = jnp.zeros_like(acc_sumd)
-
-        x = x_ref[:].astype(jnp.float32)  # (BN, D)
-        w_row = w_ref[:]  # (1, D) f32 — marshalled row-major for the VPU
-        y = y_ref[:]
-        wt = wt_ref[:]
-        off = off_ref[:]
-
-        z = jnp.sum(x * w_row, axis=1, keepdims=True) + off  # (BN, 1)
-        lv = loss.loss(z, y)
-        wl = jnp.where(wt > 0.0, wt * lv, 0.0)
-        d = jnp.where(wt > 0.0, wt * loss.d1(z, y), 0.0)  # (BN, 1)
-
-        acc_loss[:] += jnp.sum(wl, keepdims=True).reshape(1, 1)  # lint: bitwise-reduction — pallas block-local accumulate; order pinned by the sequential grid
-        acc_sumd[:] += jnp.sum(d, keepdims=True).reshape(1, 1)  # lint: bitwise-reduction — pallas block-local accumulate; order pinned by the sequential grid
-        acc_grad[:] += jnp.sum(x * d, axis=0, keepdims=True)  # (1, D)  # lint: bitwise-reduction — pallas block-local accumulate; order pinned by the sequential grid
-
-        @pl.when(i == pl.num_programs(0) - 1)
-        def _():
-            loss_out[:] = acc_loss[:]
-            grad_out[:] = acc_grad[:]
-            sumd_out[:] = acc_sumd[:]
-
-    return _kernel
-
-
 # Mosaic's default scoped-VMEM limit, and how far a kernel may raise it
 # (a v5e core has 128 MiB of VMEM; the compiler refuses a limit it cannot
 # place, and the race records the refusal).
@@ -219,7 +175,215 @@ _DEFAULT_SCOPED_VMEM = 16 << 20
 _MAX_SCOPED_VMEM = 100 << 20
 
 
-def _grid_vmem_limit(block_rows: int, d: int, itemsize: int, vpu: bool) -> Optional[int]:
+LANES = 128
+# Sublane tiles (8 float32 rows of the transposed block, 16 bfloat16) one
+# step of the rows-in-lanes kernel's inner loops handles: Mosaic does not
+# pipeline a loop's steps, so a step of one tile waits out its own loads
+# (PERF.md section 6, PR 32: the race's table).
+_LANES_UNROLL = 5
+# The most 128-row chunks a rows-in-lanes block may have: a chunk is one
+# vector register of margin sums all through the pass, and the chip has 64.
+_LANES_MAX_CHUNKS = 16
+
+
+def _row_terms(loss: PointwiseLoss, z, y, wt):
+    """(weighted loss, weighted slope) of each row from its margin, hard
+    masked as in every family: a zero-weight row contributes an exact 0 even
+    where its loss is inf or nan (a padding row, Poisson's exp overflow)."""
+    alive = wt > 0.0
+    return (jnp.where(alive, wt * loss.loss(z, y), 0.0),
+            jnp.where(alive, wt * loss.d1(z, y), 0.0))
+
+
+def held_column_major(n: int, d: int) -> bool:
+    """Whether the TPU holds an ``(n, d)`` array column-major, that is as a
+    row-major ``(d, n)``: the device's default layout is the order that pads
+    least under its (8, 128) tiles, row-major on a tie
+    (``f32[400000,2000]{0,1:T(8,128)}``: 400,000 is a multiple of 128 and
+    2,000 is not). ``tests/test_dense_grid_reference.py`` holds this rule to
+    the v5e's compiler."""
+    pad = lambda size, tile: -(-size // tile) * tile
+    return pad(d, 8) * pad(n, LANES) < pad(n, 8) * pad(d, LANES)
+
+
+def _make_lanes_kernel(loss: PointwiseLoss, d: int, rows: int, tile: int):
+    """The ``vpu`` family on a matrix held column-major: a block is
+    ``(d, rows)`` of the transposed matrix, rows along lanes, so the row
+    vectors are lane-dense ``(1, rows)`` and neither contraction crosses
+    lanes. Margins: ``tile`` features at a time, multiplied by their
+    coefficients (spread over the lanes outside the kernel) and added into
+    one ``(tile, 128)`` sum per 128 rows, then reduced over sublanes. The
+    gradient: each feature's products with the rows' slopes added over the
+    block's 128-row chunks into a ``(d, 128)`` output block that stays in
+    VMEM across the grid; its lanes are summed once, after the kernel. Loss
+    terms and slopes go out per row and are summed after the kernel too."""
+    chunks = rows // LANES
+    group = tile * _LANES_UNROLL
+    d_main = d - d % group
+    lane = lambda k: slice(k * LANES, (k + 1) * LANES)
+
+    def kernel(x_ref, y_ref, wt_ref, off_ref, w_ref, wl_out, d_out, grad_out):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            grad_out[...] = jnp.zeros_like(grad_out)
+
+        def features(start, size):
+            return [x_ref[pl.ds(start, size), lane(k)].astype(jnp.float32)
+                    for k in range(chunks)]
+
+        def margin_step(g, sums):
+            start = pl.multiple_of(g * group, group)
+            for t in range(_LANES_UNROLL):
+                at = start + t * tile
+                wg = w_ref[pl.ds(at, tile), :]
+                sums = tuple(s + xk * wg for s, xk in zip(sums, features(at, tile)))
+            return sums
+
+        sums = lax.fori_loop(
+            0, d_main // group, margin_step,
+            tuple(jnp.zeros((tile, LANES), jnp.float32) for _ in range(chunks)))
+        zs = [jnp.sum(s, axis=0, keepdims=True) for s in sums]  # lint: bitwise-reduction — pallas block-local reduce over the feature axis
+        if d_main < d:  # the features the loop's step does not divide
+            wg = w_ref[d_main:d, :]
+            zs = [z + jnp.sum(xk * wg, axis=0, keepdims=True)  # lint: bitwise-reduction — pallas block-local reduce over the feature axis
+                  for z, xk in zip(zs, features(d_main, d - d_main))]
+
+        slopes = []
+        for k, z in enumerate(zs):
+            wl, dk = _row_terms(loss, z + off_ref[:, lane(k)],
+                                y_ref[:, lane(k)], wt_ref[:, lane(k)])
+            wl_out[:, lane(k)], d_out[:, lane(k)] = wl, dk
+            slopes.append(dk)
+
+        def add_products(start, size, spread):
+            total = None
+            for xk, dk in zip(features(start, size), spread):
+                total = xk * dk if total is None else total + xk * dk
+            grad_out[pl.ds(start, size), :] += total
+
+        spread = [jnp.broadcast_to(dk, (tile, LANES)) for dk in slopes]
+
+        def grad_step(g, carry):
+            start = pl.multiple_of(g * group, group)
+            for t in range(_LANES_UNROLL):
+                add_products(start + t * tile, tile, spread)
+            return carry
+
+        lax.fori_loop(0, d_main // group, grad_step, 0)
+        if d_main < d:
+            add_products(d_main, d - d_main, [
+                jnp.broadcast_to(dk, (d - d_main, LANES)) for dk in slopes])
+
+    return kernel
+
+
+def _make_sublanes_kernel(loss: PointwiseLoss, d: int, rows: int, tile: int):
+    """The ``vpu`` family on a matrix held row-major whose width is a
+    multiple of 128: a block is ``(rows, d)``, rows along sublanes. The row
+    vectors stay lane-dense ``(1, rows)`` as in :func:`_make_lanes_kernel`;
+    what crosses between the two forms is a 128 x 128 transpose a 128-row
+    group each way. Margins: a group's rows times the coefficients (spread
+    over ``tile`` sublanes outside the kernel) added over the width's
+    128-lane chunks into 128 lane sums a row, transposed, and reduced over
+    sublanes to one lane-dense margin a row. The gradient: the slopes spread
+    over the lanes and transposed back to one a row, the products added over
+    the group's rows into a ``(tile, d)`` output block that stays in VMEM
+    across the grid; its sublanes are summed after the kernel."""
+    chunks = d // LANES
+    tiles = LANES // tile  # sublane tiles of a 128-row group
+    lane = lambda c: slice(c * LANES, (c + 1) * LANES)
+
+    def kernel(x_ref, y_ref, wt_ref, off_ref, w_ref, wl_out, d_out, grad_out):
+        @pl.when(pl.program_id(0) == 0)
+        def _():
+            grad_out[...] = jnp.zeros_like(grad_out)
+
+        def group(q, carry):
+            at = pl.multiple_of(q * LANES, LANES)
+            here = pl.ds(at, LANES)
+            features = lambda t, c: x_ref[
+                pl.ds(at + t * tile, tile), lane(c)].astype(jnp.float32)
+            sums = [jnp.zeros((tile, LANES), jnp.float32) for _ in range(tiles)]
+            for c in range(chunks):
+                wc = w_ref[:, lane(c)]
+                sums = [s + features(t, c) * wc for t, s in enumerate(sums)]
+            z = jnp.sum(jnp.concatenate(sums, axis=0).T, axis=0, keepdims=True)  # lint: bitwise-reduction — pallas block-local reduce over the feature axis
+            wl, slopes = _row_terms(
+                loss, z + off_ref[:, here], y_ref[:, here], wt_ref[:, here])
+            wl_out[:, here], d_out[:, here] = wl, slopes
+            spread = jnp.broadcast_to(slopes, (LANES, LANES)).T  # [row, lane] = slopes[row]
+            spread = [spread[t * tile:(t + 1) * tile, :] for t in range(tiles)]
+            for c in range(chunks):
+                total = None
+                for t, dt in enumerate(spread):
+                    p = features(t, c) * dt
+                    total = p if total is None else total + p
+                grad_out[:, lane(c)] += total
+            return carry
+
+        lax.fori_loop(0, rows // LANES, group, 0)
+
+    return kernel
+
+
+def _vpu_vmem_limit(rows: int, d: int, itemsize: int) -> Optional[int]:
+    """``vmem_limit_bytes`` of the ``vpu`` family's kernels: the pipeline's
+    two buffers of the matrix block, of the spread coefficients and of the
+    gradient block (at most ``(d, 128)`` float32 each), and the row
+    vectors."""
+    need = 2 * rows * d * itemsize + 4 * d * LANES * 4 + 10 * 8 * rows * 4
+    if need + (2 << 20) <= _DEFAULT_SCOPED_VMEM:
+        return None
+    return min(need + (4 << 20), _MAX_SCOPED_VMEM)
+
+
+@functools.lru_cache(maxsize=64)
+def _fused_fn_vpu(loss: PointwiseLoss, block_rows: int, interpret: bool, lanes: bool):
+    """Jitted single-pass (loss_sum, grad, sum_d) over the first
+    ``n // block_rows`` blocks of the matrix, in the orientation the device
+    holds it in: ``lanes`` for column-major (the kernel reads the transpose,
+    a bitcast), else row-major with a width that is a multiple of 128."""
+
+    @jax.jit
+    def call(x, y, weights, offsets, w):
+        n, d = x.shape
+        grid = n // block_rows
+        tile = 8 * (4 // x.dtype.itemsize)
+        w = w.astype(jnp.float32)
+        if lanes:
+            kernel = _make_lanes_kernel(loss, d, block_rows, tile)
+            x, block, at = x.T, (d, block_rows), lambda i: (0, i)
+            spread = jnp.broadcast_to(w[:, None], (d, LANES))
+        else:
+            kernel = _make_sublanes_kernel(loss, d, block_rows, tile)
+            block, at = (block_rows, d), lambda i: (i, 0)
+            spread = jnp.broadcast_to(w, (tile, d))
+        row = lambda v: v.reshape(1, n).astype(jnp.float32)
+        rows_spec = pl.BlockSpec((1, block_rows), lambda i: (0, i))
+        whole = pl.BlockSpec(spread.shape, lambda i: (0, 0))
+        # under shard_map the outputs vary over the mesh axes the shard does
+        out = functools.partial(
+            jax.ShapeDtypeStruct, dtype=jnp.float32, vma=jax.typeof(x).vma)
+        covered = out((1, grid * block_rows))
+        wl, slopes, grad = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[pl.BlockSpec(block, at), rows_spec, rows_spec, rows_spec, whole],
+            out_specs=[rows_spec, rows_spec, whole],
+            out_shape=[covered, covered, out(spread.shape)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=_vpu_vmem_limit(block_rows, d, x.dtype.itemsize),
+            ),
+            interpret=interpret,
+        )(x, row(y), row(weights), row(offsets), spread)
+        # one reduction each after the kernel, as on the two-pass path
+        return jnp.sum(wl), jnp.sum(grad, axis=1 if lanes else 0), jnp.sum(slopes)  # lint: bitwise-reduction — dense-family canonical arithmetic
+
+    return call
+
+
+def _grid_vmem_limit(block_rows: int, d: int, itemsize: int) -> Optional[int]:
     """``vmem_limit_bytes`` for one grid-pipeline block config, or None
     while the pipeline's buffers fit the default limit (1024- and 2048-row
     blocks at 512 x bf16 compile unchanged).
@@ -230,35 +394,24 @@ def _grid_vmem_limit(block_rows: int, d: int, itemsize: int, vpu: bool) -> Optio
     That alone is 20 MiB at 4096 x 512 bf16 (the compiler's own figure on a
     v5e, refused at the 16 MiB default). The raised limit adds room for
     the kernel's column temporaries (z, loss, derivative, ... — lane-padded
-    the same way) and, for the VPU family, the f32 copy of the x block
-    and its product."""
+    the same way)."""
     column = block_rows * 128 * 4
     buffers = 2 * (block_rows * d * itemsize + 3 * column)
     if buffers + (2 << 20) <= _DEFAULT_SCOPED_VMEM:
         return None
-    temporaries = 6 * column + (2 * block_rows * d * 4 if vpu else 0)
-    return min(buffers + temporaries + (2 << 20), _MAX_SCOPED_VMEM)
+    return min(buffers + 6 * column + (2 << 20), _MAX_SCOPED_VMEM)
 
 
 @functools.lru_cache(maxsize=64)
-def _fused_fn(loss: PointwiseLoss, block_rows: int, interpret: bool, vpu: bool = False):
-    """Jitted single-pass (loss_sum, grad, sum_d) for one loss/block config."""
-    kernel = _make_vpu_kernel(loss) if vpu else _make_kernel(loss)
+def _fused_fn(loss: PointwiseLoss, block_rows: int, interpret: bool):
+    """Jitted single-pass (loss_sum, grad, sum_d) of the ``grid`` family for
+    one loss/block config."""
+    kernel = _make_kernel(loss)
 
     @jax.jit
     def call(x, y, weights, offsets, w):
         n, d = x.shape
         grid = n // block_rows
-        inputs = _marshal_inputs(x, y, weights, offsets, w)
-        # the VPU formulation wants w row-major (1, D) so the broadcast
-        # multiply needs no in-kernel relayout
-        w_spec = (
-            pl.BlockSpec((1, d), lambda i: (0, 0))
-            if vpu
-            else pl.BlockSpec((d, 1), lambda i: (0, 0))
-        )
-        if vpu:
-            inputs = inputs[:4] + (inputs[4].reshape(1, d),)
         loss_sum, grad, sumd = pl.pallas_call(
             kernel,
             grid=(grid,),
@@ -267,7 +420,7 @@ def _fused_fn(loss: PointwiseLoss, block_rows: int, interpret: bool, vpu: bool =
                 pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
                 pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
                 pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-                w_spec,
+                pl.BlockSpec((d, 1), lambda i: (0, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((1, 1), lambda i: (0, 0)),
@@ -287,12 +440,10 @@ def _fused_fn(loss: PointwiseLoss, block_rows: int, interpret: bool, vpu: bool =
             # the grid axis is a pure reduction: no ordering constraint
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
-                vmem_limit_bytes=_grid_vmem_limit(
-                    block_rows, d, x.dtype.itemsize, vpu
-                ),
+                vmem_limit_bytes=_grid_vmem_limit(block_rows, d, x.dtype.itemsize),
             ),
             interpret=interpret,
-        )(*inputs)
+        )(*_marshal_inputs(x, y, weights, offsets, w))
         return _unpack_outputs(loss_sum, grad, sumd)
 
     return call
@@ -415,6 +566,41 @@ def _fused_fn_manual(loss: PointwiseLoss, block_rows: int, interpret: bool):
     return call
 
 
+def _two_pass_parts(loss, x, y, weights, offsets, w):
+    """(loss sum, X^T d, sum d) of a few rows in plain float32
+    multiply-and-reduce: the rows a kernel's block does not divide."""
+    xf = x.astype(jnp.float32)
+    z = jnp.sum(xf * w, axis=1) + offsets  # lint: bitwise-reduction — the margins' feature axis, not a slab batch axis
+    wl, d = _row_terms(loss, z, y, weights)
+    return jnp.sum(wl), jnp.sum(xf * d[:, None], axis=0), jnp.sum(d)  # lint: bitwise-reduction — dense-family canonical arithmetic
+
+
+def _vpu_value_grad_parts(loss, rows, interpret, x, y, weights, offsets, w):
+    """The ``vpu`` family: the matrix is read where it lies and never padded
+    or copied. A kernel in the orientation the device holds the matrix in
+    (:func:`held_column_major`) walks the whole blocks of rows; the rows the
+    block does not divide (fewer than a block) go through
+    :func:`_two_pass_parts`, as does a matrix held row-major at a width that
+    is no multiple of 128, which neither kernel serves."""
+    n, d = x.shape
+    lanes = held_column_major(n, d)
+    block = min(rows, n) // LANES * LANES
+    if lanes:
+        block = min(block, _LANES_MAX_CHUNKS * LANES)
+    elif d % LANES:
+        block = 0
+    covered = n // block * block if block else 0
+    parts = []
+    if covered:
+        parts.append(_fused_fn_vpu(loss, block, interpret, lanes)(x, y, weights, offsets, w))
+    if covered < n:
+        parts.append(_two_pass_parts(
+            loss, x[covered:], y[covered:], weights[covered:],
+            offsets[covered:], w.astype(jnp.float32)))
+    return tuple(sum(p) for p in zip(*parts))
+
+
+@jax.named_scope("pml.features.value_grad")
 def fused_value_grad_parts(
     loss: PointwiseLoss,
     x: jax.Array,
@@ -430,17 +616,21 @@ def fused_value_grad_parts(
     No regularization, no normalization — the caller owns that algebra
     (``GLMObjective.value_and_grad`` folds shifts/factors/L2 around these).
     ``x``: (N, D), any float dtype — bfloat16 recommended for bandwidth.
-    Rows are padded (weight 0) up to a block multiple.
 
-    ``block_rows``: an encoded (family, rows) candidate — positive =
-    automatic grid pipeline (MXU matmuls), negative = the manual
-    double-buffered variant with |block_rows| rows per chunk, >= VPU_MARK
-    = the VPU elementwise formulation (see _decode_block; the autotuner
-    races all three families and returns the winning encoding).
+    ``block_rows``: an encoded (family, rows) candidate (see _decode_block).
+    >= VPU_MARK = multiply-and-reduce on the vector unit, the family
+    :func:`select_fused_block_rows` hands out: float32 arithmetic, the
+    matrix read in the device's own layout (:func:`_vpu_value_grad_parts`).
+    The race's other families pad the rows (weight 0) up to a block
+    multiple: positive = automatic grid pipeline (MXU matmuls), negative =
+    the manual double-buffered variant with |block_rows| rows per chunk,
+    >= SCAN_MARK = the pure-XLA scan.
     """
     if interpret is None:
         interpret = _interpret_default()
     family, rows = _decode_block(block_rows)
+    if family == "vpu":
+        return _vpu_value_grad_parts(loss, rows, interpret, x, y, weights, offsets, w)
     block = min(rows, max(x.shape[0], 1))
     n, d = x.shape
     pad = (-n) % block
@@ -454,7 +644,7 @@ def fused_value_grad_parts(
     if family == "manual":
         fn = _fused_fn_manual(loss, block, interpret)
     else:
-        fn = _fused_fn(loss, block, interpret, vpu=family == "vpu")
+        fn = _fused_fn(loss, block, interpret)
     return fn(x, y, weights, offsets, w)
 
 
@@ -537,8 +727,83 @@ def reference_logistic_value_and_grad(x, y, weights, w, l2: float = 0.0):
 
 
 # ---------------------------------------------------------------------------
-# Runtime autotune: fused kernel vs. XLA two-pass, per (loss, shape, dtype)
+# Selection: from the shape, in microseconds. The race below is a bench and
+# diagnostic surface (bench.py, tools/); no training path calls it.
 # ---------------------------------------------------------------------------
+
+# The smallest matrix the kernel is handed on a TPU. The two-pass path reads
+# the matrix from HBM twice at every size raced, down to 8 MiB; from 31 MiB
+# up the kernel won by 1.46 to 1.93 times at every width and in either
+# orientation (64, 200, 512, 2,000, 2,048 wide; float32 and bfloat16), at
+# 16 MiB by 1.47, and at 8 MiB by 1.14 to 1.26, where its fixed 20 us an
+# evaluation show (PERF.md section 6, PR 32, second round). Under the line
+# the two-pass path stays: the per-entity problems, a few hundred rows of 8
+# to 22 features, are four orders of magnitude under it.
+MIN_MATRIX_BYTES = 32 << 20
+# Bytes of one pipeline buffer of the matrix block the kernels aim at: on
+# the chip 640, 1,024, 1,920 and 3,200 rows at 2,000 float32 and 512 to
+# 2,048 rows at 2,048 read alike (PERF.md section 6, PR 32).
+_BLOCK_BYTES = 8 << 20
+# Held row-major, a row pays some 2.7 ns for its group's two transposes and
+# its loss whatever its width, so a narrow matrix is bound by that and not
+# by HBM: at rows of 1 KiB (512 bfloat16) the kernel read 5.45 ms where the
+# two-pass path read 4.45, at 2 KiB (512 float32) 5.46 against 8.77, at
+# 4 KiB and 8 KiB (2,048 wide) it ran at HBM's pace, 1.97 times the
+# two-pass path's (PERF.md section 6, PR 32). The kernel unrolls over the
+# width's 128-lane chunks, which bounds the width it is built for.
+_SUBLANES_MIN_ROW_BYTES = 2048
+_SUBLANES_MAX_WIDTH = 1 << 14
+
+
+def _vpu_block_rows(n: int, d: int, itemsize: int, lanes: bool) -> Optional[int]:
+    """Rows a block of the ``vpu`` family's kernels: the most 128-row chunks
+    within ``_BLOCK_BYTES`` (and ``_LANES_MAX_CHUNKS``); a count from half
+    of that up that divides ``n`` is preferred (no rows are left to the
+    two-pass tail: 640 at 400,000 x 2,000 float32). None where 128 rows of
+    the matrix do not fit the kernel's VMEM."""
+    if _vpu_vmem_limit(LANES, d, itemsize) == _MAX_SCOPED_VMEM:
+        return None
+    chunks = max(1, _BLOCK_BYTES // (d * itemsize * LANES))
+    if lanes:
+        chunks = min(chunks, _LANES_MAX_CHUNKS)
+    for c in range(chunks, chunks // 2, -1):
+        if n % (c * LANES) == 0:
+            return c * LANES
+    return chunks * LANES
+
+
+def select_fused_block_rows(n: int, d: int, dtype=jnp.bfloat16) -> Optional[int]:
+    """The one-pass kernel's encoded block for an (N, D) dense GLM pass, or
+    ``None`` where the two-pass XLA path should run. A pure function of what
+    the caller can see before tracing — platform, storage dtype, the static
+    shape (under ``shard_map``: the local shard's) — that builds no data and
+    times nothing. The vmapped solves do not ask: ``train_glm_grid_vmapped``
+    clears the block and the per-entity solves never carry one (a vmapped
+    ``pallas_call`` grows a grid axis the kernel's accumulation does not
+    know).
+
+    ``PHOTON_ML_TPU_FUSED``: "auto" (default) gives the kernel on a TPU
+    where the matrix is over ``MIN_MATRIX_BYTES`` and, held row-major, its rows
+    are wide enough for HBM to bound the kernel; "0" never; "1" wherever the
+    kernel can run, whatever the platform and the size (interpreter mode on
+    the CPU, for tests). Never for float64.
+    """
+    mode = os.environ.get(_FUSED_ENV, "auto")
+    dtype = jnp.dtype(dtype)
+    if mode == "0" or dtype not in (jnp.float32, jnp.bfloat16):
+        return None
+    lanes = held_column_major(n, d)
+    if not lanes and (d % LANES or d > _SUBLANES_MAX_WIDTH):
+        return None
+    if mode != "1" and not (
+        _on_tpu()
+        and n * d * dtype.itemsize > MIN_MATRIX_BYTES
+        and (lanes or d * dtype.itemsize >= _SUBLANES_MIN_ROW_BYTES)
+    ):
+        return None
+    rows = _vpu_block_rows(n, d, dtype.itemsize, lanes)
+    return None if rows is None else VPU_MARK + rows
+
 
 _autotune_cache: dict = {}
 _autotune_timings: dict = {}  # key -> {candidate: sec/pass} from the race
@@ -577,22 +842,21 @@ def _time_value_and_grad(vg_fn, w0, data, iters: int = 16) -> float:
     return best
 
 
-def select_fused_block_rows(
+def race_fused_block_rows(
     loss: PointwiseLoss,
     n: int,
     d: int,
     dtype=jnp.bfloat16,
     candidates: Tuple[int, ...] = AUTOTUNE_CANDIDATES,
 ) -> Optional[int]:
-    """Pick the fused-kernel block size for an (N, D) dense GLM pass, or
-    ``None`` when the plain XLA path should be used.
-
-    Measures on the live default device with synthetic data (row count
-    capped at 2^17 — throughput is row-count-invariant past that). Results
-    are cached per (loss, n, d, dtype, platform). Controlled by
-    ``PHOTON_ML_TPU_FUSED``: "auto" (default) races fused vs. XLA on TPU,
-    "0" disables the fused path, "1" forces it (best fused candidate, no
-    XLA comparison; runs in interpreter mode on the CPU, for testing).
+    """Time every candidate and the two-pass XLA path on the live default
+    device with synthetic data (row count capped at 2^17) and return the
+    fastest, ``None`` for XLA. A bench and diagnostic surface: seconds of
+    device time and a program per candidate, so no training path calls it
+    (they call :func:`select_fused_block_rows`). Results are cached per
+    (loss, n, d, dtype, platform). ``PHOTON_ML_TPU_FUSED``: "0" returns
+    None, "1" leaves XLA out of the race and runs off a TPU too
+    (interpreter mode), "auto" races on a TPU only.
     """
     mode = os.environ.get(_FUSED_ENV, "auto")
     if mode == "0":
@@ -667,7 +931,7 @@ def autotune_report(loss: PointwiseLoss, n: int, d: int, dtype=jnp.bfloat16) -> 
     single X stream (GB/s; the two-pass XLA entry, key "xla", reads X twice
     so its effective traffic is 2x the listed figure). Diagnostic surface
     for bench.py."""
-    select_fused_block_rows(loss, n, d, dtype)  # populate cache
+    race_fused_block_rows(loss, n, d, dtype)  # populate cache
     mode = os.environ.get(_FUSED_ENV, "auto")
     platform = jax.devices()[0].platform
     n_probe = min(n, 1 << 17)

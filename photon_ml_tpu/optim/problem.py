@@ -75,8 +75,8 @@ class GLMOptimizationProblem:
     # box constraints on coefficients (OptimizationUtils.projectCoefficientsToHypercube);
     # densified (lower, upper) arrays — see optim/constraints.py
     constraints: Optional["BoxConstraints"] = None
-    # single-pass Pallas value+grad kernel block size, set by the runtime
-    # autotune (ops.fused_glm.select_fused_block_rows); None = XLA two-pass
+    # single-pass Pallas value+grad kernel block, set from the batch's shape
+    # (ops.fused_glm.select_fused_block_rows); None = XLA two-pass
     fused_block_rows: Optional[int] = None
     # carry per-iteration coefficient snapshots through the solve (the
     # ModelTracker analogue backing --validate-per-iteration; costs

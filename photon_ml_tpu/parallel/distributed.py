@@ -54,12 +54,12 @@ class DistributedFixedEffectSolver:
         self._fused_tuned = False
 
     def _maybe_autotune_fused(self, batch: GLMBatch) -> None:
-        """Race the single-pass Pallas kernel vs. XLA on the per-device shard
-        shape and adopt it if it wins (no-op off TPU / for sparse layouts)."""
+        """Adopt the one-pass kernel where the per-device shard's shape calls
+        for it (``select_fused_block_rows``: from the shape, nothing timed;
+        a no-op off a TPU and for sparse layouts)."""
         if self._fused_tuned:
             return
         self._fused_tuned = True
-        from photon_ml_tpu.ops import losses as losses_mod
         from photon_ml_tpu.ops.features import DenseFeatures
         from photon_ml_tpu.ops.fused_glm import select_fused_block_rows
 
@@ -68,7 +68,6 @@ class DistributedFixedEffectSolver:
         ):
             return
         block = select_fused_block_rows(
-            losses_mod.for_task(self.problem.task),
             batch.num_rows // self.ctx.num_devices,
             batch.dim,
             batch.features.matrix.dtype,

@@ -65,15 +65,15 @@ def train_glm_grid(
     """
     sorted_weights = sorted(reg_weights, reverse=True)
 
-    from photon_ml_tpu.ops import losses as losses_mod
     from photon_ml_tpu.ops.features import DenseFeatures
     from photon_ml_tpu.ops.fused_glm import select_fused_block_rows
 
     if problem.fused_block_rows is None and isinstance(batch.features, DenseFeatures):
-        # adopt the single-pass Pallas kernel where the live-device autotune
-        # says it beats XLA (returns None off TPU / when XLA wins)
+        # the one-pass kernel where the shape calls for it: a pure function
+        # of platform, dtype and shape (None off a TPU and for a matrix
+        # too small for the kernel to win), microseconds a job; the vmapped
+        # grid below never asks
         block = select_fused_block_rows(
-            losses_mod.for_task(problem.task),
             batch.num_rows,
             batch.dim,
             batch.features.matrix.dtype,
@@ -204,8 +204,8 @@ def train_glm_grid_vmapped(
     """
     sorted_weights = sorted(reg_weights, reverse=True)
     k = len(sorted_weights)
-    # the fused Pallas kernel is not raced here: vmapping a pallas_call
-    # adds a batch grid dimension the autotuner never measured
+    # the one-pass kernel does not serve a vmapped solve: vmapping a
+    # pallas_call adds a grid axis its accumulation does not know
     if problem.fused_block_rows is not None:
         problem = dataclasses.replace(problem, fused_block_rows=None)
     lams = jnp.asarray(sorted_weights, real_dtype())

@@ -6,8 +6,11 @@ benchmark cell ``glm-dense-epsilon.grid4``) against
 ``benchmark/references/glm_dense.py`` (float32 ``jax.numpy`` at the highest
 matmul precision, its own L-BFGS, nothing of ``photon_ml_tpu`` imported), on
 seeded data at a small size whose width is no multiple of 128, as the cell's
-2,000 is not: the one-pass kernel never serves such a width
-(``ops/fused_glm.select_fused_block_rows``), so this is the two-pass path.
+2,000 is not. Off a TPU ``ops/fused_glm.select_fused_block_rows`` keeps the
+two-pass path, so the grid here is that; the one-pass kernel the cell runs on
+the chip is held to float64 in ``tests/test_fused_glm.py``, and at the end of
+this file the chip's compiler says what ``training._solve`` is made of at the
+cell's shape.
 """
 
 from __future__ import annotations
@@ -187,16 +190,22 @@ def test_a_seed_shuffles_the_configurations_data_set():
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to check
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.mark.parametrize("product", ["matvec", "rmatvec"])
@@ -215,3 +224,172 @@ def test_dense_products_stay_float32_on_the_v5e(one_chip, product):
     ).lower(shape(rows, width), vector).compile().as_text()
     assert "multiply_reduce_fusion" in text
     assert "convolution" not in text and "bf16" not in text
+
+
+#: (rows, width) and whether the v5e holds it column-major: widths that are
+#: and are not multiples of 128, many and few rows, a tie
+HELD = [((400000, 2000), True), ((400000, 2048), False), ((400000, 200), True),
+        ((300, 2000), False), ((1000, 2000), False), ((1024, 2000), True),
+        ((100000, 127), False), ((100000, 129), True), ((8192, 40), True)]
+
+
+@pytest.mark.parametrize("storage", ["float32", "bfloat16"])
+def test_the_layout_rule_is_the_v5es(one_chip, storage):
+    """``fused_glm.held_column_major`` against the layout the v5e's compiler
+    gives a program's matrix parameter: the one-pass kernel reads the matrix
+    in that layout, and a wrong guess costs a copy of the matrix a launch."""
+    import re
+
+    from photon_ml_tpu.ops.fused_glm import held_column_major
+
+    dtype = jnp.dtype(storage)
+    for (rows, width), column_major in HELD:
+        assert held_column_major(rows, width) == column_major, (rows, width)
+        shape = lambda *s: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+        text = jax.jit(lambda m, v: m @ v).lower(
+            shape(rows, width), shape(width)).compile().as_text()
+        held = re.search(r"entry_computation_layout=\{\(\w+\[%d,%d\]\{([01],[01])"
+                         % (rows, width), text).group(1)
+        assert held == ("0,1" if column_major else "1,0"), (rows, width, held)
+
+
+@pytest.mark.parametrize("width", [2000, 2048])
+def test_the_cells_solve_reads_the_matrix_once_on_the_v5e(one_chip, monkeypatch, width):
+    """``training._solve`` at 400,000 x 2,000 float32 (the cell's shape, held
+    column-major) and at 2,048 wide (held row-major) with the block the
+    selection gives on a TPU, compiled for the v5e: the optimized program
+    calls the kernel once an evaluation (the one before the solver's loop and
+    the line search's), and holds no product, copy or bfloat16 convert of
+    the whole matrix."""
+    from photon_ml_tpu import training
+    from photon_ml_tpu.ops import fused_glm
+    from photon_ml_tpu.ops.features import DenseFeatures
+    from photon_ml_tpu.ops.normalization import NormalizationContext
+    from photon_ml_tpu.ops.objective import GLMBatch
+    from photon_ml_tpu.ops.regularization import RegularizationContext
+    from photon_ml_tpu.optim.common import OptimizerConfig
+    from photon_ml_tpu.optim.problem import GLMOptimizationProblem
+    from photon_ml_tpu.types import OptimizerType, TaskType
+
+    rows = 400000
+    # the process's backend is the CPU: steer the two places that ask
+    monkeypatch.setattr(fused_glm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fused_glm, "_interpret_default", lambda: False)
+    monkeypatch.delenv("PHOTON_ML_TPU_FUSED", raising=False)
+    block = fused_glm.select_fused_block_rows(rows, width, jnp.float32)
+    assert block is not None and fused_glm._decode_block(block)[0] == "vpu"
+    solver = _config()["sizes"]["solver"]
+    problem = GLMOptimizationProblem(
+        task=TaskType.LOGISTIC_REGRESSION, optimizer=OptimizerType.LBFGS,
+        optimizer_config=OptimizerConfig(
+            max_iterations=ITERATIONS, tolerance=0.0,
+            num_corrections=int(solver["corrections"])),
+        regularization=RegularizationContext.l2(100.0), fused_block_rows=block)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    batch = GLMBatch(DenseFeatures(shape(rows, width)),
+                     shape(rows), shape(rows), shape(rows))
+    training._solve.clear_cache()
+    compiled = training._solve.lower(
+        problem, batch, NormalizationContext.identity(), shape(width), shape()
+    ).compile()
+    training._solve.clear_cache()
+    _reads_the_matrix_once(compiled, rows, width)
+
+
+def _reads_the_matrix_once(compiled, rows, width):
+    """The optimized program of an L-BFGS solve on a ``rows`` x ``width``
+    float32 matrix (a device's own rows, where there are several)."""
+    import re
+
+    text = compiled.as_text()
+    whole = r"\[(%d,%d|%d,%d)\]" % (rows, width, width, rows)
+    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 2, len(calls)
+    assert all("pml.objective.value_and_grad/pml.features.value_grad" in l
+               for l in calls)
+    assert sum("pml.lbfgs.line_search" in l for l in calls) == 1
+    # every other line that names the whole matrix carries it or bitcasts it
+    for line in text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)" + whole + r"\S* (\S+?)\(", line)
+        if made:
+            assert made.group(1) == "f32", line[:200]
+            assert made.group(3) in ("parameter", "bitcast", "get-tuple-element"), line[:200]
+    assert not re.search(r"bf16" + whole, text)
+    assert "convolution" not in text
+    # the compiler's temporaries: megabytes, not the matrix's 3.2 GB again
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("width", [2000, 2048])
+def test_the_distributed_solve_runs_the_kernel_on_four_v5es(four_chips, monkeypatch, width):
+    """``DistributedFixedEffectSolver``'s program (``shard_map`` over the
+    rows with ``check_vma`` on, as it builds it) with 400,000 rows a chip,
+    compiled for the four chips of a v5e host: each chip's shard is held as
+    the one-chip matrix is, the kernel reads it once an evaluation, and the
+    shards' sums cross the mesh."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from photon_ml_tpu.ops import fused_glm
+    from photon_ml_tpu.ops.features import DenseFeatures
+    from photon_ml_tpu.ops.normalization import NormalizationContext
+    from photon_ml_tpu.ops.objective import GLMBatch
+    from photon_ml_tpu.ops.regularization import RegularizationContext
+    from photon_ml_tpu.optim.common import OptimizerConfig
+    from photon_ml_tpu.optim.problem import GLMOptimizationProblem
+    from photon_ml_tpu.parallel import DistributedFixedEffectSolver, MeshContext
+    from photon_ml_tpu.types import OptimizerType, TaskType
+
+    rows = 400000
+    monkeypatch.setattr(fused_glm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fused_glm, "_interpret_default", lambda: False)
+    monkeypatch.delenv("PHOTON_ML_TPU_FUSED", raising=False)
+    mesh = Mesh(np.array(four_chips), ("data",))
+    solver = DistributedFixedEffectSolver(
+        GLMOptimizationProblem(
+            task=TaskType.LOGISTIC_REGRESSION, optimizer=OptimizerType.LBFGS,
+            optimizer_config=OptimizerConfig(max_iterations=ITERATIONS, tolerance=0.0),
+            regularization=RegularizationContext.l2(100.0)),
+        MeshContext(mesh))
+    shape = lambda spec, *s: jax.ShapeDtypeStruct(
+        s, jnp.float32, sharding=NamedSharding(mesh, spec))
+    split, whole = PartitionSpec("data"), PartitionSpec()
+    n = rows * len(four_chips)
+    batch = GLMBatch(DenseFeatures(shape(split, n, width)),
+                     shape(split, n), shape(split, n), shape(split, n))
+    solver._maybe_autotune_fused(batch)
+    assert fused_glm._decode_block(solver.problem.fused_block_rows) == ("vpu", 640)
+    compiled = solver._build(NormalizationContext.identity()).lower(
+        batch, shape(whole, width), shape(whole)).compile()
+    _reads_the_matrix_once(compiled, rows, width)
+    assert "all-reduce" in compiled.as_text()
+
+
+#: (rows, width, storage): shapes just over the selection's line and odd ones
+#: over it: a tail of rows, fewer features than a tile, one 128-row chunk of
+#: a wide matrix, either orientation, either storage
+NEAR_THE_LINE = [(8197, 2000, "float32"), (300000, 30, "float32"), (1024, 10000, "float32"),
+                 (8320, 2048, "float32"), (16640, 2048, "bfloat16"), (66000, 300, "bfloat16")]
+
+
+@pytest.mark.parametrize("rows,width,storage", NEAR_THE_LINE)
+def test_what_the_selection_hands_out_compiles_on_the_v5e(one_chip, monkeypatch, rows, width, storage):
+    """A block ``select_fused_block_rows`` gives on a TPU is one the v5e's
+    compiler takes, reading the matrix in the layout it arrives in."""
+    import re
+
+    from photon_ml_tpu.ops import fused_glm, losses
+
+    monkeypatch.setattr(fused_glm, "_on_tpu", lambda: True)
+    monkeypatch.delenv("PHOTON_ML_TPU_FUSED", raising=False)
+    dtype = jnp.dtype(storage)
+    block = fused_glm.select_fused_block_rows(rows, width, dtype)
+    assert block is not None
+    shape = lambda *s, of=jnp.float32: jax.ShapeDtypeStruct(s, of, sharding=one_chip)
+    text = jax.jit(
+        lambda x, y, wt, off, w: fused_glm.fused_value_grad_parts(
+            losses.logistic, x, y, wt, off, w, block_rows=block, interpret=False)
+    ).lower(shape(rows, width, of=dtype), shape(rows), shape(rows), shape(rows),
+            shape(width)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    whole = r"\[(%d,%d|%d,%d)\]" % (rows, width, width, rows)
+    assert not re.search(r"= \w+" + whole + r"\S* (copy|transpose|fusion|convert)\(", text)

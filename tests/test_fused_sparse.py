@@ -711,7 +711,7 @@ class TestDenseAutotuneFailureLogging:
         fused_glm._autotune_timings.clear()
         fused_glm._autotune_failures.clear()
         n, d = 512, 128
-        block = fused_glm.select_fused_block_rows(
+        block = fused_glm.race_fused_block_rows(
             losses.logistic, n, d, dtype=jnp.float32,
             candidates=(256, 1 << 19),  # the second exceeds the probe rows
         )

@@ -52,8 +52,8 @@ def scopes_in(text):
 # -- device scopes ----------------------------------------------------------
 
 
-def _glm_batch(rng, sparse):
-    n, d, k = 32, 16, 4
+def _glm_batch(rng, sparse, n=32):
+    d, k = 16, 4
     labels = jnp.asarray(rng.integers(0, 2, n), jnp.float32)
     if sparse:
         feats = SparseFeatures(
@@ -74,13 +74,21 @@ GLM_PATHS = {
         "pml.features.matvec", "pml.features.rmatvec",
         "pml.features.sq_rmatvec", "pml.objective.value_and_grad",
         "pml.objective.hvp", "pml.objective.hessian_diagonal", "pml.tron.cg"}),
+    # the dense cell's path on the chip: the one-pass kernel in the place of
+    # the two products, under a scope of its own inside the evaluation's
+    "dense-lbfgs-one-pass": (False, OptimizerType.LBFGS, False, {
+        "pml.features.value_grad",
+        "pml.objective.value_and_grad", "pml.lbfgs.direction",
+        "pml.lbfgs.line_search", "pml.lbfgs.pair_update"}),
 }
 
 
-def _lowered_solve(batch, optimizer=OptimizerType.LBFGS, variance=False):
+def _lowered_solve(batch, optimizer=OptimizerType.LBFGS, variance=False,
+                   fused_block_rows=None):
     problem = GLMOptimizationProblem(
         LOGISTIC, optimizer, OptimizerConfig(max_iterations=3, tolerance=1e-6),
-        RegularizationContext.l2(1.0), compute_variance=variance)
+        RegularizationContext.l2(1.0), compute_variance=variance,
+        fused_block_rows=fused_block_rows)
     return training._solve.lower(
         problem, batch, NormalizationContext.identity(),
         jnp.zeros((batch.dim,), jnp.float32), jnp.float32(1.0))
@@ -88,14 +96,23 @@ def _lowered_solve(batch, optimizer=OptimizerType.LBFGS, variance=False):
 
 @pytest.mark.parametrize("path", sorted(GLM_PATHS))
 def test_glm_solve_lowers_with_its_scopes(rng, path):
+    from photon_ml_tpu.ops.fused_glm import VPU_MARK
+
     sparse, optimizer, variance, want = GLM_PATHS[path]
-    text = _lowered_solve(_glm_batch(rng, sparse), optimizer, variance).as_text(
-        debug_info=True)
+    one_pass = "pml.features.value_grad" in want
+    # 256 rows of 16 features are held column-major: two 128-row blocks
+    batch = _glm_batch(rng, sparse, n=256 if one_pass else 32)
+    text = _lowered_solve(
+        batch, optimizer, variance, VPU_MARK + 128 if one_pass else None
+    ).as_text(debug_info=True)
     assert scopes_in(text) == want
     assert "module @jit__solve" in text  # the name fe_solve_roofline reads
     # the kernels sit inside the objective's scope, so a kernel swap keeps it
-    assert "pml.objective.value_and_grad/pml.features.matvec" in text
-    if not sparse:
+    kernel = "value_grad" if one_pass else "matvec"
+    assert f"pml.objective.value_and_grad/pml.features.{kernel}" in text
+    if one_pass:
+        assert "pallas_call" in text
+    elif not sparse:
         assert "pml.tron.cg/while/body/pml.objective.hvp/pml.features.rmatvec" in text
 
 

@@ -59,6 +59,7 @@ ALLOWLIST = {
     # variant still reads
     "photon_ml_tpu/ops/fused_glm.py:_fused_fn.call": "autotune race shares inputs",
     "photon_ml_tpu/ops/fused_glm.py:_fused_fn_manual.call": "autotune race shares inputs",
+    "photon_ml_tpu/ops/fused_glm.py:_fused_fn_vpu.call": "the solve's dataset, read every evaluation",
     "photon_ml_tpu/ops/fused_glm.py:_time_value_and_grad": "bench-only race harness",
     # parallel/: shard_map wrappers over mesh-sharded slabs reused across
     # updates (the slabs ARE the dataset; donating them would tear it)
