@@ -14,11 +14,10 @@ line is printed and the exit code is then non-zero.
 Sub-benchmarks:
   1. Dense GLM hot loop (primary metric): L2 logistic value+gradient passes
      (the reference's ValueAndGradientAggregator treeAggregate, SURVEY.md
-     §2.2) on N=262144 x D=512, bfloat16 feature storage. The path is
-     raced here, by the bench: the single-pass fused Pallas kernel families
-     (ops/fused_glm.py race_fused_block_rows) against the two-pass XLA
-     pipeline on the live device, and the winner is measured. (Training
-     does not race: it selects from the shape, select_fused_block_rows.)
+     §2.2) on N=262144 x D=512, bfloat16 feature storage, on the path
+     training takes for that shape (ops/fused_glm.py
+     select_fused_block_rows: the one-pass kernel or the two-pass XLA
+     pipeline).
   2. Sparse-wide regime: D=1,048,576 features, 64 nnz/row through
      SparseFeatures (the reference's actual production shape — ~2M features,
      Driver.scala:334) — gather + segment-sum margins, scatter-add gradient.
@@ -122,7 +121,7 @@ def _scan_throughput(value_and_grad, w0, n_rows, batch, iters=SCAN_ITERS):
     scan = jax.jit(run)  # jit-ok: bench harness; carries reused across timed reps
     w1 = jax.block_until_ready(scan(w0, batch))[0]  # compile + warm
     # the timed call gets the warm call's carry, NOT w0 again, so it is
-    # novel work (see fused_glm._time_value_and_grad)
+    # novel work
     t0 = time.perf_counter()
     jax.block_until_ready(scan(w1, batch))
     dt = (time.perf_counter() - t0) / iters
@@ -171,23 +170,10 @@ def _bench_dense(extra, x_h, y_h, on_tpu=True):
         if rel_v > 5e-2 or rel_g > 5e-2:
             raise AssertionError(f"bf16 storage diverged from f32 path ({rel_v}, {rel_g})")
 
-    # runtime autotune: single-pass Pallas kernel families vs two-pass XLA.
-    # ONE autotune race: the selected block AND the published per-candidate
-    # record come from the same autotune_report call, so the dense_race
-    # evidence always describes the winner actually used (a second race
-    # could flip the ordering and publish a winner that differs from the
-    # measured block — ADVICE.md). A candidate the compiler refuses is
-    # recorded there as failed; a failure of the race itself fails the
-    # section.
-    report = fused_glm.autotune_report(losses.logistic, n, d, store_dtype)
-    block = report["winner"]
-    if on_tpu and report["candidates"]:
-        # keeping the race evidence in the record makes a bogus winner
-        # VISIBLE
-        extra["dense_race"] = report["candidates"]
-    extra["fused_block_rows"] = block  # None = XLA two-pass won (or off-TPU)
-    if block is not None:
-        extra["fused_family"] = "{}:{}".format(*fused_glm._decode_block(block))
+    # the path training takes for this shape: rows a block of the one-pass
+    # kernel, or None for the two-pass XLA pipeline (always off a TPU)
+    block = fused_glm.select_fused_block_rows(n, d, store_dtype)
+    extra["fused_block_rows"] = block
     obj = GLMObjective(losses.logistic, fused_block_rows=block)
     batch = GLMBatch.create(feats_store, labels)
 
@@ -200,9 +186,8 @@ def _bench_dense(extra, x_h, y_h, on_tpu=True):
         _log(f"fused parity (block={block}): value rel {rel_vf:.2e}, grad rel {rel_gf:.2e}")
         if rel_vf > 5e-2 or rel_gf > 5e-2:
             _log("fused kernel failed parity; falling back to XLA path")
-            extra["fused_block_rows"] = None
-            extra.pop("fused_family", None)  # the record must describe the
-            obj = obj_plain                  # path that actually ran
+            extra["fused_block_rows"] = None  # the record must describe
+            obj = obj_plain                   # the path that actually ran
 
     eps = _scan_throughput(
         lambda w, b: obj.value_and_grad(w, b, norm, 0.1),
@@ -220,17 +205,6 @@ def _bench_dense(extra, x_h, y_h, on_tpu=True):
     # traffic (y, w, z, d) is < 1% at D=512 and is ignored. TPU-only: an
     # HBM peak is meaningless against a CPU run.
     x_passes = 1 if extra["fused_block_rows"] else 2
-    if extra["fused_block_rows"] and extra.get("fused_family", "").startswith("scan"):
-        # the pure-XLA scan family is ALGORITHMICALLY one pass, but whether
-        # the block actually stays resident between the matvec and the
-        # rank-update is the compiler's call. 1-pass accounting UNDERSTATES
-        # achieved bandwidth if XLA re-reads the block (the conservative
-        # direction for an achieved-GB/s claim — 2-pass accounting could
-        # print a physically impossible >100% of HBM peak); flag it.
-        extra["dense_traffic_note"] = (
-            "scan family: 1-pass accounting (understates achieved GB/s if "
-            "XLA re-reads the block between contractions)"
-        )
     bytes_per_example = d * jnp.dtype(store_dtype).itemsize * x_passes
     achieved_gbs = eps * bytes_per_example / 1e9
     extra["dense_achieved_gb_s"] = round(achieved_gbs, 1)

@@ -662,8 +662,8 @@ def _lane_vg_fns(task, l2: float = 0.0):
 
 
 def _time_lane_vg(vg, w0, data, iters: int = 8) -> float:
-    """Seconds per vmapped value+grad pass, serialized on-chip (the
-    fused_glm race-timing discipline: scan-serialized, fresh carries)."""
+    """Seconds per vmapped value+grad pass, serialized on-chip by a scan
+    (a host loop would time the enqueue), each repeat on fresh carries."""
 
     def run(w, d):
         def step(w, _):

@@ -136,7 +136,7 @@ class GLMObjective:
     jit with sharded-array inputs, leave it None and XLA inserts the
     collectives itself.
 
-    ``fused_block_rows``: when set (from the batch's shape, by
+    ``fused_block_rows``: rows a block; when set (from the batch's shape, by
     ``ops.fused_glm.select_fused_block_rows``) and the batch is dense,
     ``value_and_grad`` runs the single-pass Pallas kernel — one HBM stream
     of X instead of the two-pass XLA pipeline — with the normalization and

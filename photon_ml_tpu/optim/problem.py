@@ -75,8 +75,8 @@ class GLMOptimizationProblem:
     # box constraints on coefficients (OptimizationUtils.projectCoefficientsToHypercube);
     # densified (lower, upper) arrays — see optim/constraints.py
     constraints: Optional["BoxConstraints"] = None
-    # single-pass Pallas value+grad kernel block, set from the batch's shape
-    # (ops.fused_glm.select_fused_block_rows); None = XLA two-pass
+    # rows a block of the one-pass dense value+grad kernel, set from the
+    # batch's shape (with_fused_block_for); None = XLA two-pass
     fused_block_rows: Optional[int] = None
     # carry per-iteration coefficient snapshots through the solve (the
     # ModelTracker analogue backing --validate-per-iteration; costs
@@ -166,3 +166,23 @@ class GLMOptimizationProblem:
         """lambda_1 * ||w||_1 + lambda_2/2 * ||w||^2 (GLOP.scala:235-278)."""
         l1, l2 = _split_reg_weight(self.regularization, reg_weight)
         return l1 * jnp.sum(jnp.abs(w)) + 0.5 * l2 * jnp.sum(jnp.square(w))  # lint: bitwise-reduction — l1/l2 reg over the fixed (D,) w, not a slab batch axis
+
+    # ------------------------------------------------------------------
+    def with_fused_block_for(
+        self, batch: GLMBatch, num_shards: int = 1
+    ) -> "GLMOptimizationProblem":
+        """This problem with ``fused_block_rows`` set where the batch calls
+        for the one-pass kernel: dense features, no block set by the caller,
+        and ``ops.fused_glm.select_fused_block_rows`` gives one for the shape
+        a device sees (the batch's rows over ``num_shards`` under
+        ``shard_map``). From platform, dtype and shape, microseconds a call;
+        the vmapped solves never ask."""
+        from photon_ml_tpu.ops.features import DenseFeatures
+        from photon_ml_tpu.ops.fused_glm import select_fused_block_rows
+
+        if self.fused_block_rows is not None or not isinstance(batch.features, DenseFeatures):
+            return self
+        block = select_fused_block_rows(
+            batch.num_rows // num_shards, batch.dim, batch.features.matrix.dtype
+        )
+        return self if block is None else dataclasses.replace(self, fused_block_rows=block)

@@ -51,29 +51,6 @@ class DistributedFixedEffectSolver:
         if self.problem.axis_name != self.ctx.axis:
             self.problem = dataclasses.replace(self.problem, axis_name=self.ctx.axis)
         self._jitted = None
-        self._fused_tuned = False
-
-    def _maybe_autotune_fused(self, batch: GLMBatch) -> None:
-        """Adopt the one-pass kernel where the per-device shard's shape calls
-        for it (``select_fused_block_rows``: from the shape, nothing timed;
-        a no-op off a TPU and for sparse layouts)."""
-        if self._fused_tuned:
-            return
-        self._fused_tuned = True
-        from photon_ml_tpu.ops.features import DenseFeatures
-        from photon_ml_tpu.ops.fused_glm import select_fused_block_rows
-
-        if self.problem.fused_block_rows is not None or not isinstance(
-            batch.features, DenseFeatures
-        ):
-            return
-        block = select_fused_block_rows(
-            batch.num_rows // self.ctx.num_devices,
-            batch.dim,
-            batch.features.matrix.dtype,
-        )
-        if block is not None:
-            self.problem = dataclasses.replace(self.problem, fused_block_rows=block)
 
     def _build(self, norm: NormalizationContext):
         problem = self.problem
@@ -103,7 +80,7 @@ class DistributedFixedEffectSolver:
         """
         n_dev = self.ctx.num_devices
         batch = pad_rows(batch, n_dev)
-        self._maybe_autotune_fused(batch)
+        self.problem = self.problem.with_fused_block_for(batch, n_dev)
         batch = self.ctx.put_sharded(batch)
         if init_coefficients is None:
             init_coefficients = jnp.zeros((batch.dim,), real_dtype())

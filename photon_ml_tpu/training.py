@@ -65,21 +65,7 @@ def train_glm_grid(
     """
     sorted_weights = sorted(reg_weights, reverse=True)
 
-    from photon_ml_tpu.ops.features import DenseFeatures
-    from photon_ml_tpu.ops.fused_glm import select_fused_block_rows
-
-    if problem.fused_block_rows is None and isinstance(batch.features, DenseFeatures):
-        # the one-pass kernel where the shape calls for it: a pure function
-        # of platform, dtype and shape (None off a TPU and for a matrix
-        # too small for the kernel to win), microseconds a job; the vmapped
-        # grid below never asks
-        block = select_fused_block_rows(
-            batch.num_rows,
-            batch.dim,
-            batch.features.matrix.dtype,
-        )
-        if block is not None:
-            problem = dataclasses.replace(problem, fused_block_rows=block)
+    problem = problem.with_fused_block_for(batch)
 
     try:
         # module-level jit: repeat calls with the same problem + shapes (e.g.

@@ -192,3 +192,44 @@ def test_plan_auto_pause_tariff_matches_cost_model():
         f"banked capture's cost unit ({unit!r}) — re-bank "
         "docs/PLAN_AUTO_r18.json"
     )
+
+
+# ---------------------------------------------------------------------------
+# ops/fused_glm.py's names as bench.py and tools/ use them: tier-1 imports
+# neither, so a name deleted from the module would rot there unseen
+# ---------------------------------------------------------------------------
+
+import glob
+
+ROOT = os.path.join(os.path.dirname(__file__), os.pardir)
+FUSED_GLM = os.path.join(ROOT, "photon_ml_tpu", "ops", "fused_glm.py")
+
+
+def _fused_glm_names_used(tree):
+    """Names a file takes from ``ops/fused_glm``: attributes of anything
+    called ``fused_glm`` and the names of a ``from ... fused_glm import``."""
+    used = set()
+    for node in ast.walk(tree):
+        if (isinstance(node, ast.Attribute) and isinstance(node.value, ast.Name)
+                and node.value.id == "fused_glm"):
+            used.add(node.attr)
+        elif isinstance(node, ast.ImportFrom) and (node.module or "").endswith("ops.fused_glm"):
+            used.update(alias.name for alias in node.names)
+    return used
+
+
+def test_fused_glm_names_in_bench_and_tools_exist():
+    with open(FUSED_GLM) as f:
+        module = ast.parse(f.read())
+    defined = {n.name for n in module.body if isinstance(n, (ast.FunctionDef, ast.ClassDef))}
+    defined |= {t.id for n in module.body if isinstance(n, ast.Assign)
+                for t in n.targets if isinstance(t, ast.Name)}
+    users = [BENCH] + sorted(glob.glob(os.path.join(ROOT, "tools", "**", "*.py"), recursive=True))
+    used = {}
+    for path in users:
+        with open(path) as f:
+            for name in _fused_glm_names_used(ast.parse(f.read())):
+                used.setdefault(name, os.path.relpath(path, ROOT))
+    assert "select_fused_block_rows" in used, "bench.py's dense section lost the selection"
+    gone = {name: path for name, path in used.items() if name not in defined}
+    assert not gone, f"names ops/fused_glm.py no longer defines: {gone}"

@@ -277,7 +277,7 @@ def test_the_cells_solve_reads_the_matrix_once_on_the_v5e(one_chip, monkeypatch,
     monkeypatch.setattr(fused_glm, "_interpret_default", lambda: False)
     monkeypatch.delenv("PHOTON_ML_TPU_FUSED", raising=False)
     block = fused_glm.select_fused_block_rows(rows, width, jnp.float32)
-    assert block is not None and fused_glm._decode_block(block)[0] == "vpu"
+    assert block == 640
     solver = _config()["sizes"]["solver"]
     problem = GLMOptimizationProblem(
         task=TaskType.LOGISTIC_REGRESSION, optimizer=OptimizerType.LBFGS,
@@ -356,8 +356,8 @@ def test_the_distributed_solve_runs_the_kernel_on_four_v5es(four_chips, monkeypa
     n = rows * len(four_chips)
     batch = GLMBatch(DenseFeatures(shape(split, n, width)),
                      shape(split, n), shape(split, n), shape(split, n))
-    solver._maybe_autotune_fused(batch)
-    assert fused_glm._decode_block(solver.problem.fused_block_rows) == ("vpu", 640)
+    solver.problem = solver.problem.with_fused_block_for(batch, len(four_chips))
+    assert solver.problem.fused_block_rows == 640
     compiled = solver._build(NormalizationContext.identity()).lower(
         batch, shape(whole, width), shape(whole)).compile()
     _reads_the_matrix_once(compiled, rows, width)

@@ -697,28 +697,3 @@ class TestStreamingEnvActivation:
         # scoring is margin-only (dense path) — identical coefficients in,
         # identical scores out
         assert np.array_equal(s_env, s_seg)
-
-
-class TestDenseAutotuneFailureLogging:
-    def test_skipped_and_failed_candidates_read_as_failed(self, monkeypatch):
-        """The dense race record must carry every candidate: one that never
-        ran (probe too small) appears with a 'failed: skipped:' reason
-        instead of silently vanishing from the report."""
-        from photon_ml_tpu.ops import fused_glm
-
-        monkeypatch.setenv("PHOTON_ML_TPU_FUSED", "1")
-        fused_glm._autotune_cache.clear()
-        fused_glm._autotune_timings.clear()
-        fused_glm._autotune_failures.clear()
-        n, d = 512, 128
-        block = fused_glm.race_fused_block_rows(
-            losses.logistic, n, d, dtype=jnp.float32,
-            candidates=(256, 1 << 19),  # the second exceeds the probe rows
-        )
-        assert block == 256
-        report = fused_glm.autotune_report(
-            losses.logistic, n, d, dtype=jnp.float32
-        )
-        assert report["winner"] == 256
-        skipped = report["candidates"]["grid:524288"]
-        assert "failed" in skipped and "skipped" in skipped["failed"]

@@ -96,14 +96,12 @@ def _lowered_solve(batch, optimizer=OptimizerType.LBFGS, variance=False,
 
 @pytest.mark.parametrize("path", sorted(GLM_PATHS))
 def test_glm_solve_lowers_with_its_scopes(rng, path):
-    from photon_ml_tpu.ops.fused_glm import VPU_MARK
-
     sparse, optimizer, variance, want = GLM_PATHS[path]
     one_pass = "pml.features.value_grad" in want
     # 256 rows of 16 features are held column-major: two 128-row blocks
     batch = _glm_batch(rng, sparse, n=256 if one_pass else 32)
     text = _lowered_solve(
-        batch, optimizer, variance, VPU_MARK + 128 if one_pass else None
+        batch, optimizer, variance, 128 if one_pass else None
     ).as_text(debug_info=True)
     assert scopes_in(text) == want
     assert "module @jit__solve" in text  # the name fe_solve_roofline reads

@@ -38,11 +38,9 @@ ALLOWLIST = {
     "photon_ml_tpu/optim/convergence.py:resolve_adaptive": "plan-visible via resolve()",
     "photon_ml_tpu/ops/fused_sparse.py:resolve_sparse_kernel": "plan-visible via resolve()",
     "photon_ml_tpu/io/pipeline.py:resolve_depth": "plan-visible via resolve()",
-    # kernel-local autotune mode (oracle/manual/auto race selection): a
-    # debug switch for the fused-GLM race, not a training-policy knob
+    # the one-pass dense kernel's off/auto/force switch: a debug switch of
+    # the kernel's selection, not a training-policy knob
     "photon_ml_tpu/ops/fused_glm.py:select_fused_block_rows": "kernel selection debug switch",
-    "photon_ml_tpu/ops/fused_glm.py:race_fused_block_rows": "kernel autotune debug switch",
-    "photon_ml_tpu/ops/fused_glm.py:autotune_report": "kernel autotune debug switch",
     # infrastructure knobs with no bearing on the training plan
     "photon_ml_tpu/parallel/multihost.py:resolve_barrier_timeout": "infra timeout, not a plan knob",
     "photon_ml_tpu/io/native_build.py:native_enabled": "build-time toggle",

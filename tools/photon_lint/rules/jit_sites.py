@@ -54,13 +54,8 @@ ALLOWLIST = {
     # array, explicitly reused across the lambda grid
     "photon_ml_tpu/training.py:train_glm_grid": "warm-start w0 reused across grid",
     "photon_ml_tpu/training.py:train_glm_grid_vmapped": "lane-stacked w0 reused across lanes",
-    # fused-GLM kernels: oracle/compare paths whose inputs race both
-    # autotune variants -- donation would delete the buffers the losing
-    # variant still reads
-    "photon_ml_tpu/ops/fused_glm.py:_fused_fn.call": "autotune race shares inputs",
-    "photon_ml_tpu/ops/fused_glm.py:_fused_fn_manual.call": "autotune race shares inputs",
+    # the one-pass dense kernel
     "photon_ml_tpu/ops/fused_glm.py:_fused_fn_vpu.call": "the solve's dataset, read every evaluation",
-    "photon_ml_tpu/ops/fused_glm.py:_time_value_and_grad": "bench-only race harness",
     # parallel/: shard_map wrappers over mesh-sharded slabs reused across
     # updates (the slabs ARE the dataset; donating them would tear it)
     "photon_ml_tpu/parallel/perhost_ingest.py:PerHostRandomEffectSolver.update": "dataset slabs reused per update",
