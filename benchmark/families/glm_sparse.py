@@ -13,7 +13,10 @@ from benchmark.families import common
 
 #: sizes of the CPU rehearsal (benchmark/check.py and the tests only)
 TINY = {"features": 4096, "train_rows": 2048, "held_out_rows": 128,
-        "reference_blocks": 2}
+        "reference_blocks": 16}
+
+#: the features whose sums :meth:`Cell.gradient_readings` also reads alone
+MOST_FREQUENT = 128
 
 
 def _key(seed: int):
@@ -87,6 +90,7 @@ class Cell:
         if tiny:
             self.sizes.update(TINY)
         self.limits = config["limits"]
+        self._assumed = config["assumed"]
         solver = self.sizes["solver"]
         (self.indices, self.values, self.labels), self.held_out = synthesize(
             self.sizes, config["assumed"], seed)
@@ -125,6 +129,78 @@ class Cell:
 
     def free(self):
         self._batch = None
+
+    def permute_rows(self, seed: int):
+        """A planted *sound* case (benchmark/control.py ``--permuted-rows``):
+        hand the program the same rows in another order, a permutation made
+        on the host from the seed; the reference keeps the rows as they were.
+        Same data, same mathematics, another order of addition."""
+        import jax.numpy as jnp
+
+        self._batch = None
+        order = jnp.asarray(np.random.default_rng(seed).permutation(
+            self.labels.shape[0]).astype(np.int32))
+        self._batch = program_inputs(
+            self.indices[order], self.values[order], self.labels[order],
+            int(self.sizes["features"]))
+
+    def gradient_readings(self, seed: int) -> list:
+        """How far the reference's gradient is from the exact sum of its own
+        float32 products, at zero and at one random vector: relative L2
+        against a float64 sum made on the host from the same rows
+        (``numpy.bincount``), over all features and over the
+        ``MOST_FREQUENT`` most frequent ones; beside it the accumulation the
+        reference had until PR 35 (one float32 vector carried through all the
+        rows, in 8 blocks)."""
+        import jax
+        import jax.numpy as jnp
+
+        from benchmark.references import glm_sparse
+
+        dim = int(self.sizes["features"])
+        ones = jnp.ones(self.labels.shape, jnp.float32)
+        cut = functools.partial(glm_sparse.in_blocks, self.indices,
+                                self.values, self.labels, ones)
+        rows = cut(int(self.sizes["reference_blocks"]))
+        few = cut(min(8, int(self.sizes["reference_blocks"])))
+        terms = jax.jit(glm_sparse.row_terms)
+        new = jax.jit(lambda w, rows: glm_sparse.value_and_grad(
+            w, rows, 0.0, dim)[1])
+
+        @jax.jit
+        def carried(w, rows):
+            def block(grad, part):
+                i = part[0]
+                return grad.at[i.reshape(-1)].add(
+                    glm_sparse.row_terms(w, *part)[1].reshape(-1)), None
+
+            return jax.lax.scan(block, jnp.zeros((dim,), jnp.float32), rows)[0]
+
+        counts = np.zeros(dim, np.int64)
+        for i in few[0]:
+            counts += np.bincount(np.asarray(i).reshape(-1), minlength=dim)
+        hot = np.argsort(-counts, kind="stable")[:MOST_FREQUENT]
+        scale = float(self._assumed["planted_scale"])
+        lines = []
+        for name, w in (("zero", jnp.zeros((dim,), jnp.float32)),
+                        ("random", scale * jax.random.normal(
+                            jax.random.fold_in(_key(seed), 1), (dim,)))):
+            exact = np.zeros(dim, np.float64)
+            for part in zip(*few):
+                exact += np.bincount(
+                    np.asarray(part[0]).reshape(-1),
+                    np.asarray(terms(w, *part)[1], np.float64).reshape(-1),
+                    minlength=dim)
+            line = {"at": name, "norm": float(np.linalg.norm(exact)),
+                    "most_frequent_share_of_norm2": float(
+                        np.sum(exact[hot] ** 2) / np.sum(exact ** 2))}
+            for how, grad in (("kept", new(w, rows)), ("carried", carried(w, few))):
+                grad = np.asarray(grad, np.float64)
+                line[how] = common.relative_difference(grad, exact)
+                line[how + "_most_frequent"] = common.relative_difference(
+                    grad[hot], exact[hot])
+            lines.append(line)
+        return lines
 
     def reference(self, storage: str = "float32", half_batch: bool = False) -> dict:
         import jax.numpy as jnp

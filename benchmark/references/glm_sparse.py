@@ -8,13 +8,28 @@ Float32 ``jax.numpy``, nothing of the program under test imported. A row is
 the temporaries stay small.
 
 ``storage`` is the type the values and the coefficients are held in for the
-two products; ``bfloat16`` is the control. Sums stay float32, and the sum of
-the rows' losses keeps its rounding errors (:func:`sum_with_error`): the value
-is the float32 nearest the exact sum of the float32 losses, so that a gap
-between the program's value and this one is the program's to explain. (Summed
-plainly, one float32 total carried through the blocks, the first value
-2^22 ln 2 came out 1.5e-6 low on the chip, nine times the program's own
-error: PERF.md section 6, PR 28.)
+two products; ``bfloat16`` is the control. Sums stay float32 **and keep their
+rounding errors**, the value's and the gradient's alike, so that a gap between
+the program's numbers and these is the program's to explain:
+
+* the rows' losses are summed pairwise with Knuth's two-sum at every level
+  (:func:`sum_with_error`): the value is the float32 nearest the exact sum of
+  the float32 losses. (Summed plainly, one float32 total carried through the
+  blocks, the first value 2^22 ln 2 came out 1.5e-6 low on the chip, nine
+  times the program's own error: PERF.md section 6, PR 28.)
+* each block of rows is scatter-added onto **zeros**, and the blocks' vectors
+  are added with :func:`two_sum`, what each addition lost carried beside the
+  sum and folded in once at the end. With blocks of 4,096 rows the most
+  frequent feature takes some two thousand addends a block, and the blocks'
+  small errors average out over the 1,024 blocks: on the chip the gradient
+  is within 4e-8 of its norm of the float64 sum of its float32 products,
+  over all features and over the 128 most frequent (``benchmark/control.py
+  --gradient``; 8 blocks two-summed read 1e-6). (Added into one float32
+  vector carried through all the rows, as this file did until PR 35 and as
+  a row-order program does, two million addends land on one feature one
+  after the other and the sum ends 0.7e-5 to 2.3e-5 from exact: a program
+  that adds in another order then reads as a fault. PERF.md section 6, PRs
+  31, 34 and 35.)
 """
 
 from __future__ import annotations
@@ -53,37 +68,56 @@ def sum_with_error(x):
     return x[0], error
 
 
+def in_blocks(indices, values, labels, weights, blocks, storage=jnp.float32):
+    """The rows cut into ``blocks`` equal blocks, values held in ``storage``."""
+    n, k = indices.shape
+    return (indices.reshape(blocks, n // blocks, k),
+            values.astype(storage).reshape(blocks, n // blocks, k),
+            labels.reshape(blocks, -1), weights.reshape(blocks, -1))
+
+
+def row_terms(w_s, i, v, y, rw):
+    """One block's weighted losses ``(rows,)`` and the float32 products
+    ``value * slope`` ``(rows, K)`` its gradient is the sum of."""
+    z = jnp.sum((w_s[i] * v).astype(jnp.float32), axis=-1)
+    slope = (rw * logistic_slope(z, y)).astype(v.dtype)
+    return rw * logistic_loss(z, y), (v * slope[:, None]).astype(jnp.float32)
+
+
+def block_sum(i, products, dim):
+    """One block's products scatter-added onto zeros: a ``(dim,)`` vector."""
+    return jnp.zeros((dim,), jnp.float32).at[i.reshape(-1)].add(
+        products.reshape(-1))
+
+
+def value_and_grad(w, rows, l2, dim):
+    """Value and gradient at ``w`` over ``rows`` (:func:`in_blocks`), both the
+    float32 nearest their exact sums (the module's docstring)."""
+    w_s = w.astype(rows[1].dtype)
+
+    def block(carry, part):
+        value, error, grad, grad_error = carry
+        i, v, y, rw = part
+        losses, products = row_terms(w_s, i, v, y, rw)
+        part_sum, part_error = sum_with_error(losses)
+        value, lost = two_sum(value, part_sum)
+        grad, grad_lost = two_sum(grad, block_sum(i, products, dim))
+        return (value, error + (lost + part_error),
+                grad, grad_error + grad_lost), None
+
+    zero, zeros = jnp.zeros((), jnp.float32), jnp.zeros((dim,), jnp.float32)
+    (value, error, grad, grad_error), _ = lax.scan(
+        block, (zero, zero, zeros, zeros), rows)
+    return (value + (error + 0.5 * l2 * jnp.dot(w, w)),
+            (grad + grad_error) + l2 * w)
+
+
 @functools.partial(
     jax.jit, static_argnames=("dim", "max_iter", "tol", "blocks", "storage"))
 def fit(indices, values, labels, weights, l2, dim, max_iter, tol, blocks,
         storage=jnp.float32):
     """L-BFGS from zero. ``weights`` is one per row (all ones in a sound run;
     the planted fault "half of the batch left out" zeroes every second)."""
-    n, k = indices.shape
-    idx = indices.reshape(blocks, n // blocks, k)
-    val = values.astype(storage).reshape(blocks, n // blocks, k)
-    y = labels.reshape(blocks, -1)
-    rw = weights.reshape(blocks, -1)
-
-    def vg(w):
-        w_s = w.astype(storage)
-
-        def block(carry, part):
-            value, error, grad = carry
-            i, v, y_b, rw_b = part
-            z = jnp.sum((w_s[i] * v).astype(jnp.float32), axis=-1)
-            part_sum, part_error = sum_with_error(rw_b * logistic_loss(z, y_b))
-            value, lost = two_sum(value, part_sum)
-            error = error + (lost + part_error)
-            slope = (rw_b * logistic_slope(z, y_b)).astype(storage)
-            grad = grad.at[i.reshape(-1)].add(
-                (v * slope[:, None]).astype(jnp.float32).reshape(-1))
-            return (value, error, grad), None
-
-        zero = jnp.zeros((), jnp.float32)
-        (value, error, grad), _ = lax.scan(
-            block, (zero, zero, jnp.zeros((dim,), jnp.float32)),
-            (idx, val, y, rw))
-        return value + (error + 0.5 * l2 * jnp.dot(w, w)), grad + l2 * w
-
-    return lbfgs(vg, jnp.zeros((dim,), jnp.float32), max_iter, tol)
+    rows = in_blocks(indices, values, labels, weights, blocks, storage)
+    return lbfgs(lambda w: value_and_grad(w, rows, l2, dim),
+                 jnp.zeros((dim,), jnp.float32), max_iter, tol)

@@ -10,6 +10,11 @@ Run on the CPU at the families' ``TINY`` sizes:
   the timed path broken underneath comes out with ``correct`` false: the
   solver returning its state unchanged, and half of the rows left out with
   the rest counted double.
+* a sound program that adds in another order passes: the same rows handed
+  to the program in a permuted order, against the reference on the rows as
+  they were (``glm_sparse``: its reference's gradient keeps its rounding
+  errors, and is held here to 3e-7 of a float64 sum where one float32 vector
+  carried through the rows reads over 3e-6).
 The same comparisons were read on the chip at the cells' own sizes
 (PERF.md, "How correct is decided"). The ``glm_sparse`` family's first
 gradient is compared by its norm: the timed solve's own float32 norm against
@@ -37,6 +42,11 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 CELLS = [w["name"] for w in MANIFEST["workloads"]]
+SPARSE = "glm-sparse-2m.lbfgs"
+#: rows enough that the first feature takes 524,288 addends (popularity
+#: power 3 over 4,096 features: one stored value in 16), in blocks of 256
+GRADIENT_SIZES = {"features": 4096, "train_rows": 131072, "held_out_rows": 128,
+                  "reference_blocks": 512}
 
 
 def _build(cell_name, seed):
@@ -89,6 +99,51 @@ def _half_of_the_rows_left_out(monkeypatch, family):
     monkeypatch.setattr(module, "program_inputs", halved)
 
 
+def _rows_in_another_order(monkeypatch, family):
+    module = importlib.import_module(f"benchmark.families.{family}")
+    sound = module.build
+
+    def permuted(config, job, seed, tiny=False):
+        cell = sound(config, job, seed, tiny)
+        cell.permute_rows(seed)
+        return cell
+
+    monkeypatch.setattr(module, "build", permuted)
+
+
+@pytest.fixture(scope="module")
+def gradient_readings():
+    from benchmark import harness
+
+    _, cell_file, _, config = harness.find_cell(MANIFEST, SPARSE)
+    family = importlib.import_module(f"benchmark.families.{config['family']}")
+    sized = dict(config, sizes=dict(config["sizes"], **GRADIENT_SIZES))
+    cell = family.build(sized, cell_file["job"], 22)
+    cell.free()
+    return {line["at"]: line for line in cell.gradient_readings(22)}
+
+
+@pytest.mark.parametrize("at", ["zero", "random"])
+def test_glm_sparse_reference_gradient_is_the_exact_sum(gradient_readings, at):
+    """Rows drawn with the cell's popularity power: against the float64 sum
+    of the same float32 products the reference's gradient is within 3e-7 of
+    the norm, over all features and over the 128 most frequent; one float32
+    vector carried through the rows (the reference until PR 35, and any
+    row-order program) is ten times as far."""
+    line = gradient_readings[at]
+    assert line["kept"] < 3e-7 and line["kept_most_frequent"] < 3e-7, line
+    assert line["carried"] > 3e-6 and line["carried_most_frequent"] > 3e-6, line
+
+
+@pytest.mark.parametrize("seed", [12, 13])
+def test_glm_sparse_permuted_rows_run_is_correct(seed, monkeypatch):
+    """Same data, same mathematics, another order of addition: a whole run
+    of the harness on permuted rows comes out ``correct``."""
+    _rows_in_another_order(monkeypatch, "glm_sparse")
+    last = _run(SPARSE, seed=seed)
+    assert last["correct"], last["compared"]
+
+
 @pytest.mark.parametrize("cell_name", CELLS)
 def test_sound_run_is_correct(cell_name):
     last = _run(cell_name, seed=3)
@@ -109,11 +164,22 @@ def test_control_in_bfloat16_fails(cell_name, seed):
 def test_glm_sparse_control_fails_three_numbers(seed):
     """As on the chip at the cell's own size (PERF.md section 2): the
     bfloat16 control is over the limit on the values and on both coefficient
-    numbers."""
-    cell, _ = _build("glm-sparse-2m.lbfgs", seed)
+    numbers, whose limits PR 35 set against a reference with exact sums."""
+    cell, _ = _build(SPARSE, seed)
     numbers = cell.compare(cell.reference("bfloat16"), cell.reference())
     failed = [n for n, limit in cell.limits.items() if numbers[n] > limit]
     assert {"values_gap", "change_norm_gap", "coefficients_gap"} <= set(failed), numbers
+
+
+@pytest.mark.parametrize("seed", [5, 6, 7])
+def test_glm_sparse_half_batch_fails_four_numbers(seed):
+    """The planted fault fails every number but the count of iterations, as
+    on the chip (PERF.md section 2; three is the least the limits are held
+    to)."""
+    cell, _ = _build(SPARSE, seed)
+    numbers = cell.compare(cell.reference(half_batch=True), cell.reference())
+    failed = [n for n, limit in cell.limits.items() if numbers[n] > limit]
+    assert set(failed) == set(cell.limits) - {"iterations_gap"}, numbers
 
 
 def test_first_gradient_norm_reads_the_programs_rounding_alone():
