@@ -128,6 +128,18 @@ class SparseFeatures:
     row in the batch. Padding slots carry ``values == 0`` and any valid index
     (conventionally 0) so gathers stay in-bounds and scatter-adds of zero are
     no-ops.
+
+    ``tiles``: the second layout of a large wide batch
+    (``ops/tiled_sparse.py``): the same stored values in (row block x
+    feature tile) buckets, on which the gather and the scatter-add are
+    float32-exact one-hot products on the matrix unit. :func:`auto_transpose`
+    builds it once, on the device, when the batch is placed; the fixed
+    effect's value-and-gradient pass reads it through :meth:`tiled`, and
+    nothing else does: ``matvec`` / ``rmatvec`` / ``sq_rmatvec`` as called
+    here (scoring, TRON's Hessian-vector product, the variances) read
+    ``indices`` / ``values``, which stay. It is in feature order and cannot
+    be cut by rows: whatever builds a ``SparseFeatures`` from some of these
+    rows, pads or shards them, or re-stores the values, leaves it behind.
     """
 
     indices: Array  # (N, K) int32
@@ -143,10 +155,26 @@ class SparseFeatures:
     t_idx: Optional[Array] = None  # (nnz,) int32, sorted feature index
     t_row: Optional[Array] = None  # (nnz,) int32, source row of each entry
     t_val: Optional[Array] = None  # (nnz,) entry values in t_idx order
+    tiles: Optional["TileLayout"] = None  # ops/tiled_sparse.py
 
     @property
     def num_rows(self) -> int:
         return self.indices.shape[0]
+
+    def tiled(self) -> Optional["TiledFeatures"]:
+        """The two products over the tile layout, for the value-and-gradient
+        pass; None where the batch carries none. Refuses a layout that does
+        not fit these rows (one of the two was cut without the other)."""
+        from photon_ml_tpu.ops.tiled_sparse import TiledFeatures
+
+        if self.tiles is None or self.t_idx is not None:
+            return None
+        return TiledFeatures(self.tiles.check(self.num_rows))
+
+    def without_tiles(self) -> "SparseFeatures":
+        """These rows with the row-order layouts alone: what goes on to be
+        padded or sharded by rows."""
+        return dataclasses.replace(self, tiles=None)
 
     def with_transpose(self) -> "SparseFeatures":
         """Precompute the sorted transpose layout (host-side, once at
@@ -232,7 +260,8 @@ class SparseFeatures:
 
     # -- pytree protocol ----------------------------------------------------
     def tree_flatten(self):
-        return (self.indices, self.values, self.t_idx, self.t_row, self.t_val), self.dim
+        return (self.indices, self.values, self.t_idx, self.t_row, self.t_val,
+                self.tiles), self.dim
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -266,15 +295,60 @@ def from_scipy_like(rows, dim: int, dtype=jnp.float32) -> SparseFeatures:
 # comparison without a code change. The row-blocked value-and-gradient pass
 # (``ops/objective.py``) needs the row-order layout: a batch with the CSC
 # view keeps the whole-batch pass.
+#
+# The tile layout (``ops/tiled_sparse.py``) is the production rule for the
+# second layout since PR 36: 15.011 -> 2 to 3 s a job of the benchmark's
+# sparse cell (PERF.md section 6). It is chosen from what can be seen when the
+# batch is placed, with no flag and no race.
 SPARSE_TRANSPOSE_MIN_DIM = 1 << 16
 
 
+def wants_tiles(platform: str, index_dtype, value_dtype, n: int, k: int,
+                dim: int) -> bool:
+    """The rule for the tile layout, a pure function of what placement sees:
+    a TPU (the kernels are the matrix unit's), int32 indices and float32
+    values (the pieces are float32's), more stored slots than one row block
+    of the blocked pass (a smaller batch's element loops are milliseconds,
+    and the per-entity batches never come near), a feature space at or over
+    ``SPARSE_TRANSPOSE_MIN_DIM`` (under it the row-order gather stays in fast
+    memory) and at or under ``tiled_sparse.MAX_DIM`` (over it the kernels'
+    resident tables do not fit)."""
+    from photon_ml_tpu.ops.objective import ROW_BLOCK_NNZ
+    from photon_ml_tpu.ops.tiled_sparse import MAX_DIM
+
+    return (
+        platform == "tpu"
+        and jnp.dtype(index_dtype) == jnp.int32
+        and jnp.dtype(value_dtype) == jnp.float32
+        and n * k > ROW_BLOCK_NNZ
+        and SPARSE_TRANSPOSE_MIN_DIM <= dim <= MAX_DIM
+    )
+
+
 def auto_transpose(feats: SparseFeatures) -> SparseFeatures:
-    """Apply the production transpose-layout rule (see comment above)."""
+    """Give freshly placed features their second layout, by the production
+    rule. ``io/libsvm.py`` ``to_batch`` (the GLM driver) and the benchmark's
+    sparse family call this once on a new ``SparseFeatures``, outside every
+    job. Where :func:`wants_tiles` says so (and the arrays lie whole on one
+    device: the layout cannot be sharded by rows) the features come back
+    with the tile layout built on that device beside ``indices`` /
+    ``values``, for ``GLMObjective.value_and_grad``; else, under
+    ``PHOTON_ML_TPU_SPARSE_TRANSPOSE=1``, with the sorted transpose; else as
+    they came."""
     from photon_ml_tpu.compile.overrides import sparse_transpose_forced
 
-    if feats.t_idx is not None or feats.dim < SPARSE_TRANSPOSE_MIN_DIM:
+    if feats.t_idx is not None or feats.tiles is not None \
+            or feats.dim < SPARSE_TRANSPOSE_MIN_DIM:
         return feats
     if sparse_transpose_forced():
         return feats.with_transpose()
+    placed = isinstance(feats.indices, jax.Array) and not isinstance(
+        feats.indices, jax.core.Tracer)
+    if placed and len(feats.indices.devices()) == 1 and wants_tiles(
+            next(iter(feats.indices.devices())).platform, feats.indices.dtype,
+            feats.values.dtype, *feats.indices.shape, feats.dim):
+        from photon_ml_tpu.ops import tiled_sparse
+
+        return dataclasses.replace(feats, tiles=tiled_sparse.build(
+            feats.indices, feats.values, feats.dim))
     return feats

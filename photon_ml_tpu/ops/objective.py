@@ -7,13 +7,11 @@ ValueAndGradientAggregator + HessianVectorAggregator, re-designed batched):
   z_i       = (x_i - shift) . (w * factor) + offset_i
             = x_i . w_eff + margin_shift + offset_i           (folded form)
 
-where ``w_eff = w * factor`` and ``margin_shift = -w_eff . shift``; raw data
-is never normalized in memory. On Spark this was a per-datum loop inside
+where ``w_eff = w * factor`` and ``margin_shift = -w_eff . shift``; raw data is
+never normalized in memory. On Spark this was a per-datum loop inside
 treeAggregate (ValueAndGradientAggregator.scala:120-139 / :205-220); here each
 quantity is one batched matmul/gather pass that XLA fuses end-to-end, and the
-cross-device reduction is a single ``psum`` when running under ``shard_map``
-(the treeAggregate-depth knob is obsolete).
-
+cross-device reduction is a single ``psum`` when running under ``shard_map``.
 Padding rows are expressed with ``weight == 0`` — they contribute exactly
 zero to every sum, so bucketed/padded batches need no separate mask.
 """
@@ -30,6 +28,7 @@ from jax import lax
 from photon_ml_tpu.ops.features import Features, SparseFeatures, _acc_dtype
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.normalization import NormalizationContext
+from photon_ml_tpu.ops.tiled_sparse import TiledFeatures, pairwise_sum
 
 Array = jax.Array
 
@@ -37,10 +36,8 @@ Array = jax.Array
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class GLMBatch:
-    """Struct-of-arrays batch: the TPU analogue of RDD[LabeledPoint].
-
-    (data/LabeledPoint.scala:28-62 spec: label, features, offset, weight.)
-    """
+    """Struct-of-arrays batch: the TPU analogue of RDD[LabeledPoint]
+    (data/LabeledPoint.scala:28-62 spec: label, features, offset, weight)."""
 
     features: Features
     labels: Array  # (N,)
@@ -72,31 +69,35 @@ class GLMBatch:
         return cls(*children)
 
 
-#: Stored non-zeros (rows x padded K) one row block of the blocked
-#: value-and-gradient pass aims at. A padded-sparse batch that holds more is
-#: walked in blocks of ``ROW_BLOCK_NNZ // K`` rows; one at or under it is one
-#: block, which is the whole-batch pass. Set from a sweep on the v5e at
-#: (2^22, 64) over 2^21 features (PERF.md section 6, PR 26).
+#: Stored non-zeros (rows x padded K) a row block of the blocked value-and-
+#: gradient pass aims at; a padded-sparse batch that holds more is walked in
+#: blocks of ``ROW_BLOCK_NNZ // K`` rows (a v5e sweep: PERF.md section 6, PR 26).
 ROW_BLOCK_NNZ = 1 << 22
 
 
 def _block_rows(features) -> Optional[int]:
-    """Rows per block of the blocked value-and-gradient pass, from the
-    batch's static shape alone; None where the whole batch is one block.
-
-    Only ``SparseFeatures`` in row order blocks: the sorted-transpose arrays
-    (``t_idx``) are in feature order and cannot be cut by rows. Under
-    ``shard_map`` the shape seen here is the device's local shard, so every
-    device blocks its own rows; no caller hands ``value_and_grad`` a
-    ``SparseFeatures`` whose rows plain ``jit`` has sharded.
-    """
+    """Rows per block of the blocked value-and-gradient pass, from the batch's
+    static shape alone; None where the whole batch is one block. Only
+    ``SparseFeatures`` in row order blocks: the sorted transpose (``t_idx``)
+    and the tile layout (``tiles``: built by ``auto_transpose`` at placement,
+    read by this pass alone; its kernels walk their own blocks) are in feature
+    order and cannot be cut by rows. Under ``shard_map`` the shape is the local
+    shard's; no caller hands over rows that plain ``jit`` has sharded."""
     if not isinstance(features, SparseFeatures) or features.t_idx is not None:
         return None
     n, k = features.indices.shape
-    if n * k <= ROW_BLOCK_NNZ:
+    if n * k <= ROW_BLOCK_NNZ or features.tiles is not None:
         return None
     rows = max(ROW_BLOCK_NNZ // k, 1)
     return rows - rows % 8 if rows > 8 else rows
+
+
+def _pass_view(batch):
+    """(the batch as the value-and-gradient pass reads it, its rows a block):
+    features that carry the tile layout are read through it, in one pass."""
+    tiled = batch.features.tiled() if isinstance(batch.features, SparseFeatures) else None
+    return (batch, _block_rows(batch.features)) if tiled is None else (
+        dataclasses.replace(batch, features=tiled), None)
 
 
 def _maybe_psum(x, axis_name: Optional[str]):
@@ -110,17 +111,16 @@ def _wmul(weights: Array, x: Array) -> Array:
 
 
 def _row_sum(features, x: Array) -> Array:
-    """Scalar row reduction, slab-aware.
-
-    Sparse-slab batches reduce through the fixed-association pairwise tree
-    (``fused_sparse.tree_row_sum``) so every sparse family — the generic
-    scatter/segment path here AND the fused Pallas wrappers — produces the
-    bitwise-identical scalar in every fusion context (a plain ``reduce``'s
-    association order changes with producer fusion; a one-ulp loss value
-    flips line searches). Dense batches keep the plain ``jnp.sum``.
-    """
+    """Scalar row reduction, slab-aware. Sparse-slab batches reduce through
+    the fixed-association pairwise tree (``fused_sparse.tree_row_sum``) so
+    every sparse family produces the bitwise-identical scalar whatever fusion
+    the ``reduce`` would land in. The tile layout's one pass sums all of its
+    rows' losses at once, by halves: one float32 ``reduce`` over 2^22 of them
+    is 2e-6 off. Dense batches and a row block's rows keep ``jnp.sum``."""
     from photon_ml_tpu.ops.fused_sparse import SparseSlab, tree_row_sum
 
+    if isinstance(features, TiledFeatures):
+        return pairwise_sum(x)
     if isinstance(features, SparseSlab):
         return tree_row_sum(x)
     return jnp.sum(x)
@@ -174,7 +174,7 @@ class GLMObjective:
     @jax.named_scope("pml.objective.value_and_grad")
     def value_and_grad(self, w, batch, norm, l2_weight=0.0) -> Tuple[Array, Array]:
         w_eff = norm.effective_coefficients(w)
-        rows = _block_rows(batch.features)  # None: the whole batch at once
+        batch, rows = _pass_view(batch)  # rows None: the whole batch at once
         if self._use_fused(batch):
             from photon_ml_tpu.ops import fused_glm
 

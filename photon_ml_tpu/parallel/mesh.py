@@ -95,12 +95,21 @@ def pad_rows(batch: GLMBatch, multiple: int) -> GLMBatch:
     Padding rows carry weight 0 and contribute exactly zero to every
     objective sum (ops/objective.py `_wmul`), so no mask plumbing is needed —
     the reference's uneven Spark partitions become even shards for free.
+
+    Sparse features come back without their tile layout whether rows were
+    added or not: this is the gateway to ``put_sharded``, and the layout is
+    in feature order (the sharded solve blocks its own rows instead).
     """
     n = batch.num_rows
     target = ((n + multiple - 1) // multiple) * multiple
+    feats = batch.features
+    if isinstance(feats, SparseFeatures) and feats.tiles is not None:
+        # what follows pads these rows or shards them over devices, and the
+        # tile layout (ops/tiled_sparse.py) can be neither: it stays behind
+        feats = feats.without_tiles()
+        batch = GLMBatch(feats, batch.labels, batch.offsets, batch.weights)
     if target == n:
         return batch
-    feats = batch.features
     if isinstance(feats, DenseFeatures):
         feats = DenseFeatures(_pad_array_leading(feats.matrix, target))
     elif isinstance(feats, SparseFeatures):

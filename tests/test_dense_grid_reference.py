@@ -15,6 +15,7 @@ cell's shape.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -393,3 +394,67 @@ def test_what_the_selection_hands_out_compiles_on_the_v5e(one_chip, monkeypatch,
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     whole = r"\[(%d,%d|%d,%d)\]" % (rows, width, width, rows)
     assert not re.search(r"= \w+" + whole + r"\S* (copy|transpose|fusion|convert)\(", text)
+
+
+# -- the wide fixed effect's tile layout on the v5e (the sparse cell's job) -----
+
+
+def test_the_sparse_cells_solve_runs_both_kernels_on_the_v5e(one_chip, monkeypatch):
+    """``training._solve`` at the sparse cell's own shape (2^22 rows of 64
+    over 2^21 features, float32) on features that carry the tile layout in
+    the shipped geometry, compiled for the v5e: every evaluation (the one
+    before the solver's loop and the line search's) is one gather kernel and
+    one scatter-add kernel under the scopes the benchmark's metrics read,
+    and no element-by-element gather or scatter of the stored values is
+    left."""
+    import re
+
+    from photon_ml_tpu import training
+    from photon_ml_tpu.ops import tiled_sparse as ts
+    from photon_ml_tpu.ops.features import SparseFeatures
+    from photon_ml_tpu.ops.normalization import NormalizationContext
+    from photon_ml_tpu.ops.objective import GLMBatch
+    from photon_ml_tpu.ops.regularization import RegularizationContext
+    from photon_ml_tpu.optim.common import OptimizerConfig
+    from photon_ml_tpu.optim.problem import GLMOptimizationProblem
+    from photon_ml_tpu.types import OptimizerType, TaskType
+
+    n, k, dim = 1 << 22, 64, 1 << 21
+    from photon_ml_tpu.ops import fused_glm
+
+    # the process's backend is the CPU: steer the one place that asks
+    monkeypatch.setattr(fused_glm, "_interpret_default", lambda: False)
+    g = ts.GEOMETRY
+    chunks = g.blocks(n) * g.slots_per_block(k, dim) // g.chunk
+    shape = lambda dtype, *s: jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+    f32 = functools.partial(shape, jnp.float32)
+    layout = ts.TileLayout(
+        shape(jnp.int32, chunks // g.group, 1, g.group),
+        shape(jnp.int32, chunks, g.chunk), f32(chunks, g.chunk), g, n, k, dim)
+    assert layout.check(n) is layout
+    feats = SparseFeatures(shape(jnp.int32, n, k), f32(n, k), dim, tiles=layout)
+    problem = GLMOptimizationProblem(
+        task=TaskType.LOGISTIC_REGRESSION, optimizer=OptimizerType.LBFGS,
+        optimizer_config=OptimizerConfig(
+            max_iterations=2, tolerance=0.0, num_corrections=10),
+        regularization=RegularizationContext.l2(1.0))
+    training._solve.clear_cache()
+    compiled = training._solve.lower(
+        problem, GLMBatch(feats, f32(n), f32(n), f32(n)),
+        NormalizationContext.identity(), f32(dim), f32()).compile()
+    training._solve.clear_cache()
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    inside = "pml.objective.value_and_grad/pml.features.%s/pml.features.tile_%s"
+    for product in ("matvec", "rmatvec"):
+        mine = [l for l in calls if inside % (product, product) in l]
+        assert len(mine) == 2, (product, len(mine))
+        assert sum("pml.lbfgs.line_search" in l for l in mine) == 1
+    assert len(calls) == 4
+    # the row-order arrays are parameters the solve never reads
+    rows = [l for l in text.splitlines() if "[%d,%d]" % (n, k) in l]
+    assert all(re.match(r"HloModule |\s*%\S+ = \w+\[%d,%d\]\S* parameter\(" % (n, k), l)
+               for l in rows), [l[:160] for l in rows]
+    # temporaries: the solver's history and row vectors, no longer the 4.3 GB
+    # of two padded copies of the rows (PERF.md section 7)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
