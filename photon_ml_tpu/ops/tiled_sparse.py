@@ -60,18 +60,18 @@ _VMEM_LIMIT = 100 * 1024 * 1024
 
 @dataclasses.dataclass(frozen=True)
 class Geometry:
-    """Sizes of the layout, all powers of two; the shipped ones are chosen on
-    the v5e from a sweep at (2^22, 64) over 2^21 features (PERF.md section 6,
-    PR 36). A table of ``h x 128`` float32 costs the matrix unit ``3 h / 128``
-    row pushes a slot (at least one), so the two sides are kept equal: 64 and
-    64 cost three, 128 and 32 four. A chunk is the unit of padding; a grid
-    step's chunks are unrolled, so that the compiler runs one chunk's second
-    product under the next one's first."""
+    """Sizes of the layout, all powers of two; the shipped ones won races on
+    the v5e at (2^22, 64) over 2^21 features (PERF.md section 6): tables of 32
+    rows cost more a chunk than 64, not less, and pad more (0.243 against 0.158
+    s a product), 128 rows nearly twice as much, chunks of 512 over a third
+    more. A chunk is the unit of padding; a grid step's chunks are unrolled,
+    so that the compiler runs one chunk's second product under the next one's
+    first: 64 a step beat 32 by 4 to 5 %."""
 
     block_rows: int = 8192  # rows a block: a table of 64 x 128
     tile_features: int = 8192  # features a tile: a table of 64 x 128
     chunk: int = 256  # slots a chunk
-    group: int = 32  # chunks a grid step
+    group: int = 64  # chunks a grid step
 
     def __post_init__(self):
         for v in dataclasses.astuple(self):
@@ -439,7 +439,8 @@ def build(indices: Array, values: Array, dim: int,
     g = geometry
     chunks = g.blocks(n) * g.slots_per_block(k, dim) // g.chunk
     nbytes = chunks * (8 * g.chunk + 4)
-    facts = dict(tiles=g.tiles(dim), chunks=chunks,
+    facts = dict(block_rows=g.block_rows, tile_features=g.tile_features,
+                 chunk=g.chunk, group=g.group, tiles=g.tiles(dim), chunks=chunks,
                  slots_over_stored=chunks * g.chunk / max(n * k, 1), bytes=nbytes)
     t0 = time.perf_counter()
     with profiling.span("pml.features.tile_layout", **facts):
