@@ -24,8 +24,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
 from photon_ml_tpu.data.game import RandomEffectDataset
+from photon_ml_tpu.ops import fused_glm
 from photon_ml_tpu.ops import losses as losses_mod
 from photon_ml_tpu.ops.features import DenseFeatures
 from photon_ml_tpu.ops.normalization import NormalizationContext
@@ -40,7 +42,7 @@ Array = jax.Array
 
 
 def entity_lane_fns(task, optimizer, optimizer_config, regularization,
-                    reg_weight=None):
+                    reg_weight=None, gspmd=False):
     """Per-lane solver closures over ONE entity's ``(x, y, off, w, ...)``
     problem, shared by the one-shot vmapped solve and the convergence-
     compaction scheduler (optim/scheduler.py) — both paths build the SAME
@@ -61,6 +63,14 @@ def entity_lane_fns(task, optimizer, optimizer_config, regularization,
     solve under one name in a trace; each evaluation of a lane's objective
     runs under ``pml.re.value_and_grad`` inside it, so a trace tells the
     lanes' evaluations from the fixed effect's.
+
+    A dense lane's value and gradient has a batching rule of its own: where
+    the lanes are ``vmap``-ed together and ``fused_glm.select_lane_block``
+    gives a block for their ``(M, D)`` (float32 on a TPU), all the lanes'
+    evaluations are one lane-block kernel that reads the stack once
+    (``pml.re.fused_value_grad``), in place of XLA's two passes. Everywhere
+    else, and where ``gspmd`` says GSPMD partitions the lanes (a pallas
+    call it cannot partition), it is the unbatched objective, vmapped.
     """
     from photon_ml_tpu.optim.lbfgs import (
         lbfgs_advance_,
@@ -84,14 +94,33 @@ def entity_lane_fns(task, optimizer, optimizer_config, regularization,
         # XLA scatter / segment-sum) without touching the solver kernels
         return x if hasattr(x, "matvec") else DenseFeatures(x)
 
+    def dense_vg(x, y, off_e, w_e, wt, l2):
+        return obj.value_and_grad(wt, GLMBatch(DenseFeatures(x), y, off_e, w_e), norm, l2)
+
+    lanes_vg = custom_vmap(dense_vg)
+
+    @lanes_vg.def_vmap
+    def lanes_rule(lanes, batched, x, y, off_e, w_e, wt, l2):
+        m, d = x.shape[-2:]
+        block = None if gspmd else fused_glm.select_lane_block(m, d, x.dtype)
+        if block is None or not all(batched[:5]):
+            axes = [0 if b else None for b in batched]
+            return jax.vmap(dense_vg, in_axes=axes)(x, y, off_e, w_e, wt, l2), (True, True)
+        lv, grad, _ = fused_glm.lane_value_grad_parts(loss, x, y, w_e, off_e, wt, block)
+        # the regularization as GLMObjective.value_and_grad adds it
+        value, grad = jax.vmap(
+            lambda lv, g, w, l2: (lv + 0.5 * l2 * jnp.sum(jnp.square(w)), g + l2 * w),  # lint: bitwise-reduction — l2 reg over one lane's (D,) w, as GLMObjective adds it
+            in_axes=(0, 0, 0, 0 if batched[5] else None),
+        )(lv, grad, wt, l2)
+        return (value, grad), (True, True)
+
     def vg_of(x, y, off_e, w_e):
-        batch = GLMBatch(feats_of(x), y, off_e, w_e)
-
-        @jax.named_scope("pml.re.value_and_grad")
-        def vg(wt):
-            return obj.value_and_grad(wt, batch, norm, l2)
-
-        return vg
+        if hasattr(x, "matvec"):
+            batch = GLMBatch(x, y, off_e, w_e)
+            vg = lambda wt: obj.value_and_grad(wt, batch, norm, l2)
+        else:
+            vg = lambda wt: lanes_vg(x, y, off_e, w_e, wt, l2)
+        return jax.named_scope("pml.re.value_and_grad")(vg)
 
     if optimizer == OptimizerType.TRON:
 
@@ -335,6 +364,7 @@ class RandomEffectCoordinate:
                 schedule=self.solve_schedule,
                 label=self.solve_label,
                 resume=resume,
+                gspmd=self.mesh_ctx is not None,
             )
             if self.mesh_ctx is not None:
                 # the coefficient slab keeps the sharded padded shape (the

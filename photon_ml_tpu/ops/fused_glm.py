@@ -19,6 +19,13 @@ pure function of platform, dtype and shape: a number of rows a block on a
 TPU for a matrix of more than ``MIN_MATRIX_BYTES``, ``None`` (the two-pass
 path) elsewhere.
 
+Many small problems at once, the per-entity lanes of a random effect, are
+the same pass over a stack ``(E, M, D)``: :func:`lane_value_grad_parts`
+reads a block of lanes once for their margins, losses and gradients, in the
+layout the device holds the stack in, behind :func:`select_lane_block`
+(platform, dtype and ``(M, D)``; ``algorithm/random_effect.entity_lane_fns``
+hands it the vmapped lanes).
+
 Numerically: margins/loss/derivative are computed in f32. Zero-weight rows
 contribute exactly nothing (hard-masked, so even an inf/nan loss on such a
 row is zeroed). Runs in interpreter mode on the CPU backend only (tests).
@@ -331,6 +338,117 @@ def fused_value_grad_parts(
 
 
 # ---------------------------------------------------------------------------
+# Many small problems at once: the per-entity lanes.
+# ---------------------------------------------------------------------------
+
+
+def _pairwise(terms):
+    """The sum of ``terms`` by adjacent pairs, level by level (an odd one
+    out goes up a level as it is)."""
+    while len(terms) > 1:
+        terms = [a + b for a, b in zip(terms[::2], terms[1::2])] + terms[len(terms) // 2 * 2:]
+    return terms[0]
+
+
+def _make_lane_block_kernel(loss: PointwiseLoss, d: int, lanes: int):
+    """The kernel over a block of ``lanes`` problems of a lane stack held as
+    ``(d, E, m)``: each feature a plane of the lanes' rows, rows along the
+    vector lanes. Eight problems (a sublane tile) at a time: the margins are
+    the features' planes times the coefficients (each spread over the rows)
+    added by pairs (:func:`_pairwise`), then the offsets; loss and slope a
+    row; the gradient each feature's plane times the slopes, summed over the
+    rows. Of the sound orders raced on the chip, margins by pairs kept the
+    GLMix cell's comparison with its reference within its limits; added in
+    feature order they did not (PERF.md section 6).
+    A problem's sums, loss and ``sum(d)`` included, are over its own rows in
+    an order set by ``(m, d)`` alone, so its bits do not depend on which
+    problems share the block or the call. The ``(8, 128)`` result a tile
+    holds the gradient in its first ``d`` lanes, the loss sum in lane ``d``
+    and ``sum(d)`` in lane ``d + 1``."""
+
+    def kernel(x_ref, y_ref, wt_ref, off_ref, w_ref, out_ref):
+        lane = lax.broadcasted_iota(jnp.int32, (8, LANES), 1)
+
+        def tile(t, carry):
+            here = pl.ds(pl.multiple_of(t * 8, 8), 8)
+            w = w_ref[here, :]
+            z = _pairwise([x_ref[j, here, :] * w[:, j:j + 1] for j in range(d)])
+            wl, slopes = _row_terms(loss, z + off_ref[here, :],
+                                    y_ref[here, :], wt_ref[here, :])
+            out = jnp.zeros((8, LANES), jnp.float32)
+            for j in range(d):
+                g = jnp.sum(x_ref[j, here, :] * slopes, axis=1, keepdims=True)  # lint: bitwise-reduction — pallas block-local reduce over a lane's own rows
+                out = jnp.where(lane == j, g, out)
+            out = jnp.where(lane == d, jnp.sum(wl, axis=1, keepdims=True), out)  # lint: bitwise-reduction — pallas block-local reduce over a lane's own rows
+            out = jnp.where(lane == d + 1, jnp.sum(slopes, axis=1, keepdims=True), out)  # lint: bitwise-reduction — pallas block-local reduce over a lane's own rows
+            out_ref[here, :] = out
+            return carry
+
+        lax.fori_loop(0, lanes // 8, tile, 0)
+
+    return kernel
+
+
+def _lanes_vmem_limit(lanes: int, m: int, d: int) -> Optional[int]:
+    """``vmem_limit_bytes`` of the lane-block kernel: two buffers each of
+    the block's features, its three row vectors, its coefficients and its
+    result, rows padded to whole vector lanes."""
+    m_pad = -(-m // LANES) * LANES
+    need = 2 * 4 * lanes * (m_pad * (d + 3) + 2 * LANES)
+    if need + (2 << 20) <= _DEFAULT_SCOPED_VMEM:
+        return None
+    return min(need + (4 << 20), _MAX_SCOPED_VMEM)
+
+
+@jax.named_scope("pml.re.fused_value_grad")
+def lane_value_grad_parts(
+    loss: PointwiseLoss,
+    x: jax.Array,
+    y: jax.Array,
+    weights: jax.Array,
+    offsets: jax.Array,
+    w: jax.Array,
+    block_lanes: int,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Each lane's (sum w_i*l_i, X^T d, sum d) with d = w_i*l'_i, for a
+    stack of E float32 problems ``x`` (E, M, D), rows ``y``, ``weights``,
+    ``offsets`` (E, M) and coefficients ``w`` (E, D), in one read of the
+    stack: :func:`fused_value_grad_parts` for many small problems.
+
+    The kernel reads the stack as ``(D, E, M)``: rows minor, features
+    major. Where the TPU holds the stack so (the order that pads least: at
+    the GLMix cell's 34,624 x 512 x 22 ``{1,0,2}``) the transpose is a
+    bitcast and nothing is copied; where it does not (a width that is a
+    multiple of 8, a few lanes) the compiler lays the stack out so once, in
+    front of the solver's loops. ``block_lanes`` lanes a
+    grid step, as :func:`select_lane_block` hands them out; E need not be a
+    multiple of it (the last block's missing lanes are read as garbage and
+    never written)."""
+    if interpret is None:
+        interpret = _interpret_default()
+    e, m, d = x.shape
+    rows = pl.BlockSpec((block_lanes, m), lambda i: (i, 0))
+    parts = pl.pallas_call(
+        _make_lane_block_kernel(loss, d, block_lanes),
+        grid=(pl.cdiv(e, block_lanes),),
+        in_specs=[pl.BlockSpec((d, block_lanes, m), lambda i: (0, i, 0)),
+                  rows, rows, rows,
+                  pl.BlockSpec((block_lanes, d), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((block_lanes, LANES), lambda i: (i, 0)),
+        # under shard_map the result varies over the mesh axes the shard does
+        out_shape=jax.ShapeDtypeStruct(
+            (e, LANES), jnp.float32, vma=jax.typeof(x).vma),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_lanes_vmem_limit(block_lanes, m, d),
+        ),
+        interpret=interpret,
+    )(jnp.transpose(x, (2, 0, 1)), y, weights, offsets, w)
+    return parts[:, d], parts[:, :d], parts[:, d + 1]
+
+
+# ---------------------------------------------------------------------------
 # Selection: from the shape, in microseconds.
 # ---------------------------------------------------------------------------
 
@@ -405,3 +523,28 @@ def select_fused_block_rows(n: int, d: int, dtype=jnp.bfloat16) -> Optional[int]
     ):
         return None
     return _vpu_block_rows(n, d, dtype.itemsize, lanes)
+
+
+# Lanes a grid step of the lane-block kernel. At 34,624 lanes of 512 x 22
+# float32 on the chip (margins then added in feature order), 32, 64 and 128
+# read alike (2.425 to 2.428 ms an evaluation, 731 GB/s), 16 read 2.53 ms
+# and 8 3.07 ms (PERF.md section 6); 64 divides that stack.
+_LANE_BLOCK = 64
+
+
+def select_lane_block(m: int, d: int, dtype=jnp.float32) -> Optional[int]:
+    """Lanes a grid step of :func:`lane_value_grad_parts` for a stack of
+    ``(m, d)`` problems, or ``None`` where the lanes keep XLA's two passes.
+    A pure function of platform, dtype and ``(m, d)``, never of the number
+    of lanes, so a lane computes the same bits however many lanes share
+    the call. On a TPU, for float32 rows that fill whole vector lanes
+    (``m`` a multiple of 128) and a gradient that fits one lane-row of the
+    result with its two sums (``d + 2 <= 128``): the most lanes, from
+    ``_LANE_BLOCK`` down, whose buffers fit the kernel's VMEM."""
+    if not (_on_tpu() and jnp.dtype(dtype) == jnp.float32
+            and m % LANES == 0 and d + 2 <= LANES):
+        return None
+    lanes = _LANE_BLOCK
+    while lanes > 8 and _lanes_vmem_limit(lanes, m, d) == _MAX_SCOPED_VMEM:
+        lanes //= 2
+    return None if _lanes_vmem_limit(lanes, m, d) == _MAX_SCOPED_VMEM else lanes

@@ -182,6 +182,7 @@ def device_solve(
     schedule,
     label: str = "re_solve",
     resume: Optional[dict] = None,
+    gspmd: bool = False,
 ) -> OptResult:
     """Solve every lane of ``data`` with the fused on-device
     chunk→compact→resume loop; bitwise-equal to
@@ -206,6 +207,7 @@ def device_solve(
         optimizer=optimizer,
         optimizer_config=optimizer_config,
         regularization=regularization,
+        gspmd=gspmd,
     )
     lanes = int(w0.shape[0])
     max_iter = optimizer_config.max_iterations

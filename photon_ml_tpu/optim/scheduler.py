@@ -401,17 +401,18 @@ solve_stats = SolveStats()
 # batches and same-ladder streaming blocks collapse onto few executables)
 # ---------------------------------------------------------------------------
 
-_STATICS = ("task", "optimizer", "optimizer_config", "regularization")
+_STATICS = ("task", "optimizer", "optimizer_config", "regularization", "gspmd")
 _INIT_JIT = None
 _CHUNK_JIT = None
 _GATHER_JIT = None
 _SCATTER_JIT = None
 
 
-def _lane_fns(task, optimizer, optimizer_config, regularization):
+def _lane_fns(task, optimizer, optimizer_config, regularization, gspmd=False):
     from photon_ml_tpu.algorithm.random_effect import entity_lane_fns
 
-    return entity_lane_fns(task, optimizer, optimizer_config, regularization)
+    return entity_lane_fns(task, optimizer, optimizer_config, regularization,
+                           gspmd=gspmd)
 
 
 def _init_batch(data, w0, **cfg):
@@ -419,9 +420,10 @@ def _init_batch(data, w0, **cfg):
     global _INIT_JIT
     if _INIT_JIT is None:
 
-        def impl(data, w0, task, optimizer, optimizer_config, regularization):
+        def impl(data, w0, task, optimizer, optimizer_config, regularization,
+                 gspmd):
             _, init_one, _, _ = _lane_fns(
-                task, optimizer, optimizer_config, regularization
+                task, optimizer, optimizer_config, regularization, gspmd
             )
             return jax.vmap(init_one)(*data, w0)
 
@@ -440,9 +442,9 @@ def _chunk_batch(data, state, limit, **cfg):
         from photon_ml_tpu.compile import donation_enabled
 
         def impl(data, state, limit, task, optimizer, optimizer_config,
-                 regularization):
+                 regularization, gspmd):
             _, _, advance_one, _ = _lane_fns(
-                task, optimizer, optimizer_config, regularization
+                task, optimizer, optimizer_config, regularization, gspmd
             )
             return jax.vmap(
                 advance_one, in_axes=(0, 0, 0, 0, 0, None)
@@ -567,6 +569,7 @@ def compacted_solve(
     schedule: SolveSchedule,
     label: str = "re_solve",
     resume: Optional[dict] = None,
+    gspmd: bool = False,
 ) -> OptResult:
     """Solve every lane of ``data = (x, labels, offsets, weights)`` (each
     with leading entity axis E) with chunked, convergence-compacted vmapped
@@ -587,6 +590,10 @@ def compacted_solve(
     restart uncompacted and re-compact at the next pause — lane arithmetic
     is batch-independent, so results are unchanged).
 
+    ``gspmd``: the lanes are partitioned by GSPMD (a coordinate's
+    ``mesh_ctx``), so their evaluations stay XLA's products, which it
+    partitions, and not the lane-block kernel (``entity_lane_fns``).
+
     ``schedule.loop == "device"`` routes the solve through the fused
     on-device loop (optim/fused_schedule.py) instead — same bitwise
     results, O(#rungs) host dispatches. The ``optim.device_drain`` fault
@@ -604,6 +611,7 @@ def compacted_solve(
         optimizer=optimizer,
         optimizer_config=optimizer_config,
         regularization=regularization,
+        gspmd=gspmd,
     )
     with profiling.span("pml.sched.solve", label=label,
                         lanes=int(w0.shape[0]), loop=schedule.loop):

@@ -10,7 +10,7 @@ seeded data at a small size whose width is no multiple of 128, as the cell's
 two-pass path, so the grid here is that; the one-pass kernel the cell runs on
 the chip is held to float64 in ``tests/test_fused_glm.py``, and at the end of
 this file the chip's compiler says what ``training._solve`` is made of at the
-cell's shape.
+cell's shape, and what the GLMix cell's per-entity lanes' programs are.
 """
 
 from __future__ import annotations
@@ -458,3 +458,68 @@ def test_the_sparse_cells_solve_runs_both_kernels_on_the_v5e(one_chip, monkeypat
     # temporaries: the solver's history and row vectors, no longer the 4.3 GB
     # of two padded copies of the rows (PERF.md section 7)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# -- the per-entity lanes on the v5e (the GLMix cell's per-user solve) ---------
+
+
+def _lane_program(one_chip, monkeypatch, lanes, which):
+    """The optimized program of the vmapped per-entity L-BFGS closure
+    ``which`` ("init": one evaluation; "solve": the whole solve) over
+    ``lanes`` problems of the GLMix cell's 512 x 22 float32, the kernel's
+    block selected as on a TPU."""
+    from photon_ml_tpu.algorithm.random_effect import entity_lane_fns
+    from photon_ml_tpu.ops import fused_glm
+    from photon_ml_tpu.ops.regularization import RegularizationContext
+    from photon_ml_tpu.optim.common import OptimizerConfig
+    from photon_ml_tpu.types import OptimizerType, TaskType
+
+    # the process's backend is the CPU: steer the two places that ask
+    monkeypatch.setattr(fused_glm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fused_glm, "_interpret_default", lambda: False)
+    solve_one, init_one, _, _ = entity_lane_fns(
+        TaskType.LOGISTIC_REGRESSION, OptimizerType.LBFGS,
+        OptimizerConfig(max_iterations=40, tolerance=1e-8, num_corrections=10),
+        RegularizationContext.l2(1.0))
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    m, d = 512, 22
+    fn = {"init": init_one, "solve": solve_one}[which]
+    return jax.jit(jax.vmap(fn)).lower(
+        shape(lanes, m, d), shape(lanes, m), shape(lanes, m), shape(lanes, m),
+        shape(lanes, d)).compile().as_text()
+
+
+def _kernel_calls(text):
+    import re
+
+    calls = [l for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert all("pml.re.value_and_grad/pml.re.fused_value_grad" in l for l in calls)
+    assert not re.search(r"multiply_reduce_fusion[.\d]* = f32\[%s,512\]"
+                         % re.search(r"f32\[(\d+),512,22\]", text).group(1), text)
+    return calls
+
+
+def test_the_lanes_evaluation_reads_the_slab_once_on_the_v5e(one_chip, monkeypatch):
+    """At the GLMix cell's 34,624 lanes of 512 x 22 float32 an evaluation of
+    every lane's objective is one kernel call under both scopes the cell's
+    metrics read: the slab is held with the rows minor and the features
+    major (``{1,0,2}``), the kernel reads it as it lies (a bitcast), and no
+    product of XLA's or copy of the 1.56 GB slab is left."""
+    import re
+
+    text = _lane_program(one_chip, monkeypatch, 34624, "init")
+    assert re.search(r"entry_computation_layout=\{\(f32\[34624,512,22\]\{1,0,2:", text)
+    assert len(_kernel_calls(text)) == 1
+    for line in text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%\S+ = f32\[(34624,512,22|22,34624,512)\]\S* (\S+?)\(", line)
+        if made:
+            assert made.group(2) in ("parameter", "bitcast"), line[:200]
+
+
+def test_the_lanes_solve_evaluates_through_the_kernel_on_the_v5e(one_chip, monkeypatch):
+    """The vmapped L-BFGS solve (1,024 lanes keep the compile short): the
+    batching rule reaches the evaluations inside the solver's loops too, the
+    one before the loop and the line search's, each one kernel call."""
+    calls = _kernel_calls(_lane_program(one_chip, monkeypatch, 1024, "solve"))
+    assert len(calls) == 2
+    assert sum("pml.lbfgs.line_search" in l for l in calls) == 1
