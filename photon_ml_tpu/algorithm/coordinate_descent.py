@@ -17,7 +17,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
-import time
 from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -44,12 +43,10 @@ class CoordinateDescentResult:
     total_scores: Array  # (N,) final summed training scores
     objective_history: List[float]  # after every coordinate update
     validation_history: List[Dict[str, float]]  # per update, per evaluator
-    # coordinate name (or "(fused-cycle)") -> cumulative HOST seconds from
-    # dispatching its update to the dispatch returning: the device may still
-    # be solving, so this is not solve time ("(grid)" alone is a combo's
-    # whole wall time, it blocks at the end). It marks which path ran; a
+    # which loop ran: "per-update" (one program per coordinate update),
+    # "fused-cycle" (one per descent iteration) or "grid" (run_grid); a
     # trace's pml.cd.* spans give the times
-    timings: Dict[str, float]
+    path: str
     # coordinate name -> the LAST update's OptResult (vmapped solves carry a
     # leading entity axis; bucketed coordinates a tuple per bucket) — the
     # raw material of the reference's OptimizationTracker summaries
@@ -73,29 +70,46 @@ def _is_pytree(coord) -> bool:
 _SITE_PROGRAMS: Dict[Tuple[str, str, bool], Callable] = {}
 
 
+def _call(kind: str, coord, *args):
+    """``coord.score(w)``, or ``coord.update(off, w0)`` with the per-entity
+    solve's evaluation counts reduced beside its tracker, in the same
+    program (``optim.scheduler.result_evaluation_counts``; None where the
+    tracker is no lane-stacked L-BFGS result)."""
+    if kind == "score":
+        return coord.score(*args)
+    from photon_ml_tpu.optim.scheduler import result_evaluation_counts
+
+    params, tracker = coord.update(*args)
+    return params, tracker, result_evaluation_counts(tracker)
+
+
 def _site_program(kind: str, name: str, donate: bool) -> Callable:
     key = (kind, name, donate)
     if key not in _SITE_PROGRAMS:
         from photon_ml_tpu.compile import instrumented_jit
 
         _SITE_PROGRAMS[key] = instrumented_jit(
-            lambda coord, *args: getattr(coord, kind)(*args),
+            functools.partial(_call, kind),
             site=f"cd.{kind}[{name}]", donate_argnums=(2,) if donate else ())
     return _SITE_PROGRAMS[key]
 
 
 def _bind(kind: str, name: str, coord, donate: bool) -> Callable:
-    """``coord.update(off, w0)`` or ``coord.score(w)`` as the descent calls
-    it: raw for a ``cd_jit=False`` coordinate, the shared site program with
-    the coordinate as its argument for a pytree, else a jit that closes
-    over the coordinate. ``donate`` donates ``w0`` into the update."""
+    """``coord.update(off, w0)`` (with its evaluation counts, see
+    :func:`_call`) or ``coord.score(w)`` as the descent calls it: raw for a
+    ``cd_jit=False`` coordinate (no counts: reducing them there would be
+    launches of their own), the shared site program with the coordinate as
+    its argument for a pytree, else a jit that closes over the coordinate.
+    ``donate`` donates ``w0`` into the update."""
     if not getattr(coord, "cd_jit", True):
-        return getattr(coord, kind)
+        if kind == "score":
+            return coord.score
+        return lambda *args: (*coord.update(*args), None)
     if _is_pytree(coord):
         return functools.partial(_site_program(kind, name, donate), coord)
     from photon_ml_tpu.compile import instrumented_jit
 
-    return instrumented_jit(lambda *args: getattr(coord, kind)(*args),
+    return instrumented_jit(functools.partial(_call, kind, coord),
                             site=f"cd.{kind}[{name}]",
                             donate_argnums=(1,) if donate else ())
 
@@ -137,8 +151,8 @@ class CoordinateDescent:
         validation metrics) unrolled into a single jitted cycle. The host
         dispatches once per iteration instead of ~4x per coordinate, and
         XLA can overlap across coordinate boundaries. Trade-offs: checkpoints land at iteration
-        (not per-update) granularity, and per-coordinate wall timings
-        collapse into one '(fused-cycle)' entry.
+        (not per-update) granularity, and no tracker (nor evaluation
+        record, ``optim.scheduler.solve_stats``) leaves the compiled cycle.
 
         ``divergence_guard`` (resilience.guards.DivergenceGuard) gates every
         update: a non-finite parameter/score state is rolled back to the
@@ -230,6 +244,17 @@ class CoordinateDescent:
                     }
                 )
         return params, scores, total, objs, vals
+
+    def _record_evaluations(self, name: str, tracker, counts) -> None:
+        """Enter a one-shot per-entity solve's evaluation counts (device
+        scalars of the update's own program: no launch, no host wait) in the
+        solve ledger under the coordinate's ``solve_label``."""
+        from photon_ml_tpu.optim.scheduler import solve_stats
+
+        solve_stats.record_evaluations(
+            getattr(self.coordinates[name], "solve_label", name), "one-shot",
+            tracker.evaluations.shape[0], counts,
+        )
 
     def _require_jittable_coordinates(self, mode: str) -> None:
         """fused_cycle / run_grid wrap EVERY coordinate in one outer jit; a
@@ -406,7 +431,6 @@ class CoordinateDescent:
                     objective_history = restored.objective_history
                     validation_history = restored.validation_history
 
-            t0 = time.perf_counter()
             objective_dev: List[Array] = []
             validation_dev: List[Dict[str, Array]] = []
 
@@ -471,7 +495,6 @@ class CoordinateDescent:
                             site="cycle",
                         )
             jax.block_until_ready(total)
-            elapsed = time.perf_counter() - t0
 
             _drain()
             out.append(
@@ -480,7 +503,7 @@ class CoordinateDescent:
                     total_scores=total[0],
                     objective_history=objective_history,
                     validation_history=validation_history,
-                    timings={"(grid)": elapsed},
+                    path="grid",
                 )
             )
         return out
@@ -573,9 +596,6 @@ class CoordinateDescent:
         validation_dev: List[Dict[str, Array]] = []
         objective_history: List[float] = []
         validation_history: List[Dict[str, float]] = []
-        # per-coordinate entries only where they are actually measured (the
-        # fused path measures whole cycles, not coordinates)
-        timings = {} if self.fused_cycle else {n: 0.0 for n in names}
         trackers: Dict[str, object] = {}
         total = jnp.zeros((num_rows,), real_dtype())
         for n in names:
@@ -660,7 +680,6 @@ class CoordinateDescent:
                 if step <= start_step:
                     continue
                 with profiling.span("pml.cd.iteration", iteration=it):
-                    t0 = time.perf_counter()
                     with profiling.span("pml.cd.cycle", iteration=it):
                         new_params, new_scores, new_total, objs, vals = self._cycle_fn(
                             self._arguments, params, scores, total
@@ -694,9 +713,6 @@ class CoordinateDescent:
                                 else:
                                     vals = []
                     params, scores, total = new_params, new_scores, new_total
-                    timings["(fused-cycle)"] = (
-                        timings.get("(fused-cycle)", 0.0) + time.perf_counter() - t0
-                    )
                     objective_dev.extend(objs)
                     validation_dev.extend(vals)
                     is_last = it == num_iterations - 1
@@ -740,7 +756,7 @@ class CoordinateDescent:
                 total_scores=total,
                 objective_history=objective_history,
                 validation_history=validation_history,
-                timings=timings,
+                path="fused-cycle",
                 guard_events=(
                     list(guard.events[guard_events_start:])
                     if guard is not None
@@ -758,7 +774,6 @@ class CoordinateDescent:
                         continue  # already completed before the restart
                     if not skip_rest_of_cycle and name not in frozen:
                         partial = total - scores[name]  # sum of the OTHER coordinates
-                        t0 = time.perf_counter()
                         with profiling.span("pml.cd.update", coordinate=name):
                             try:
                                 if midstep is not None and step == int(
@@ -782,9 +797,13 @@ class CoordinateDescent:
                                     ].update(partial, params[name], resume=midstep)
                                     midstep = None
                                 else:
-                                    new_params, trackers[name] = self._update_fns[name](
-                                        partial, params[name]
+                                    new_params, trackers[name], counts = (
+                                        self._update_fns[name](partial, params[name])
                                     )
+                                    if counts is not None:
+                                        self._record_evaluations(
+                                            name, trackers[name], counts
+                                        )
                             except _preemption.Preempted as e:
                                 # an inner loop drained at a block/chunk boundary:
                                 # checkpoint the completed steps PLUS the in-flight
@@ -815,7 +834,6 @@ class CoordinateDescent:
                                 )
                                 if not ok and guard.mode == "skip_cycle":
                                     skip_rest_of_cycle = True
-                        timings[name] += time.perf_counter() - t0
                         params[name] = new_params
                         total = partial + new_score
                         scores[name] = new_score
@@ -879,7 +897,7 @@ class CoordinateDescent:
             total_scores=total,
             objective_history=objective_history,
             validation_history=validation_history,
-            timings=timings,
+            path="per-update",
             trackers=trackers,
             guard_events=(
                 list(guard.events[guard_events_start:]) if guard is not None else []

@@ -310,6 +310,7 @@ class RandomEffectCoordinate:
         return jnp.zeros((self.num_entities, self.local_dim), real_dtype())
 
     # ------------------------------------------------------------------
+    @jax.named_scope("pml.re.offsets")
     def gathered_offsets(self, residual_offsets: Array) -> Array:
         """Global (N,) residual scores gathered into the entity-major
         (E, M) layout and added to the base offsets (the addScoresToOffsets

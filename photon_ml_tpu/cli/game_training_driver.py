@@ -1738,7 +1738,14 @@ class GameTrainingDriver:
         from photon_ml_tpu.compile import compile_stats
 
         self.logger.info(compile_stats.summary())
-        if self.solve_schedule is not None or self.plan.adaptive is not None:
+        ran_random_effect = any(
+            name in p.random_effect_data_configs
+            for combo in self.combo_coords for name in combo
+        )
+        if (ran_random_effect or self.solve_schedule is not None
+                or self.plan.adaptive is not None):
+            # the per-entity solves' ledger: lane-iterations of compacted
+            # solves, evaluations of every recorded one
             from photon_ml_tpu.optim.scheduler import solve_stats
 
             self.logger.info(solve_stats.summary())

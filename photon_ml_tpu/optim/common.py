@@ -68,6 +68,12 @@ class OptResult(NamedTuple):
     # was run with track_coefficients (the ModelTracker analogue,
     # supervised/model/ModelTracker.scala); None otherwise
     coefficient_history: Optional[Array] = None
+    # () int32 objective evaluations the problem made, and (max_iter + 1,)
+    # int32 of them an iteration (entry 0 the initial one, 0 past
+    # `iterations`): a lane's own work under vmap (optim/lbfgs.py _State).
+    # None where the solver keeps no count (TRON, the streaming solvers)
+    evaluations: Optional[Array] = None
+    evaluation_history: Optional[Array] = None
 
 
 def summarize_result(res: OptResult) -> str:

@@ -199,6 +199,7 @@ def device_solve(
         _lane_fns,
         _restore_state,
         _snapshot_state,
+        record_compacted_evaluations,
         solve_stats,
     )
 
@@ -288,4 +289,6 @@ def device_solve(
             device_chunks=device_chunks,
         )
     )
-    return result_of(state)
+    result = result_of(state)
+    record_compacted_evaluations(result, label)
+    return result

@@ -79,7 +79,16 @@ class _State(NamedTuple):
     ``pg0_norm``, fixed at init) ride in the state instead of living as
     Python-closure constants, so a paused state can be handed to a
     different compiled chunk kernel (or gathered into a compacted batch by
-    optim/scheduler.py) and resumed bit-exactly."""
+    optim/scheduler.py) and resumed bit-exactly.
+
+    ``evaluations`` counts the objective evaluations this problem made: 1
+    at init, then each line-search trial of each iteration it ran;
+    ``evaluation_history[i]`` holds iteration ``i``'s trials (entry 0 the
+    initial evaluation, entries past ``iteration`` 0). Both count the
+    problem's OWN work, also under ``vmap``: the batched outer loop keeps a
+    lane's carry only while that lane's own predicate holds, so the line
+    searches a stopped lane's body still runs (while other lanes go on)
+    are discarded with the rest of that body and never counted."""
 
     w: Array
     f: Array  # smooth value
@@ -97,6 +106,8 @@ class _State(NamedTuple):
     w_history: Array  # (max_iter + 1, D) if tracking, else (1, 1) dummy
     F0: Array  # objective at w0 (function-convergence reference)
     pg0_norm: Array  # initial pseudo-gradient norm (gradient-tol reference)
+    evaluations: Array  # () int32 objective evaluations made
+    evaluation_history: Array  # (max_iter + 1,) int32 evaluations an iteration
 
 
 @functools.partial(jax.jit, static_argnames=("value_and_grad_fn", "config"))
@@ -183,6 +194,8 @@ def lbfgs_init_(
         w_history=w_hist0,
         F0=F0,
         pg0_norm=pg0_norm,
+        evaluations=jnp.ones((), jnp.int32),
+        evaluation_history=jnp.zeros((max_iter + 1,), jnp.int32).at[0].set(1),
     )
 
 
@@ -263,7 +276,7 @@ def lbfgs_advance_(
 
         init = (t0, s.w, s.f, s.g, s.F, jnp.zeros((), jnp.int32), jnp.zeros((), bool))
         with jax.named_scope("pml.lbfgs.line_search"):
-            t, w_new, f_new, g_new, F_new, _, ls_ok = lax.while_loop(
+            t, w_new, f_new, g_new, F_new, trials, ls_ok = lax.while_loop(
                 ls_cond, ls_body, init
             )
 
@@ -337,6 +350,13 @@ def lbfgs_advance_(
             ),
             F0=s.F0,
             pg0_norm=s.pg0_norm,
+            evaluations=s.evaluations + trials,
+            # a select, not a scatter: under vmap each lane writes its own
+            # entry, which the chip does in one elementwise pass
+            evaluation_history=jnp.where(
+                jnp.arange(s.evaluation_history.shape[0]) == it,
+                trials, s.evaluation_history,
+            ),
         )
 
     return lax.while_loop(cond, body, state)
@@ -354,6 +374,8 @@ def lbfgs_result(state: _State, track_coefficients: bool = False) -> OptResult:
         value_history=state.value_history,
         grad_norm_history=state.grad_norm_history,
         coefficient_history=state.w_history if track_coefficients else None,
+        evaluations=state.evaluations,
+        evaluation_history=state.evaluation_history,
     )
 
 

@@ -33,6 +33,12 @@ lane's arithmetic: final results are bitwise-equal to the one-shot kernel
 Telemetry: every compacted solve records per-chunk active-lane counts and
 the lane-iteration ledger in :data:`solve_stats` (the CompileStats
 pattern); drivers log ``solve_stats.summary()`` next to the compile stats.
+The same registry prices per-entity solves in objective evaluations, the
+unit that costs time (an L-BFGS iteration is 1 to ``max_line_search_steps``
+of them): compacted solves and the one-shot vmapped solve a descent's
+program runs (``algorithm/coordinate_descent.py``) both record what their
+lanes made themselves and what a vmapped loop needs when stopped lanes do
+not hold its line search (:func:`evaluation_counts`).
 
 Env control: ``PHOTON_SOLVE_CHUNK`` = ``off`` (default) | ``on`` | K
 (chunk size) | ``device[:K]`` (the fused on-device loop,
@@ -221,6 +227,49 @@ class SolveRecord:
         return len(self.chunks)
 
 
+@dataclasses.dataclass
+class EvaluationRecord:
+    """Objective evaluations of one per-entity solve (:func:`evaluation_counts`).
+
+    The two counts are the int32 device scalars the solving program reduced
+    until the ledger is read, and host ints after: recording adds no launch
+    and no host wait to the solve."""
+
+    label: str
+    kind: str  # "one-shot" (a descent program's vmapped solve) | "compacted"
+    lanes: int
+    lane_evaluations: object  # sum over lanes of each lane's own evaluations
+    needed_evaluations: object  # sum over iterations of the most any lane made
+
+
+def evaluation_counts(evaluations, evaluation_history):
+    """``(lane_evaluations, needed_evaluations)`` of a lane-stacked L-BFGS
+    result, as int32 scalars (traced inside the program that solves):
+
+      * ``lane_evaluations`` — the lanes' own evaluations summed, the floor
+        of a loop that ran each lane only for itself;
+      * ``needed_evaluations`` — ``max(h[:, 0]) + sum_{i>=1} max over lanes
+        of h[:, i]`` for ``h = evaluation_history``: what a vmapped loop
+        runs when lanes that have stopped do not hold its line search.
+
+    The loop as it runs also pays the line searches of stopped lanes, which
+    no counter sees (the trace's count of evaluations does)."""
+    with jax.named_scope("pml.re.evaluation_counts"):
+        lane = jnp.sum(evaluations)  # lint: bitwise-reduction — int32 counts; integer addition is exact in any order
+        needed = jnp.sum(jnp.max(evaluation_history, axis=0))  # lint: bitwise-reduction — int32 counts; integer addition is exact in any order
+    return lane, needed
+
+
+def result_evaluation_counts(result):
+    """:func:`evaluation_counts` of a lane-stacked result, or None where it
+    is not one (an unbatched solve, a bucketed tuple) or its solver keeps
+    no count (TRON)."""
+    history = getattr(result, "evaluation_history", None)
+    if history is None or history.ndim != 2:
+        return None
+    return evaluation_counts(result.evaluations, history)
+
+
 class SolveStats:
     """Registry of compacted-solve ledgers (thread-safe: the streaming
     prefetch pipeline can overlap block solves with host work).
@@ -232,7 +281,14 @@ class SolveStats:
     process memory with the run length. The per-block convergence ledger
     added for adaptive scheduling (optim/convergence.py) is keyed by block
     label and updated in place, so it is bounded by the BLOCK COUNT, not
-    the run length."""
+    the run length.
+
+    The evaluation ledger (:class:`EvaluationRecord`, one-shot and compacted
+    solves) is a separate book: the lane-iteration counters above, which
+    :meth:`realized_plan_cost` feeds the planner, count compacted solves
+    alone. Its records hold device scalars until read; past
+    ``2 * RECENT_KEEP`` unread records the oldest ``RECENT_KEEP`` are read
+    and folded into per-(kind, label) totals, so it stays bounded too."""
 
     RECENT_KEEP = 32
     HOTTEST_KEEP = 5
@@ -246,6 +302,9 @@ class SolveStats:
         self._worst: Optional[SolveRecord] = None
         self._recent: List[SolveRecord] = []
         self._blocks: dict = {}
+        self._evaluations_unread: List[EvaluationRecord] = []
+        self._evaluations_read: List[EvaluationRecord] = []
+        self._evaluation_totals: dict = {}
 
     def record(self, rec: SolveRecord) -> None:
         with self._lock:
@@ -259,6 +318,63 @@ class SolveStats:
                 self._worst = rec
             self._recent.append(rec)
             del self._recent[: -self.RECENT_KEEP]
+
+    def record_evaluations(self, label: str, kind: str, lanes: int,
+                           counts) -> None:
+        """One per-entity solve's :func:`evaluation_counts` (device scalars,
+        kept unread)."""
+        rec = EvaluationRecord(label, kind, int(lanes), *counts)
+        with self._lock:
+            self._evaluations_unread.append(rec)
+            if len(self._evaluations_unread) > 2 * self.RECENT_KEEP:
+                self._read_evaluations(self.RECENT_KEEP)
+
+    def _read_evaluations(self, n: Optional[int] = None) -> None:
+        """Read the ``n`` oldest unread evaluation records (all: None) in
+        one transfer and fold them into the totals. Holds the lock."""
+        batch = self._evaluations_unread[:n]
+        if not batch:
+            return
+        values = jax.device_get(
+            [(r.lane_evaluations, r.needed_evaluations) for r in batch]
+        )
+        del self._evaluations_unread[: len(batch)]
+        for rec, (lane, needed) in zip(batch, values):
+            rec.lane_evaluations, rec.needed_evaluations = int(lane), int(needed)
+            t = self._evaluation_totals.setdefault(
+                (rec.kind, rec.label),
+                {"solves": 0, "lanes": 0, "lane_evaluations": 0,
+                 "needed_evaluations": 0, "lane_means": 0.0},
+            )
+            t["solves"] += 1
+            t["lanes"] += rec.lanes
+            t["lane_evaluations"] += rec.lane_evaluations
+            t["needed_evaluations"] += rec.needed_evaluations
+            t["lane_means"] += rec.lane_evaluations / max(rec.lanes, 1)
+        self._evaluations_read.extend(batch)
+        del self._evaluations_read[: -self.RECENT_KEEP]
+
+    def evaluation_snapshot(self) -> List[EvaluationRecord]:
+        """The most recent evaluation records, read (newest last)."""
+        with self._lock:
+            self._read_evaluations()
+            return list(self._evaluations_read)
+
+    def evaluation_totals(self, label: Optional[str] = None) -> dict:
+        """Kind -> summed ``solves``, ``lanes``, ``lane_evaluations``,
+        ``needed_evaluations`` and ``lane_means`` (each solve's lane
+        evaluations over its lanes) of every recorded solve, of ``label``
+        alone where given; reads what is unread."""
+        with self._lock:
+            self._read_evaluations()
+            out: dict = {}
+            for (kind, lab), t in self._evaluation_totals.items():
+                if label is not None and lab != label:
+                    continue
+                into = out.setdefault(kind, dict.fromkeys(t, 0))
+                for key, value in t.items():
+                    into[key] += value
+            return out
 
     def record_block(self, label: str, *, score: Optional[float] = None,
                      executed: int = 0, skipped: bool = False) -> None:
@@ -297,6 +413,9 @@ class SolveStats:
             self._worst = None
             self._recent.clear()
             self._blocks.clear()
+            self._evaluations_unread.clear()
+            self._evaluations_read.clear()
+            self._evaluation_totals.clear()
 
     def totals(self) -> dict:
         with self._lock:
@@ -333,7 +452,9 @@ class SolveStats:
 
     def summary(self) -> str:
         """Driver-log summary: the ledger plus per-chunk active-lane decay
-        of the worst (largest-baseline) solve."""
+        of the worst (largest-baseline) solve, and the per-entity solves'
+        evaluations: needed a solve against a lane's own."""
+        evaluations = self.evaluation_totals()
         with self._lock:  # one acquisition: totals + worst must be coherent
             t = {
                 "solves": self._counters["solves"],
@@ -364,6 +485,16 @@ class SolveStats:
                 f"{t['baseline_lane_iterations']} one-shot "
                 f"(saved {t['saved_lane_iterations']}, {pct:.1f}%)"
             )
+        lines.append("solve evaluations: " + "; ".join(
+            f"{kind} " + (
+                f"{t['solves']} solves / {t['lanes']} lanes, "
+                f"{t['needed_evaluations'] / t['solves']:.1f} needed a solve vs "
+                f"{t['lane_means'] / t['solves']:.1f} a lane's own"
+                if t else "none"
+            )
+            for kind, t in (("one-shot", evaluations.get("one-shot")),
+                            ("compacted", evaluations.get("compacted")))
+        ))
         if worst is not None:
             decay = " -> ".join(
                 f"{c.active_lanes}/{c.batch_lanes}@{c.limit}" for c in worst.chunks
@@ -402,6 +533,7 @@ solve_stats = SolveStats()
 # ---------------------------------------------------------------------------
 
 _STATICS = ("task", "optimizer", "optimizer_config", "regularization", "gspmd")
+_COUNTS_JIT = None
 _INIT_JIT = None
 _CHUNK_JIT = None
 _GATHER_JIT = None
@@ -413,6 +545,20 @@ def _lane_fns(task, optimizer, optimizer_config, regularization, gspmd=False):
 
     return entity_lane_fns(task, optimizer, optimizer_config, regularization,
                            gspmd=gspmd)
+
+
+def record_compacted_evaluations(result, label: str) -> None:
+    """Record a compacted solve's merged per-lane counters (one small
+    program; none where the solver keeps no count)."""
+    global _COUNTS_JIT
+    if result.evaluation_history is None:
+        return
+    if _COUNTS_JIT is None:
+        _COUNTS_JIT = instrumented_jit(evaluation_counts, site="scheduler.counts")
+    solve_stats.record_evaluations(
+        label, "compacted", result.evaluations.shape[0],
+        _COUNTS_JIT(result.evaluations, result.evaluation_history),
+    )
 
 
 def _init_batch(data, w0, **cfg):
@@ -753,4 +899,6 @@ def _host_solve(data, w0, cfg, schedule, label, resume) -> OptResult:
             chunks=chunks,
         )
     )
-    return result_of(state)
+    result = result_of(state)
+    record_compacted_evaluations(result, label)
+    return result

@@ -6,6 +6,7 @@ save), then round-trips through the scoring driver and feature indexing job.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -245,6 +246,14 @@ class TestGameTraining:
         assert metrics["AUC"] > 0.8, metrics
         # objective decreases across coordinate updates
         assert result.objective_history[-1] < result.objective_history[0]
+
+    def test_the_log_prices_the_per_entity_solves_in_evaluations(self, trained):
+        """No compaction flag: the per-user solves run one-shot, and the log
+        still carries the solve ledger with their evaluations."""
+        _, out, _ = trained
+        with open(os.path.join(out, "photon-ml-tpu-game.log")) as f:
+            text = f.read()
+        assert re.search(r"solve evaluations: one-shot [1-9]\d* solves / \d+ lanes", text)
 
     def test_model_layout_on_disk(self, trained):
         _, out, _ = trained
@@ -720,7 +729,7 @@ class TestVmappedGrid:
                 np.asarray(rs.objective_history),
                 rtol=1e-4,
             )
-        assert "(grid)" in vm.results[0][1].timings
+        assert vm.results[0][1].path == "grid"
         # the saved best model matches the sequential best
         from photon_ml_tpu.io import model_io
 
@@ -753,7 +762,7 @@ class TestVmappedGrid:
         )
         assert len(driver.results) == 2
         assert "shared-compile-grid" in driver.timer.totals
-        assert "(grid)" in driver.results[0][1].timings
+        assert driver.results[0][1].path == "grid"
 
     def test_vmapped_grid_falls_back_when_ineligible(self, game_avro_dirs, tmp_path):
         """Combos varying beyond lambda -> sequential fallback (logged),
@@ -774,7 +783,7 @@ class TestVmappedGrid:
             + flags
         )
         assert len(driver.results) == 2  # sequential path still ran the grid
-        assert "(grid)" not in driver.results[0][1].timings
+        assert driver.results[0][1].path != "grid"
 
 
 class TestDateRangeDiscovery:

@@ -187,7 +187,7 @@ def build_coordinates(data, schedule=None):
                           "pml.lbfgs.pair_update"}),
     ("global", "score", {"pml.fe.score", "pml.features.matvec"}),
     ("per-user", "update", {"pml.re.lane_solve", "pml.re.value_and_grad",
-                            "pml.objective.value_and_grad",
+                            "pml.re.offsets", "pml.objective.value_and_grad",
                             "pml.features.matvec", "pml.features.rmatvec",
                             "pml.lbfgs.direction", "pml.lbfgs.line_search",
                             "pml.lbfgs.pair_update"}),
@@ -281,7 +281,7 @@ SITES = [
     ("fused", "cd.fused_cycle"), ("grid", "cd.grid_cycle"),
     ("sched-host", "scheduler.init"), ("sched-host", "scheduler.chunk"),
     ("sched-host", "scheduler.compact"), ("sched-host", "scheduler.scatter"),
-    ("sched-device", "scheduler.rung"),
+    ("sched-host", "scheduler.counts"), ("sched-device", "scheduler.rung"),
 ]
 
 

@@ -578,7 +578,7 @@ def run_config_game(results, fast):
         + ["--output-dir", os.path.join(tmp, "output-vgrid"),
            "--vmapped-grid", "true"]
     )
-    assert "(grid)" in vg.results[0][1].timings, "grid API path did not engage"
+    assert vg.results[0][1].path == "grid", "grid API path did not engage"
     vg_obj = float(vg.results[0][1].objective_history[-1])
     vg_rmse = float(vg.results[0][2]["RMSE"])
     assert abs(vg_obj - ours_obj) / abs(ours_obj) < 1e-7, (vg_obj, ours_obj)
