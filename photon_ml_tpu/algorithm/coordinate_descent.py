@@ -73,14 +73,16 @@ _SITE_PROGRAMS: Dict[Tuple[str, str, bool], Callable] = {}
 def _call(kind: str, coord, *args):
     """``coord.score(w)``, or ``coord.update(off, w0)`` with the per-entity
     solve's evaluation counts reduced beside its tracker, in the same
-    program (``optim.scheduler.result_evaluation_counts``; None where the
-    tracker is no lane-stacked L-BFGS result)."""
+    program, at the widths its one-shot solve ran
+    (``optim.scheduler.result_evaluation_counts``; None where the tracker
+    is no lane-stacked L-BFGS result)."""
     if kind == "score":
         return coord.score(*args)
     from photon_ml_tpu.optim.scheduler import result_evaluation_counts
 
     params, tracker = coord.update(*args)
-    return params, tracker, result_evaluation_counts(tracker)
+    return params, tracker, result_evaluation_counts(
+        tracker, getattr(coord, "rung_widths", None))
 
 
 def _site_program(kind: str, name: str, donate: bool) -> Callable:
@@ -251,9 +253,11 @@ class CoordinateDescent:
         solve ledger under the coordinate's ``solve_label``."""
         from photon_ml_tpu.optim.scheduler import solve_stats
 
+        coord = self.coordinates[name]
         solve_stats.record_evaluations(
-            getattr(self.coordinates[name], "solve_label", name), "one-shot",
+            getattr(coord, "solve_label", name), "one-shot",
             tracker.evaluations.shape[0], counts,
+            widths=getattr(coord, "rung_widths", None),
         )
 
     def _require_jittable_coordinates(self, mode: str) -> None:

@@ -7,9 +7,11 @@ optimizer }, scoring = join models with data by entity. TPU-native:
   * entities are the leading axis of padded ``(E, M, D_loc)`` tensors
     (built at ingest, data/game.py), so "one optimizer per entity"
     (RandomEffectOptimizationProblem.scala:39-125) is the SAME while_loop
-    kernel ``vmap``-ed over the entity axis — converged entities keep
-    looping as masked no-ops until the slowest lane finishes, which is why
-    the kernels are branch-free;
+    kernel ``vmap``-ed over the entity axis — converged entities ride
+    along as masked no-ops, which is why the kernels are branch-free; a
+    one-shot solve of many entities steps over a ladder of narrowing
+    widths, so that stopped lanes leave the batch (optim/fused_schedule.py
+    ``ladder_solve``);
   * sharding the entity axis over the mesh gives the reference's
     co-partitioned-RDD model parallelism with zero joins;
   * scoring is one gather: score_n = sum_k val_nk * W[entity(n), col_nk] —
@@ -386,12 +388,38 @@ class RandomEffectCoordinate:
                 "scheduler's chunk boundaries; this coordinate solves "
                 "one-shot (no solve_schedule)"
             )
-        solve_one, _, _, _ = entity_lane_fns(
+        solve_one, init_one, advance_one, result_of = entity_lane_fns(
             self.task, self.optimizer, self.optimizer_config,
             self.regularization, reg_weight,
         )
-        results = jax.vmap(solve_one)(feats, ds.labels, off, ds.weights, init_coefficients)
+        data = (feats, ds.labels, off, ds.weights)
+        # under the traced-lambda grid's vmap the lanes keep one width: a
+        # rung's exit would wait for the slowest lambda's lanes
+        widths = self.rung_widths if reg_weight is None else (self.num_entities,)
+        if len(widths) == 1:
+            results = jax.vmap(solve_one)(*data, init_coefficients)
+        else:
+            from photon_ml_tpu.optim.fused_schedule import ladder_solve
+
+            state, _ = ladder_solve(
+                data, init_coefficients, widths, init_one, advance_one,
+                self.optimizer_config.max_iterations,
+            )
+            results = result_of(state)
         return results.coefficients, results
+
+    @property
+    def rung_widths(self) -> Tuple[int, ...]:
+        """The widths a one-shot ``update`` runs its iterations at
+        (``optim.fused_schedule.lane_widths``), from the lane count and the
+        block the lanes' kernel takes at the dataset's shape: one width, the
+        plain vmapped loop, for a few hundred lanes."""
+        from photon_ml_tpu.optim.fused_schedule import lane_widths
+
+        x = self.dataset.x
+        unit = None if self._slab is not None else fused_glm.select_lane_block(
+            *x.shape[-2:], x.dtype)
+        return lane_widths(self.num_entities, unit)
 
     # ------------------------------------------------------------------
     def coefficient_variances(self, coefficients: Array,

@@ -49,13 +49,22 @@ device[:CHUNK]`` or ``PHOTON_SOLVE_CHUNK=device[:CHUNK]`` via
 dispatch: an injected fault degrades the solve to the host chunk loop
 (results stay bitwise), recorded in the log; a real compile or runtime
 error of the fused program raises.
+
+The one-shot per-entity solve (``RandomEffectCoordinate.update``) compacts
+too, inside its own program and with no host re-entry:
+:func:`ladder_solve` runs the lanes one L-BFGS iteration at a time over a
+static ladder of widths (:func:`lane_widths`, from the lane count and the
+lanes' kernel block), gathering the lanes still running into the next
+narrower rung as soon as few enough are left. Its compaction and the rung
+program's are one pair of functions (:func:`compact_lanes`,
+:func:`scatter_lanes`).
 """
 
 from __future__ import annotations
 
 import logging
 import math
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -70,7 +79,10 @@ from photon_ml_tpu.utils import profiling
 
 logger = logging.getLogger(__name__)
 
-__all__ = ["device_solve", "rung_ladder", "next_lower_rung"]
+__all__ = [
+    "device_solve", "rung_ladder", "next_lower_rung", "lane_widths",
+    "ladder_solve", "rung_entries",
+]
 
 _RUNG_JIT = None
 
@@ -102,6 +114,32 @@ def next_lower_rung(bucketer, rung: int) -> int:
     return prev
 
 
+def running_lanes(state):
+    """How many lanes of a lane-stacked solver state still run
+    (``reason == 0``), as an int32 scalar."""
+    return jnp.sum((state.reason == 0).astype(jnp.int32))  # lint: bitwise-reduction — int32 flag count; integer addition is exact in any order
+
+
+def compact_lanes(data, state, width: int):
+    """``(idx, data, state)`` of the ``width`` lanes a narrower batch takes
+    (``width`` static): the running lanes first, then stopped ones as
+    fillers, each group in ascending lane index — the host loop's
+    ``np.nonzero`` order. ``idx`` holds ``width`` distinct lanes, and a
+    stopped filler advances as the identity (the solvers' ``reason != 0``
+    mask), so :func:`scatter_lanes` puts every lane back exactly."""
+    idx = jnp.argsort((state.reason != 0).astype(jnp.int32), stable=True)[:width]
+    # in bounds and distinct by construction: "clip" spares the gathered
+    # batch the pass that "fill" masks out-of-bounds rows with
+    take = lambda a: jnp.take(a, idx, axis=0, mode="clip", unique_indices=True)
+    return idx, jax.tree.map(take, data), jax.tree.map(take, state)
+
+
+def scatter_lanes(state, idx, part):
+    """Lanes ``idx`` of ``state`` replaced by the batch ``part``
+    :func:`compact_lanes` gathered and a solver advanced."""
+    return jax.tree.map(lambda f, p: f.at[idx].set(p), state, part)
+
+
 def _rung_step(data, state, limit, horizon, target, chunk, *, rung, **cfg):
     """One fused rung dispatch: while_loop the chunk→compact→resume cycle
     at width ``rung`` until the active count drops to ``target`` or the
@@ -116,35 +154,18 @@ def _rung_step(data, state, limit, horizon, target, chunk, *, rung, **cfg):
         def impl(data, state, limit, horizon, target, chunk, rung, **cfg):
             _, _, advance_one, _ = _lane_fns(**cfg)
 
-            def n_active_of(st):
-                return jnp.sum((st.reason == 0).astype(jnp.int32))  # lint: bitwise-reduction — int32 flag count; integer addition is exact in any order
-
             def cond(carry):
                 st, lim, _, _ = carry
-                return (n_active_of(st) > target) & (lim < horizon)
+                return (running_lanes(st) > target) & (lim < horizon)
 
             def body(carry):
                 st, lim, executed, dchunks = carry
-                # in-program compaction: actives first, each group in
-                # ascending entity index — the host loop's np.nonzero
-                # order, so the gathered batch is the same one the host
-                # loop would have built on this rung
-                inactive = (st.reason != 0).astype(jnp.int32)
-                order = jnp.argsort(inactive, stable=True)
-                idx = order[:rung]  # static slice: shapes stay fixed
-                take = lambda a: jnp.take(a, idx, axis=0)
-                data_r = jax.tree.map(take, data)
-                st_r = jax.tree.map(take, st)
+                idx, data_r, st_r = compact_lanes(data, st, rung)
                 new_lim = jnp.minimum(lim + chunk, horizon)
                 st_r = jax.vmap(
                     advance_one, in_axes=(0, 0, 0, 0, 0, None)
                 )(*data_r, st_r, new_lim)
-                # idx holds rung DISTINCT entity indices; converged
-                # fillers advanced as the identity, so scattering every
-                # lane back at its own index is exact
-                st = jax.tree.map(
-                    lambda f, p: f.at[idx].set(p), st, st_r
-                )
+                st = scatter_lanes(st, idx, st_r)
                 advanced = jnp.maximum(
                     jnp.minimum(jnp.max(st_r.iteration), new_lim) - lim, 0
                 )
@@ -158,7 +179,7 @@ def _rung_step(data, state, limit, horizon, target, chunk, *, rung, **cfg):
             st, lim, executed, dchunks = lax.while_loop(
                 cond, body, (state, limit, zero, zero)
             )
-            return st, lim, executed, dchunks, n_active_of(st)
+            return st, lim, executed, dchunks, running_lanes(st)
 
         _RUNG_JIT = instrumented_jit(
             impl,
@@ -292,3 +313,100 @@ def device_solve(
     result = result_of(state)
     record_compacted_evaluations(result, label)
     return result
+
+
+# ---------------------------------------------------------------------------
+# the one-shot solve's ladder: compaction inside the program that solves
+# ---------------------------------------------------------------------------
+
+#: Lanes a unit of the ladder where the lanes' batching rule picks no lane
+#: block (off a TPU, a sparse slab): the block it picks on the chip at the
+#: GLMix cell's shape, so a ladder has the same widths on either.
+LADDER_UNIT = 64
+#: Units in the narrowest rung. Every rung is one more copy of the solver's
+#: body and the lane kernel in the program, so the ladder stops where a
+#: rung saves a few hundred lanes' evaluations.
+LADDER_FLOOR_UNITS = 4
+
+
+def lane_widths(lanes: int, unit: Optional[int] = None) -> Tuple[int, ...]:
+    """The widths a one-shot solve of ``lanes`` lanes runs its iterations
+    at, widest first: ``lanes``, then halving widths below it, each rounded
+    up to a multiple of ``unit`` (the lanes' kernel block, default
+    :data:`LADDER_UNIT`), while they are at least
+    :data:`LADDER_FLOOR_UNITS` units. One width, ``lanes``, where ``lanes``
+    is under twice that floor: the solve is then the plain vmapped loop.
+    At 34,624 lanes of 64: 34,624, 17,344, 8,704, 4,352, 2,176, 1,088, 576,
+    320."""
+    unit = unit or LADDER_UNIT
+    floor = LADDER_FLOOR_UNITS * unit
+    widths = [lanes]
+    if lanes < 2 * floor:
+        return tuple(widths)
+    width = -(-lanes // 2)
+    while True:
+        width = -(-width // unit) * unit
+        if width < floor:
+            return tuple(widths)
+        widths.append(width)
+        width = -(-width // 2)
+
+
+def ladder_solve(data, w0, widths, init_one, advance_one, max_iterations: int):
+    """Every lane of ``data = (x, labels, offsets, weights)`` solved from
+    ``w0`` in one traced program, as ``vmap`` of the one-shot solve would,
+    but iterated over the static ``widths`` (:func:`lane_widths`): rung
+    ``k`` advances its batch one iteration at a time while more than
+    ``widths[k + 1]`` of its lanes run, then :func:`compact_lanes` gathers
+    the running ones (and fillers) into rung ``k + 1`` from rung ``k``'s own
+    arrays, once a rung; the last rung runs to the end. Each rung's final
+    state is scattered back into the batch it was gathered from, so the
+    returned state is in lane order. Returns that state and the iteration
+    at which each width took over, int32 ``(len(widths),)``: what
+    :func:`rung_entries` reads back from the lanes' iteration counts.
+
+    ``init_one`` / ``advance_one`` are :func:`~photon_ml_tpu.algorithm.
+    random_effect.entity_lane_fns`' closures. A lane's iterations are the
+    same arithmetic whichever batch and width it runs in (the resumable
+    solvers' contract, tests/test_scheduler.py), so every lane ends with
+    the one-shot loop's bits; the rung a lane runs in decides only how many
+    other lanes' evaluations the batched line search waits for."""
+    step = jax.vmap(advance_one, in_axes=(0, 0, 0, 0, 0, None))
+
+    def rung(k, data_k, state_k, limit):
+        target = widths[k + 1] if k + 1 < len(widths) else 0
+
+        def cond(carry):
+            st, lim = carry
+            return (running_lanes(st) > target) & (lim < max_iterations)
+
+        def body(carry):
+            st, lim = carry
+            return step(*data_k, st, lim + 1), lim + 1
+
+        # a scope a rung: each width's evaluations are a place of their own
+        # in the trace, so a count of the lane kernel's executions sums them
+        with jax.named_scope(f"pml.re.rung{k}"):
+            state_k, limit = lax.while_loop(cond, body, (state_k, limit))
+        if k + 1 == len(widths):
+            return state_k, []
+        with jax.named_scope("pml.re.compact"):
+            idx, data_n, state_n = compact_lanes(data_k, state_k, widths[k + 1])
+        state_n, entries = rung(k + 1, data_n, state_n, limit)
+        with jax.named_scope("pml.re.compact"):
+            return scatter_lanes(state_k, idx, state_n), [limit] + entries
+
+    state, entries = rung(0, data, jax.vmap(init_one)(*data, w0), jnp.int32(0))
+    return state, jnp.stack([jnp.int32(0)] + entries)
+
+
+def rung_entries(iterations, widths, max_iterations: int):
+    """The iteration at which :func:`ladder_solve` entered each of its
+    ``widths``, as int32 ``(len(widths),)``, from the lanes' own iteration
+    counts: a lane runs after ``i`` iterations exactly where it made more
+    than ``i``, and rung ``k`` takes over at the first ``i`` with at most
+    ``widths[k]`` lanes running (or at ``max_iterations``)."""
+    limits = jnp.arange(max_iterations)
+    running = jnp.sum((iterations[:, None] > limits).astype(jnp.int32), axis=0)  # lint: bitwise-reduction — int32 counts; integer addition is exact in any order
+    return jnp.stack([jnp.int32(0)] + [
+        jnp.sum((running > w).astype(jnp.int32)) for w in widths[1:]])  # lint: bitwise-reduction — int32 counts; integer addition is exact in any order

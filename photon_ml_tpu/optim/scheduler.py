@@ -37,8 +37,9 @@ The same registry prices per-entity solves in objective evaluations, the
 unit that costs time (an L-BFGS iteration is 1 to ``max_line_search_steps``
 of them): compacted solves and the one-shot vmapped solve a descent's
 program runs (``algorithm/coordinate_descent.py``) both record what their
-lanes made themselves and what a vmapped loop needs when stopped lanes do
-not hold its line search (:func:`evaluation_counts`).
+lanes made themselves, what a vmapped loop needs when stopped lanes do
+not hold its line search (:func:`evaluation_counts`), and that need priced
+at the widths the loop ran each iteration at (:func:`result_evaluation_counts`).
 
 Env control: ``PHOTON_SOLVE_CHUNK`` = ``off`` (default) | ``on`` | K
 (chunk size) | ``device[:K]`` (the fused on-device loop,
@@ -231,15 +232,35 @@ class SolveRecord:
 class EvaluationRecord:
     """Objective evaluations of one per-entity solve (:func:`evaluation_counts`).
 
-    The two counts are the int32 device scalars the solving program reduced
+    The counts are the int32 device values the solving program reduced
     until the ledger is read, and host ints after: recording adds no launch
-    and no host wait to the solve."""
+    and no host wait to the solve. ``widths`` are the batch widths the loop
+    ran at, widest first, and ``rung_entries`` the iteration each took over
+    at; ``iteration_needed`` is the most evaluations any lane made in each
+    iteration (entry 0 the initial one). Without them the solve ran at one
+    width, ``lanes``."""
 
     label: str
     kind: str  # "one-shot" (a descent program's vmapped solve) | "compacted"
     lanes: int
     lane_evaluations: object  # sum over lanes of each lane's own evaluations
     needed_evaluations: object  # sum over iterations of the most any lane made
+    widths: Optional[tuple] = None
+    rung_entries: object = None  # (len(widths),) int32
+    iteration_needed: object = None  # (max_iter + 1,) int32
+
+    @property
+    def width_evaluations(self) -> float:
+        """The needed evaluations priced at the width each iteration ran at,
+        in full-width evaluations: sum over iterations of width times the
+        most any running lane made, over the solve's lanes (read). Equals
+        ``needed_evaluations`` for a solve at one width."""
+        if self.widths is None:
+            return float(self.needed_evaluations)
+        rung = np.searchsorted(np.asarray(self.rung_entries)[1:],
+                               np.arange(len(self.iteration_needed)))
+        widths = np.asarray(self.widths, np.int64)[rung]
+        return int(np.dot(widths, np.asarray(self.iteration_needed, np.int64))) / self.lanes
 
 
 def evaluation_counts(evaluations, evaluation_history):
@@ -260,14 +281,23 @@ def evaluation_counts(evaluations, evaluation_history):
     return lane, needed
 
 
-def result_evaluation_counts(result):
-    """:func:`evaluation_counts` of a lane-stacked result, or None where it
-    is not one (an unbatched solve, a bucketed tuple) or its solver keeps
-    no count (TRON)."""
+def result_evaluation_counts(result, widths=None):
+    """:func:`evaluation_counts` of a lane-stacked result, then each
+    iteration's most evaluations of any lane and the iteration at which
+    each of ``widths`` (the one-shot ladder's, ``optim.fused_schedule
+    .lane_widths``; None: one width) took over, as traced in the program
+    that solves; None where the result is no lane-stacked one (an unbatched
+    solve, a bucketed tuple) or its solver keeps no count (TRON)."""
+    from photon_ml_tpu.optim.fused_schedule import rung_entries
+
     history = getattr(result, "evaluation_history", None)
     if history is None or history.ndim != 2:
         return None
-    return evaluation_counts(result.evaluations, history)
+    with jax.named_scope("pml.re.evaluation_counts"):
+        entries = rung_entries(result.iterations, widths or (history.shape[0],),
+                               history.shape[1] - 1)
+        per_iteration = jnp.max(history, axis=0)
+    return (*evaluation_counts(result.evaluations, history), per_iteration, entries)
 
 
 class SolveStats:
@@ -320,10 +350,15 @@ class SolveStats:
             del self._recent[: -self.RECENT_KEEP]
 
     def record_evaluations(self, label: str, kind: str, lanes: int,
-                           counts) -> None:
-        """One per-entity solve's :func:`evaluation_counts` (device scalars,
-        kept unread)."""
-        rec = EvaluationRecord(label, kind, int(lanes), *counts)
+                           counts, widths=None) -> None:
+        """One per-entity solve's :func:`evaluation_counts`, or
+        :func:`result_evaluation_counts` of a solve at ``widths`` (device
+        values, kept unread)."""
+        lane, needed, *rungs = counts
+        rec = EvaluationRecord(label, kind, int(lanes), lane, needed)
+        if rungs:
+            rec.iteration_needed, rec.rung_entries = rungs
+            rec.widths = tuple(widths or (int(lanes),))
         with self._lock:
             self._evaluations_unread.append(rec)
             if len(self._evaluations_unread) > 2 * self.RECENT_KEEP:
@@ -336,21 +371,27 @@ class SolveStats:
         if not batch:
             return
         values = jax.device_get(
-            [(r.lane_evaluations, r.needed_evaluations) for r in batch]
+            [(r.lane_evaluations, r.needed_evaluations, r.iteration_needed,
+              r.rung_entries) for r in batch]
         )
         del self._evaluations_unread[: len(batch)]
-        for rec, (lane, needed) in zip(batch, values):
+        for rec, (lane, needed, per_iteration, entries) in zip(batch, values):
             rec.lane_evaluations, rec.needed_evaluations = int(lane), int(needed)
+            if rec.widths is not None:
+                rec.iteration_needed = tuple(int(n) for n in per_iteration)
+                rec.rung_entries = tuple(int(i) for i in entries)
             t = self._evaluation_totals.setdefault(
                 (rec.kind, rec.label),
                 {"solves": 0, "lanes": 0, "lane_evaluations": 0,
-                 "needed_evaluations": 0, "lane_means": 0.0},
+                 "needed_evaluations": 0, "lane_means": 0.0,
+                 "width_evaluations": 0.0},
             )
             t["solves"] += 1
             t["lanes"] += rec.lanes
             t["lane_evaluations"] += rec.lane_evaluations
             t["needed_evaluations"] += rec.needed_evaluations
             t["lane_means"] += rec.lane_evaluations / max(rec.lanes, 1)
+            t["width_evaluations"] += rec.width_evaluations
         self._evaluations_read.extend(batch)
         del self._evaluations_read[: -self.RECENT_KEEP]
 
@@ -362,9 +403,10 @@ class SolveStats:
 
     def evaluation_totals(self, label: Optional[str] = None) -> dict:
         """Kind -> summed ``solves``, ``lanes``, ``lane_evaluations``,
-        ``needed_evaluations`` and ``lane_means`` (each solve's lane
-        evaluations over its lanes) of every recorded solve, of ``label``
-        alone where given; reads what is unread."""
+        ``needed_evaluations``, ``lane_means`` (each solve's lane
+        evaluations over its lanes) and ``width_evaluations``
+        (:attr:`EvaluationRecord.width_evaluations`) of every recorded
+        solve, of ``label`` alone where given; reads what is unread."""
         with self._lock:
             self._read_evaluations()
             out: dict = {}
@@ -488,7 +530,8 @@ class SolveStats:
         lines.append("solve evaluations: " + "; ".join(
             f"{kind} " + (
                 f"{t['solves']} solves / {t['lanes']} lanes, "
-                f"{t['needed_evaluations'] / t['solves']:.1f} needed a solve vs "
+                f"{t['needed_evaluations'] / t['solves']:.1f} needed a solve, "
+                f"{t['width_evaluations'] / t['solves']:.1f} at the widths run, vs "
                 f"{t['lane_means'] / t['solves']:.1f} a lane's own"
                 if t else "none"
             )

@@ -16,6 +16,9 @@ The load-bearing claims, pinned BITWISE:
     chunk loop — results stay bitwise, and the next solve is fused again.
 """
 
+import functools
+import re
+
 import numpy as np
 import pytest
 
@@ -453,3 +456,217 @@ class TestCoordinateWiring:
         assert np.array_equal(
             np.asarray(host.score(st_host)), np.asarray(dev.score(st_dev))
         )
+
+
+# ---------------------------------------------------------------------------
+# the one-shot solve's ladder: compaction inside the descent's own program
+# ---------------------------------------------------------------------------
+
+
+#: The three solvers a one-shot update can run per lane, as the device-loop
+#: tests above name them: regularization and optimizer of the coordinate.
+LANE_SOLVERS = {
+    "lbfgs-l2": (RegularizationContext.l2(0.1), OptimizerType.LBFGS),
+    "owlqn-l1": (RegularizationContext.elastic_net(0.3, 0.5), OptimizerType.LBFGS),
+    "tron": (RegularizationContext.l2(0.1), OptimizerType.TRON),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _ladder_case(solver: str):
+    """About 2,000 users of 3 to 300 rows each: their lanes stop from the
+    third iteration to the thirteenth or later, so a one-shot update runs
+    iterations at every width of its ladder (2,000, 1,024, 512, 256). The
+    update as a descent compiles it, and the plain vmapped loop of the same
+    lanes beside it."""
+    data, _ = make_glmix_data(
+        np.random.default_rng(43), num_users=2000,
+        rows_per_user_range=(3, 300), d_fixed=4, d_random=3,
+    )
+    ds = build_random_effect_dataset(
+        data, RandomEffectDataConfig("userId", "per_user")
+    )
+    reg, optimizer = LANE_SOLVERS[solver]
+    coord = RandomEffectCoordinate(
+        dataset=ds, task=TaskType.LOGISTIC_REGRESSION, optimizer=optimizer,
+        regularization=reg,
+    )
+    solve_one, init_one, advance_one, result_of = entity_lane_fns(
+        coord.task, coord.optimizer, coord.optimizer_config,
+        coord.regularization,
+    )
+    resid = jnp.zeros((data.num_rows,), jnp.float32)
+    lanes = (ds.x, ds.labels, coord.gathered_offsets(resid), ds.weights)
+    w0 = coord.initial_coefficients()
+    ladder = jax.jit(lambda lanes, w0: fused_schedule.ladder_solve(
+        lanes, w0, coord.rung_widths, init_one, advance_one,
+        coord.optimizer_config.max_iterations,
+    ))
+    return dict(
+        data=data, coord=coord, resid=resid, lanes=lanes, w0=w0,
+        ladder=lambda lanes, w0: result_of(ladder(lanes, w0)[0]),
+        ladder_entries=lambda: ladder(lanes, w0)[1],
+        one_shot=jax.jit(jax.vmap(solve_one))(*lanes, w0),
+        one_shot_of=jax.jit(jax.vmap(solve_one)),
+    )
+
+
+def _top_level_whiles(fn, *args):
+    return sum(e.primitive.name == "while" for e in jax.make_jaxpr(fn)(*args).eqns)
+
+
+class TestOneShotLadder:
+    """The ladder's lanes against the plain vmapped loop's, bit for bit.
+
+    Both sides run the same lanes in the same order: XLA's CPU backend
+    does not give a lane the same bits in every place of a batch (it
+    contracts a multiply and an add into one FMA in some places of a
+    vectorized loop), so on the CPU the plain loop of permuted lanes is
+    itself a few ulp off in some lanes. That a lane's bits follow neither
+    its place nor its batch's width is checked on the chip, where the
+    benchmark's GLMix cell compares to the parent's digits."""
+
+    def test_widths_halve_from_the_lanes_down_to_the_floor(self):
+        widths = fused_schedule.lane_widths
+        assert widths(34624) == (34624, 17344, 8704, 4352, 2176, 1088, 576, 320)
+        assert widths(2000) == (2000, 1024, 512, 256)
+        assert widths(512) == (512, 256)
+        assert widths(511) == (511,) and widths(40) == (40,)
+        # the unit is the lanes' kernel block where it picks one
+        assert widths(2000, 16) == (2000, 1008, 512, 256, 128, 64)
+        for lanes in (600, 2000, 34624, 100003):
+            for unit in (8, 64):
+                w = widths(lanes, unit)
+                assert all(a > b for a, b in zip(w, w[1:]))
+                assert all(x % unit == 0 and x >= 4 * unit for x in w[1:])
+
+    @pytest.mark.parametrize("solver", list(LANE_SOLVERS))
+    def test_matches_the_one_shot_loop_bitwise_across_the_rungs(self, solver):
+        case = _ladder_case(solver)
+        coord, one_shot = case["coord"], case["one_shot"]
+        widths = coord.rung_widths
+        assert widths == (2000, 1024, 512, 256)
+        _, got = jax.jit(coord.update)(case["resid"], case["w0"])
+        assert_results_bitwise(got, one_shot)
+        # iterations ran at three widths at least: the lanes crossed rungs
+        entries = np.asarray(fused_schedule.rung_entries(
+            one_shot.iterations, widths, coord.optimizer_config.max_iterations))
+        ends = np.append(entries[1:], int(np.max(one_shot.iterations)))
+        assert np.count_nonzero(ends > entries) >= 3
+
+    def test_the_entries_read_back_are_the_loops_own(self):
+        """``rung_entries`` re-derives from the lanes' iteration counts the
+        iteration at which the ladder took each width; it must read what the
+        loop itself recorded."""
+        case = _ladder_case("lbfgs-l2")
+        coord = case["coord"]
+        recorded = np.asarray(case["ladder_entries"]())
+        derived = np.asarray(fused_schedule.rung_entries(
+            case["one_shot"].iterations, coord.rung_widths,
+            coord.optimizer_config.max_iterations))
+        assert np.array_equal(recorded, derived)
+        assert np.all(np.diff(recorded) > 0)
+
+    def test_each_width_evaluates_in_a_place_of_its_own(self):
+        """A trace counts a scope's executions per place in the program (the
+        op_name's prefix): the lane kernel of each rung has to sit under a
+        prefix of its own, or every width's evaluations count as the
+        widest's."""
+        case = _ladder_case("lbfgs-l2")
+        coord = case["coord"]
+        text = jax.jit(coord.update).lower(
+            case["resid"], case["w0"]).as_text(debug_info=True)
+        rungs = set(re.findall(
+            r"(pml\.re\.rung\d+)/while/body/[^\"]*pml\.re\.value_and_grad", text))
+        assert rungs == {f"pml.re.rung{k}" for k in range(len(coord.rung_widths))}
+
+    def test_permuted_lanes_keep_their_bits(self):
+        case = _ladder_case("lbfgs-l2")
+        order = np.random.default_rng(3).permutation(case["w0"].shape[0])
+        take = lambda a: a[order]
+        lanes, w0 = jax.tree.map(take, case["lanes"]), take(case["w0"])
+        assert_results_bitwise(case["ladder"](lanes, w0),
+                               case["one_shot_of"](*lanes, w0))
+
+    def test_inside_the_descents_compiled_update(self):
+        from photon_ml_tpu.algorithm.coordinate_descent import CoordinateDescent
+        from photon_ml_tpu.ops import losses
+
+        case = _ladder_case("lbfgs-l2")
+        data, coord = case["data"], case["coord"]
+        loss = losses.for_task(TaskType.LOGISTIC_REGRESSION)
+        labels, weights = jnp.asarray(data.response), jnp.asarray(data.weight)
+        descent = CoordinateDescent(
+            {"per_user": coord},
+            lambda total: jnp.sum(weights * loss.loss(total, labels)),
+        )
+        assert getattr(coord, "cd_jit", True)
+        result = descent.run(num_iterations=1, num_rows=data.num_rows)
+        one_shot = case["one_shot"]
+        assert_results_bitwise(result.trackers["per_user"], one_shot)
+        assert np.array_equal(
+            np.asarray(result.coefficients["per_user"]),
+            np.asarray(one_shot.coefficients),
+        )
+
+    def test_a_sparse_slab_steps_over_the_ladder_bitwise(self):
+        """The streaming coordinate's blocks may hand ``update`` a sparse
+        slab; the ladder gathers its leaves as it gathers a dense stack."""
+        data, _ = make_glmix_data(
+            np.random.default_rng(43), num_users=1200,
+            rows_per_user_range=(3, 200), d_fixed=4, d_random=5,
+        )
+        ds = build_random_effect_dataset(
+            data, RandomEffectDataConfig("userId", "per_user"))
+        coord = RandomEffectCoordinate(
+            dataset=ds, task=TaskType.LOGISTIC_REGRESSION,
+            regularization=RegularizationContext.l2(0.1),
+            sparse_kernel="scatter",
+        )
+        assert coord._slab is not None
+        assert coord.rung_widths == (1200, 640, 320)
+        resid = jnp.zeros((data.num_rows,), jnp.float32)
+        w0 = coord.initial_coefficients()
+        _, got = jax.jit(coord.update)(resid, w0)
+        solve_one, *_ = entity_lane_fns(
+            coord.task, coord.optimizer, coord.optimizer_config,
+            coord.regularization,
+        )
+        one_shot = jax.jit(jax.vmap(solve_one))(
+            coord._slab, ds.labels, coord.gathered_offsets(resid), ds.weights, w0)
+        assert_results_bitwise(got, one_shot)
+        entries = np.asarray(fused_schedule.rung_entries(
+            one_shot.iterations, coord.rung_widths,
+            coord.optimizer_config.max_iterations))
+        assert np.all(np.diff(entries) > 0)
+
+    def test_below_the_floor_the_update_is_the_one_shot_loop(self, glmix):
+        """Under twice the floor the ladder is one width, and the update's
+        program is the vmapped solve's own single loop."""
+        ds = build_random_effect_dataset(
+            glmix, RandomEffectDataConfig("userId", "per_user"))
+        small = RandomEffectCoordinate(
+            dataset=ds, task=TaskType.LOGISTIC_REGRESSION,
+            regularization=RegularizationContext.l2(0.1),
+        )
+        assert small.rung_widths == (ds.num_entities,)
+        solve_one, *_ = entity_lane_fns(
+            small.task, small.optimizer, small.optimizer_config,
+            small.regularization,
+        )
+        resid = jnp.zeros((glmix.num_rows,), jnp.float32)
+        w0 = small.initial_coefficients()
+
+        def plain(resid, w0):
+            off = small.gathered_offsets(resid)
+            results = jax.vmap(solve_one)(ds.x, ds.labels, off, ds.weights, w0)
+            return results.coefficients, results
+
+        assert _top_level_whiles(small.update, resid, w0) == 1
+        assert str(jax.make_jaxpr(small.update)(resid, w0)) == str(
+            jax.make_jaxpr(plain)(resid, w0))
+        case = _ladder_case("lbfgs-l2")
+        big = case["coord"]
+        assert _top_level_whiles(
+            big.update, case["resid"], big.initial_coefficients(),
+        ) == len(big.rung_widths)

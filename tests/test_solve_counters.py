@@ -3,8 +3,10 @@
 carried state and in ``OptResult``), and the solve ledger that prices the
 per-entity solves in them (``optim/scheduler.py``: ``evaluation_counts``,
 ``solve_stats``), for the one-shot vmapped solve a descent's program runs
-and for compacted solves; the benchmark's two readers of that ledger
-(``benchmark/metrics/readers/counters.py``)."""
+and for compacted solves, the one-shot solve's priced at the widths its
+ladder ran each iteration at (``optim/fused_schedule.py``); the benchmark's
+readers of that ledger (``benchmark/metrics/readers/counters.py``,
+``widths.py``)."""
 
 import json
 import os
@@ -351,3 +353,99 @@ def test_the_counter_readers_read_nothing_under_another_label(glmix, ledger):
     ledger.record_evaluations("per-item", "one-shot", 4, (jnp.int32(8), jnp.int32(3)))
     read = _readers()
     assert read["needed"]() is None and read["lane"]() is None
+
+
+# -- the needed evaluations priced at the widths the loop ran ---------------
+
+
+def _host_width_evaluations(tracker, widths):
+    """The ladder run on the host: each iteration's width from the lanes
+    still running before it, times the most evaluations any lane made."""
+    iterations = np.asarray(tracker.iterations)
+    history = np.asarray(tracker.evaluation_history)
+    lanes = iterations.shape[0]
+    total = lanes * int(history[:, 0].max())
+    for i in range(1, history.shape[1]):
+        running = int(np.count_nonzero(iterations > i - 1))
+        width = widths[sum(running <= w for w in widths[1:])]
+        total += width * int(history[:, i].max())
+    return total / lanes
+
+
+def test_at_one_width_the_priced_count_is_the_needed_one(glmix, ledger):
+    _descent(glmix).run(2, glmix.num_rows)
+    records = ledger.evaluation_snapshot()
+    assert len(records) == 2
+    for rec in records:
+        assert rec.widths == (rec.lanes,) and rec.rung_entries == (0,)
+        assert rec.width_evaluations == rec.needed_evaluations
+    totals = ledger.evaluation_totals("per-user")["one-shot"]
+    assert totals["width_evaluations"] == totals["needed_evaluations"]
+    assert "at the widths run" in ledger.summary()
+
+
+@pytest.fixture(scope="module")
+def skewed():
+    """About 2,000 users of 3 to 300 rows: the one-shot solve's ladder is
+    2,000, 1,024, 512 and 256 lanes wide, and the lanes stop over enough
+    iterations to run at each."""
+    data, _ = make_glmix_data(np.random.default_rng(43), num_users=2000,
+                              rows_per_user_range=(3, 300), d_fixed=4, d_random=3)
+    return data
+
+
+def test_the_ladder_prices_each_iteration_at_its_width(skewed, ledger):
+    coord = RandomEffectCoordinate(
+        build_random_effect_dataset(skewed, RandomEffectDataConfig("userId", "per_user")),
+        LOGISTIC, regularization=RegularizationContext.l2(1e-1), solve_label="per-user")
+    loss = losses.for_task(LOGISTIC)
+    labels, weights = jnp.asarray(skewed.response), jnp.asarray(skewed.weight)
+    result = CoordinateDescent(
+        {"per-user": coord}, lambda total: jnp.sum(weights * loss.loss(total, labels)),
+    ).run(1, skewed.num_rows)
+    tracker = result.trackers["per-user"]
+    (rec,) = ledger.evaluation_snapshot()
+    assert rec.widths == coord.rung_widths == (2000, 1024, 512, 256)
+    iterations = np.asarray(tracker.iterations)
+    running = [int(np.count_nonzero(iterations > i))
+               for i in range(coord.optimizer_config.max_iterations)]
+    assert rec.rung_entries == tuple(
+        [0] + [sum(r > w for r in running) for w in rec.widths[1:]])
+    assert rec.width_evaluations == pytest.approx(
+        _host_width_evaluations(tracker, rec.widths), rel=1e-12)
+    assert rec.width_evaluations < rec.needed_evaluations
+    totals = ledger.evaluation_totals("per-user")["one-shot"]
+    assert f"{totals['width_evaluations']:.1f} at the widths run" in ledger.summary()
+    read = _width_reader()
+    assert read() == pytest.approx(rec.width_evaluations)
+
+
+def test_a_compacted_solve_is_priced_at_its_needed_count(ledger):
+    """The compacted loops keep no widths in the ledger: their price is the
+    needed count, as a one-shot solve's at one width."""
+    data, w0 = _lanes()
+    compacted_solve(data, w0, schedule=SolveSchedule(chunk_size=2), label="lanes", **LANE_KW)
+    (rec,) = ledger.evaluation_snapshot()
+    assert rec.kind == "compacted" and rec.widths is None
+    assert rec.width_evaluations == rec.needed_evaluations
+    totals = ledger.evaluation_totals("lanes")["compacted"]
+    assert totals["width_evaluations"] == totals["needed_evaluations"]
+
+
+def _width_reader():
+    from benchmark.metrics.readers import widths
+
+    with open(os.path.join(ROOT, "benchmark", "metrics",
+                           "re_width_evaluations_per_solve.json")) as f:
+        spec = json.load(f)
+    return lambda: widths.width_evaluations_per_solve({"metric": spec})
+
+
+def test_the_width_reader_reads_only_its_label(glmix, ledger):
+    read = _width_reader()
+    assert read() is None
+    ledger.record_evaluations("per-item", "one-shot", 4, (jnp.int32(8), jnp.int32(3)))
+    assert read() is None
+    _descent(glmix).run(2, glmix.num_rows)
+    records = [r for r in ledger.evaluation_snapshot() if r.label == "per-user"]
+    assert read() == pytest.approx(np.mean([r.width_evaluations for r in records]))
